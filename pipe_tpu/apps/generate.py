@@ -134,6 +134,8 @@ def build_argparser() -> argparse.ArgumentParser:
 
 def main(argv=None) -> int:
     args = build_argparser().parse_args(argv)
+    from ..utils.platform import configure_compile_cache
+    configure_compile_cache()
     if args.cpu:
         from ..utils.platform import force_cpu_platform
         force_cpu_platform(args.cpu)

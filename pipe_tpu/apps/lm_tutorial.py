@@ -61,6 +61,8 @@ def build_argparser() -> argparse.ArgumentParser:
 
 def main(argv=None) -> int:
     args = build_argparser().parse_args(argv)
+    from pipe_tpu.utils.platform import configure_compile_cache
+    configure_compile_cache()
     if args.cpu:
         from pipe_tpu.utils.platform import force_cpu_platform
         force_cpu_platform(args.cpu)

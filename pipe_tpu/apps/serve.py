@@ -262,6 +262,8 @@ def build_argparser() -> argparse.ArgumentParser:
 
 def main(argv=None) -> int:
     args = build_argparser().parse_args(argv)
+    from ..utils.platform import configure_compile_cache
+    configure_compile_cache()
     if args.cpu:
         from ..utils.platform import force_cpu_platform
         force_cpu_platform(args.cpu)
@@ -302,6 +304,21 @@ def main(argv=None) -> int:
         print("--replicas > 1 requires --stages 1 (the fleet router "
               "shards single-device engines)", file=sys.stderr)
         return 2
+    if replicas > 1 and args.fleet == "proc":
+        import jax
+        if jax.default_backend() != "cpu":
+            # one process for each chip: this parent initialises the
+            # weights on the default backend and so holds the chip, while
+            # every replica child is started with JAX_PLATFORMS=cpu
+            # (fleet/proc.py _spawn_env) — a fleet serving from the CPU
+            # beside an idle accelerator
+            print(f"--fleet proc is refused on the "
+                  f"{jax.default_backend()!r} backend: a chip belongs to "
+                  f"one process, this parent holds it, and the replica "
+                  f"children run on the CPU. Use --fleet thread (one "
+                  f"process drives every replica), or --cpu N for a CPU "
+                  f"process fleet.", file=sys.stderr)
+            return 2
 
     if args.prompts_file:
         if not os.path.isfile(args.prompts_file):
@@ -596,7 +613,7 @@ def main(argv=None) -> int:
     from ..serve import EngineDraining
 
     t0 = time.monotonic()
-    i = rejected = done = 0
+    i = rejected = done = errors = 0
     while i < len(prompts) or not eng.idle:
         if eng.draining:
             i = len(prompts)      # stop submitting; finish what's live
@@ -616,6 +633,7 @@ def main(argv=None) -> int:
             continue
         for r in eng.tick():
             done += 1
+            errors += r.status == "error"
             print(json.dumps({
                 "request": r.request_id, "status": r.status,
                 "finish_reason": r.finish_reason,
@@ -632,7 +650,7 @@ def main(argv=None) -> int:
         "backend": (f"Fleet[{args.fleet}]({type(backend).__name__} x "
                     f"{replicas})"
                     if replicas > 1 else type(backend).__name__),
-        "finished": done, "rejected": rejected,
+        "finished": done, "rejected": rejected, "errors": errors,
         "drained": eng.draining,
         "elapsed_s": round(elapsed, 3),
         "resident": bool(getattr(backend, "resident", False)),
@@ -679,6 +697,14 @@ def main(argv=None) -> int:
     events.close()
     if metrics_server is not None:
         metrics_server.shutdown()
+    if errors:
+        # the engine contains a backend failure (a compile error
+        # included) to the requests it hit; the driver still fails
+        last = getattr(eng, "last_error", None)
+        print(f"{errors} request(s) ended in an engine error"
+              + (f"; last: {type(last).__name__}: {last}"
+                 if last is not None else ""), file=sys.stderr)
+        return 1
     return 0
 
 

@@ -49,6 +49,8 @@ def main(argv=None) -> int:
         parser.error("--steps must be >= 1")
     if args.stages < 1 or args.interleave < 1:
         parser.error("--stages and --interleave must be >= 1")
+    from pipe_tpu.utils.platform import configure_compile_cache
+    configure_compile_cache()
     if args.cpu:
         from pipe_tpu.utils.platform import force_cpu_platform
         force_cpu_platform(args.cpu)

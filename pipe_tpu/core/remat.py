@@ -31,6 +31,7 @@ from typing import Any, Callable
 
 import jax
 import jax.numpy as jnp
+from jax.extend import core as jc
 
 __all__ = [
     "CHECKPOINT_MODES",
@@ -156,9 +157,8 @@ class _SplitPlan:
 
     def __init__(self, closed, n_param_leaves: int, params_treedef,
                  out_tree):
-        jc = jax.core
         jaxpr = closed.jaxpr
-        if any(isinstance(c, jc.Tracer) for c in closed.consts):
+        if any(isinstance(c, jax.core.Tracer) for c in closed.consts):
             raise SplitUnsupported(
                 "stage fn closes over traced values (its jaxpr has tracer "
                 "consts) — pass everything through params/h/ctx so the "
@@ -251,7 +251,6 @@ class _SplitPlan:
         output — more zs/taps, but the replay never recomputes a
         param-dependent value, so cascaded param contractions stay
         linear."""
-        jc = jax.core
         jaxpr = self.closed.jaxpr
         cls, consumers = self.cls, self._consumers
         producer, m_set = self._producer, self._m_set
@@ -349,7 +348,6 @@ class _SplitPlan:
     # pjit / custom_jvp_call / scan eqns run atomically and stay
     # differentiable (everything binds on the caller's tracers).
     def eval_tapped(self, args, zs):
-        jc = jax.core
         jaxpr = self.closed.jaxpr
         if len(zs) != len(self.inject):
             raise ValueError(
@@ -384,7 +382,6 @@ class _SplitPlan:
     # taps as closure constants. Linear in params by construction (or the
     # transpose below fails loudly).
     def _replay(self, param_leaves, tap_vals):
-        jc = jax.core
         jaxpr = self.closed.jaxpr
         env: dict = {}
         taps = dict(zip(self.tap_vars, tap_vals))

@@ -12,6 +12,7 @@ is unavailable, with identical token-for-token semantics (asserted by
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 import threading
@@ -33,14 +34,27 @@ _build_failed = False
 
 
 def _build_lib(src: str, lib: str, *extra_flags: str) -> Optional[str]:
-    """Compile a shared library if missing or stale; None on failure."""
+    """Compile a shared library if missing or stale; None on failure.
+
+    Stale means the hash of the source (and flags) stored beside the
+    library differs from the source's: a tree copied as it lies carries an
+    untracked library whose mtime says nothing about which source built it.
+    """
+    cmd = ["g++", "-O3", "-std=c++17", "-shared", "-fPIC", *extra_flags]
+    stamp = lib + ".sha256"
     try:
-        if (not os.path.exists(lib)
-                or os.path.getmtime(lib) < os.path.getmtime(src)):
-            subprocess.run(
-                ["g++", "-O3", "-std=c++17", "-shared", "-fPIC",
-                 *extra_flags, src, "-o", lib],
-                check=True, capture_output=True, timeout=120)
+        with open(src, "rb") as f:
+            want = hashlib.sha256(
+                " ".join(cmd).encode() + b"\0" + f.read()).hexdigest()
+        have = None
+        if os.path.exists(lib) and os.path.exists(stamp):
+            with open(stamp) as f:
+                have = f.read().strip()
+        if have != want:
+            subprocess.run(cmd + [src, "-o", lib],
+                           check=True, capture_output=True, timeout=120)
+            with open(stamp, "w") as f:
+                f.write(want + "\n")
         return lib
     except (OSError, subprocess.SubprocessError):
         return None

@@ -179,14 +179,18 @@ class ReplicaSpec:
 def _spawn_env(repo_root: Optional[str] = None,
                jax_platform: str = "cpu") -> Dict[str, str]:
     """Child environment, the ``runtime/_multiproc_check`` discipline:
-    fresh interpreters must not boot an accelerator plugin meant for
-    the parent (it would hang platform selection) and must not inherit
-    a forced device count — the child picks its own platform."""
+    a chip belongs to one process — the parent's, if it has one — so
+    replica children are started on the CPU (``jax_platform`` defaults
+    to it; ``apps/serve.py`` refuses ``--fleet proc`` on an accelerator
+    for that reason). They must not inherit a forced device count: the
+    child picks its own. The checkout goes in FRONT of the caller's
+    ``PYTHONPATH``; the rest of it is kept."""
     if repo_root is None:
         repo_root = os.path.dirname(os.path.dirname(
             os.path.dirname(os.path.abspath(__file__))))
     env = dict(os.environ)
-    env["PYTHONPATH"] = repo_root
+    env["PYTHONPATH"] = os.pathsep.join(
+        p for p in (repo_root, env.get("PYTHONPATH")) if p)
     env.pop("XLA_FLAGS", None)
     env["JAX_PLATFORMS"] = jax_platform
     return env
@@ -1572,6 +1576,8 @@ def _main(argv: Optional[List[str]] = None) -> int:
     ap.add_argument("--host", default="127.0.0.1",
                     help="parent listener address to dial back to")
     args = ap.parse_args(argv)
+    from ..utils.platform import configure_compile_cache
+    configure_compile_cache()
     worker(args.port, args.token, args.host)
     return 0
 

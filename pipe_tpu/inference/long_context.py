@@ -43,7 +43,6 @@ from ..models.long_context_lm import ContextParallelLM
 from ..parallel.mesh import CONTEXT_AXIS
 from .generate import GenerationConfig, check_positions, sample_logits
 from .quant import dequant_tree
-from ..utils.compat import shard_map
 
 __all__ = ["ContextShardedGenerator"]
 
@@ -385,7 +384,7 @@ class ContextShardedGenerator:
                 P(None, CONTEXT_AXIS),   # prompt: sequence-sharded
                 P(),
             )
-            run = jax.jit(shard_map(
+            run = jax.jit(jax.shard_map(
                 functools.partial(self._device_program, s_local=s_local),
                 mesh=self.mesh, in_specs=in_specs, out_specs=P(),
                 check_vma=False))
@@ -420,7 +419,7 @@ class ContextShardedGenerator:
                 jax.tree_util.tree_map(lambda _: P(), post_params),
                 P(None, CONTEXT_AXIS),   # prompt: sequence-sharded
             )
-            run = jax.jit(shard_map(
+            run = jax.jit(jax.shard_map(
                 functools.partial(self._device_program_beam,
                                   s_local=s_local),
                 mesh=self.mesh, in_specs=in_specs, out_specs=(P(), P()),

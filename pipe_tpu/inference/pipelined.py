@@ -48,7 +48,6 @@ from ..parallel.mesh import STAGE_AXIS
 from .generate import (GenerationConfig, check_positions, head_logits,
                        sample_logits, sequence_lengths)
 from .quant import QuantLeaf, dequant_tree
-from ..utils.compat import shard_map
 
 __all__ = ["PipelinedGenerator"]
 
@@ -457,7 +456,7 @@ class PipelinedGenerator:
                 jax.tree_util.tree_map(lambda _: P(), post_params),
                 P(), P(),
             )
-            run = jax.jit(shard_map(
+            run = jax.jit(jax.shard_map(
                 functools.partial(self._device_program, p=p, rpg=rpg),
                 mesh=self.mesh, in_specs=in_specs, out_specs=P(),
                 check_vma=False))
@@ -517,7 +516,7 @@ class PipelinedGenerator:
                 jax.tree_util.tree_map(lambda _: P(), post_params),
                 P(),
             )
-            run = jax.jit(shard_map(
+            run = jax.jit(jax.shard_map(
                 functools.partial(self._device_program_beam, p=p, rpg=rpg),
                 mesh=self.mesh, in_specs=in_specs, out_specs=(P(), P()),
                 check_vma=False))

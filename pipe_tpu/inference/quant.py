@@ -1,9 +1,9 @@
 """Weight-only int8 quantization for KV-cached decoding.
 
-Single-sequence decode is weight-bandwidth-bound: every generated token
-re-reads every parameter once (the 520M tutorial model measures ~2.6 ms/
-token at batch 1 — the HBM roofline on ~1 GB of bf16 weights,
-``GEN_BENCH_r03.jsonl``). Halving the bytes halves that floor: block
+Single-sequence decode re-reads every parameter once per generated token,
+so by bytes it is weight-bandwidth-bound (the 520M tutorial model streams
+~1 GB of bf16 weights per step; its decode time is not measured on the
+current installation). Halving the bytes halves that floor: block
 weights quantize to int8 with one float32 scale per output channel
 (absmax symmetric), and the dequantize (`q * scale`) happens INSIDE the
 compiled decode step, where XLA fuses it into the matmul's operand read —
@@ -14,8 +14,9 @@ compute dtype), inference-only, symmetric per-channel — the standard
 first rung of the quantization ladder. Per-channel absmax keeps the
 worst-case relative weight error ~0.4%; the accuracy contract (trained
 tiny model: teacher-forced logits within tolerance, top-1 next-token
-agreement) is pinned in ``tests/test_quant.py``, and the throughput claim
-is measured on the real chip (``tools/gen_bench.py --int8``).
+agreement) is pinned in ``tests/test_quant.py``; the throughput effect is
+what ``tools/gen_bench.py --int8`` times on the chip (not measured on the
+current installation).
 
 Mechanics: :func:`quantize_params` maps every quantizable 2-D weight leaf
 to a :class:`QuantLeaf` pytree node (int8 codes + f32 scales) in the SAME

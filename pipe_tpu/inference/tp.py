@@ -33,7 +33,6 @@ from jax.sharding import Mesh, PartitionSpec as P
 from ..obs.telemetry import get_registry
 from ..parallel.mesh import MODEL_AXIS
 from .generate import GenerationConfig, Generator, check_positions
-from ..utils.compat import shard_map
 
 __all__ = ["TPShardedGenerator"]
 
@@ -105,13 +104,13 @@ class TPShardedGenerator(Generator):
             P(),
         )
         if beam:
-            run = jax.jit(shard_map(
+            run = jax.jit(jax.shard_map(
                 lambda sp, pre, post, pr: self._generate_beam(
                     (sp, pre, post), pr),
                 mesh=self.mesh, in_specs=in_specs, out_specs=(P(), P()),
                 check_vma=False))
         else:
-            run = jax.jit(shard_map(
+            run = jax.jit(jax.shard_map(
                 lambda sp, pre, post, pr, k: self._generate(
                     (sp, pre, post), pr, k),
                 mesh=self.mesh, in_specs=in_specs + (P(),),

@@ -105,7 +105,7 @@ def main(n_stages: int = 4, chunks: int = 8,
         from pipe_tpu.parallel.scheduled import ScheduledPipeline
 
         scheds = {}
-        # "1f1b+policy" is the HEADLINE training program (BENCH_r03:
+        # "1f1b+policy" is the HEADLINE training program (bench.py's:
         # except_last + dots_saveable) running on the real multi-device
         # stage axis — the configuration the single-chip bench reports,
         # proven here to execute on the very topology it is sold for.

@@ -469,7 +469,7 @@ def train_flops_per_token(cfg, checkpoint: str, chunks: int):
     return required, hardware
 
 
-# bf16 peak FLOP/s per chip by device kind (dense; conservative defaults).
+# bf16 peak FLOP/s per chip by device kind (dense, published).
 _PEAK_BF16 = (
     ("v6", 918e12),     # Trillium
     ("v5p", 459e12),
@@ -480,14 +480,24 @@ _PEAK_BF16 = (
 )
 
 
-def peak_flops_per_chip() -> float:
+def peak_flops_per_chip() -> Optional[float]:
+    """Published bf16 peak of the first device, or None on the CPU (no
+    accelerator peak exists, so MFU/HFU are None there). An accelerator
+    whose ``device_kind`` is not in the table raises: a utilization
+    against a guessed peak is not a measurement."""
     import jax
 
-    kind = jax.devices()[0].device_kind.lower()
+    dev = jax.devices()[0]
+    if dev.platform == "cpu":
+        return None
+    kind = dev.device_kind.lower()
     for tag, peak in _PEAK_BF16:
         if tag in kind:
             return peak
-    return 197e12  # unknown kind: assume v5e-class
+    raise ValueError(
+        f"no published bf16 peak for device_kind {dev.device_kind!r} "
+        f"(platform {dev.platform!r}); add it to obs.telemetry._PEAK_BF16 "
+        f"with its source")
 
 
 def device_memory_peaks() -> Dict[str, Dict[str, int]]:
@@ -563,9 +573,10 @@ class StepReport:
                                                     chunks)
             peak = peak_flops if peak_flops is not None \
                 else peak_flops_per_chip()
-            per_chip = tps / max(n_stages, 1)
-            mfu = (req_tok * per_chip) / peak
-            hfu = (hw_tok * per_chip) / peak
+            if peak is not None:
+                per_chip = tps / max(n_stages, 1)
+                mfu = (req_tok * per_chip) / peak
+                hfu = (hw_tok * per_chip) / peak
         return cls(step=step, wall_sec=wall_sec, tokens=tokens,
                    n_stages=n_stages, chunks=chunks, checkpoint=checkpoint,
                    schedule=schedule, loss=loss, tokens_per_sec=tps,

@@ -279,27 +279,34 @@ class MultiHeadAttention(Module):
         k = proj(params["wk"], params["bk"])
         v = proj(params["wv"], params["bv"])
         dk = ctx.fold(1).key if ctx.key is not None else None
-        # Flash (Pallas) path when no attention-weight dropout is active and
-        # the tiling covers the sequence; the XLA path otherwise. The choice
-        # is static at trace time.
-        # Flash handles attention-weight dropout only when compiled on TPU
-        # (the kernel's hardware PRNG regenerates masks in backward);
-        # interpret mode and unsupported tilings use the XLA path.
+        # The choice is static at trace time. Flash handles attention-weight
+        # dropout only when compiled on TPU (the kernel's hardware PRNG
+        # regenerates masks in backward; interpret mode has no PRNG).
         on_tpu = jax.default_backend() == "tpu"
         dropout_active = self.dropout > 0.0 and ctx.train and dk is not None
-        # auto: the measured-crossover heuristic lives in flash_auto_ok;
-        # explicit impl="flash" bypasses it (tiling support still required).
         if self.impl == "flash":
+            # asked for by name: the kernel runs or the call fails —
+            # only "auto" may choose the XLA path
             from .pallas_attention import supports
-            use_flash = (not dropout_active or on_tpu) and supports(s)
+            if not supports(s):
+                raise ValueError(
+                    f"impl='flash' cannot tile seq_len {s} (needs a "
+                    f"multiple of 8 that one 128-row block covers or "
+                    f"divides); use impl='auto' or 'xla'")
+            if dropout_active and not on_tpu:
+                raise ValueError(
+                    "impl='flash' with attention dropout needs the TPU "
+                    f"PRNG, but the backend is {jax.default_backend()!r}; "
+                    "use impl='auto' or 'xla'")
+            use_flash = True
         elif self.impl == "auto":
+            # the measured-crossover heuristic lives in flash_auto_ok
             use_flash = ((not dropout_active or on_tpu)
                          and flash_auto_ok(s))
         else:
             use_flash = False
         if use_flash:
             from .pallas_attention import flash_attention
-        if use_flash:
             o = flash_attention(
                 q, k, v, causal=self.causal,
                 dropout_rate=self.dropout if dropout_active else 0.0,

@@ -38,6 +38,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ..obs.telemetry import get_registry
+
 __all__ = ["flash_attention", "supports"]
 
 NEG_INF = float("-inf")
@@ -338,6 +340,10 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
             f"use ops.layers.dot_product_attention")
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
+    # trace-time counters: a run that must not interpret (chip_smoke.py)
+    # asserts the first stays 0
+    get_registry().counter("ops.flash_attention.interpreted" if interpret
+                           else "ops.flash_attention.compiled").inc()
     if not 0.0 <= dropout_rate < 1.0:
         raise ValueError(f"dropout_rate must be in [0, 1), got {dropout_rate}")
     if dropout_rate > 0.0:

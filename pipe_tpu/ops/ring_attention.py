@@ -29,8 +29,6 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from ..utils.compat import axis_size
-
 __all__ = ["ring_attention", "blockwise_attention_reference"]
 
 
@@ -72,7 +70,7 @@ def ring_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     in ``q``'s dtype. Differentiable (AD reverses the ring automatically —
     the same property the pipeline's backward relies on, SURVEY §7).
     """
-    n = axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     idx = jax.lax.axis_index(axis_name)
     b, sq, h, d = q.shape
     scale = scale if scale is not None else 1.0 / math.sqrt(d)

@@ -33,8 +33,6 @@ from typing import Callable, Optional
 import jax
 import jax.numpy as jnp
 
-from ..utils.compat import axis_size
-
 __all__ = ["ulysses_attention"]
 
 
@@ -58,7 +56,7 @@ def ulysses_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     Returns the attention output with the INPUT sharding
     (``[rows, seq_local, heads, head_dim]``).
     """
-    c = axis_size(axis_name)
+    c = jax.lax.axis_size(axis_name)
     heads = q.shape[2]
     if heads % c:
         raise ValueError(

@@ -17,7 +17,6 @@ import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
 from ..ops.ring_attention import ring_attention
-from ..utils.compat import shard_map
 
 __all__ = ["CONTEXT_AXIS", "make_context_mesh", "context_parallel_attention"]
 
@@ -54,7 +53,7 @@ def context_parallel_attention(mesh: Mesh, q: jax.Array, k: jax.Array,
     else:
         raise ValueError(f"impl must be ring|ulysses, got {impl!r}")
     spec = P(None, axis, None, None)
-    fn = shard_map(
+    fn = jax.shard_map(
         body, mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
         check_vma=False)
     return fn(q, k, v)

@@ -65,7 +65,6 @@ from ..core.partition import StageCtx
 from ..core.remat import apply_remat, checkpoint_stop, validate_mode
 from .mesh import DATA_AXIS, STAGE_AXIS
 from ..utils.rng import make_key
-from ..utils.compat import shard_map
 
 __all__ = ["HeteroSpmdPipeline"]
 
@@ -287,7 +286,7 @@ class HeteroSpmdPipeline:
                            else P(STAGE_AXIS)), sp_)
                 for sp_ in stage_specs)
             for stage_specs in stat_specs)
-        run = shard_map(
+        run = jax.shard_map(
             functools.partial(
                 self._device_program, m=m, plans=plans,
                 capacities=capacities, lane_specs=lane_specs,

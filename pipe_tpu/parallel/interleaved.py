@@ -40,7 +40,6 @@ from ..core.partition import StageCtx
 from ..core.remat import checkpoint_stop, validate_mode
 from .mesh import DATA_AXIS, STAGE_AXIS
 from ..utils.rng import make_key
-from ..utils.compat import shard_map
 
 __all__ = ["InterleavedSpmdPipeline", "stack_interleaved_params",
            "unstack_interleaved_params"]
@@ -175,7 +174,7 @@ class InterleavedSpmdPipeline:
                           + [None] * (len(s.shape) - 1))),
             out_spec)
 
-        run = shard_map(
+        run = jax.shard_map(
             functools.partial(self._device_program, m=m, stop=stop,
                               train=train),
             mesh=self.mesh, in_specs=in_specs, out_specs=out_specs,

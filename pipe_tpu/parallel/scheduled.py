@@ -78,11 +78,11 @@ import dataclasses
 import functools
 import warnings
 from typing import Any, Callable, Optional
-from ..utils.compat import shard_map
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.extend import core as jex_core
 from jax.sharding import Mesh, PartitionSpec as P
 
 from ..core.partition import StageCtx
@@ -353,12 +353,13 @@ class ScheduledPipeline:
     # survives. None = auto: ON for d > 1 on accelerator backends when the
     # compiler accepts the table, OFF on CPU meshes (explicit True forces
     # it anywhere, which is how the cpu8 probes run it). Tables the
-    # compiler rejects fall back loudly to the interpreted executor
-    # (warnings.warn + the scheduled.phase.rejected counter). Bitwise
-    # parity with the interpreted executor: the aligner preserves each
-    # (stage, op-code) stream's order — F ops feed loss/stats and B/W ops
-    # feed the grad accumulators, disjoint state — so every accumulation
-    # order is preserved even though F/B interleaving changes.
+    # compiler rejects fall back loudly to the interpreted executor, under
+    # auto as under True (warnings.warn + the scheduled.phase.rejected
+    # counter). Bitwise parity with the interpreted executor: the aligner
+    # preserves each (stage, op-code) stream's order — F ops feed
+    # loss/stats and B/W ops feed the grad accumulators, disjoint state —
+    # so every accumulation order is preserved even though F/B
+    # interleaving changes.
     phase_compile: Optional[bool] = None
 
     def __post_init__(self):
@@ -544,9 +545,10 @@ class ScheduledPipeline:
         return self.mesh.devices.flat[0].platform != "cpu"
 
     def _phase_verdict(self, m):
-        """Phase-compile this pipeline's table at m (cached per m). On
-        rejection: bump the fallback counter and — when the user explicitly
-        asked for phase compilation — warn once, naming the reason."""
+        """Phase-compile this pipeline's table at m (cached per m). Only
+        reached when phase compilation is on (asked for, or auto on an
+        accelerator), so a rejection is never silent: bump the fallback
+        counter and warn once, naming the reason."""
         if m not in self._phase_cache:
             tables = self.schedule.op_tables(m, self.n_stages)
             op0, mb0 = tables[0], tables[1]
@@ -557,12 +559,11 @@ class ScheduledPipeline:
                 get_registry().counter("scheduled.phase.compiled").inc()
             else:
                 get_registry().counter("scheduled.phase.rejected").inc()
-                if self.phase_compile:
-                    warnings.warn(
-                        f"phase_compile=True but the phase compiler "
-                        f"rejected the {self.schedule.name!r} op table at "
-                        f"m={m} ({verdict.reason}); falling back to the "
-                        f"interpreted table executor", stacklevel=3)
+                warnings.warn(
+                    f"phase_compile={self.phase_compile} but the phase "
+                    f"compiler rejected the {self.schedule.name!r} op "
+                    f"table at m={m} ({verdict.reason}); falling back to "
+                    f"the interpreted table executor", stacklevel=3)
             self._phase_cache[m] = verdict
         return self._phase_cache[m]
 
@@ -660,7 +661,7 @@ class ScheduledPipeline:
         if self.stat_spec is not None:    # stats: psum'd in-program
             out_specs = out_specs + (
                 jax.tree_util.tree_map(lambda _: P(), self.stat_spec),)
-        run = shard_map(
+        run = jax.shard_map(
             functools.partial(self._device_program, m=m),
             mesh=self.mesh, in_specs=in_specs, out_specs=out_specs,
             check_vma=False)
@@ -741,7 +742,7 @@ class ScheduledPipeline:
         if self.stat_spec is not None:   # stats: psum'd in-program
             out_specs = (out_specs, jax.tree_util.tree_map(
                 lambda _: P(), self.stat_spec))
-        run = shard_map(
+        run = jax.shard_map(
             functools.partial(self._device_forward, m=m, train=train,
                               out_fn=out_fn),
             mesh=self.mesh, in_specs=in_specs, out_specs=out_specs,
@@ -1639,7 +1640,7 @@ class ScheduledPipeline:
                 pos += len(leaves_k)
             split_res_pt = {}
             for idx, ov in enumerate(jpr.jaxpr.outvars):
-                hit = (None if isinstance(ov, jax.core.Literal)
+                hit = (None if isinstance(ov, jex_core.Literal)
                        else src_of.get(ov))
                 if hit is not None:
                     sp_ = res_specs[idx]

@@ -161,16 +161,15 @@ class BuddyStore:
             from jax.sharding import PartitionSpec as P
 
             from ..parallel.mesh import STAGE_AXIS
-            from ..utils.compat import shard_map
 
             perm = [(i, (i + 1) % self.n) for i in range(self.n)]
 
             def send(xs):
                 return [jax.lax.ppermute(x, STAGE_AXIS, perm) for x in xs]
 
-            self._ring = jax.jit(shard_map(
+            self._ring = jax.jit(jax.shard_map(
                 send, mesh=self.mesh, in_specs=P(STAGE_AXIS),
-                out_specs=P(STAGE_AXIS)))
+                out_specs=P(STAGE_AXIS), check_vma=False))
         return self._ring
 
     # -- capture / restore ---------------------------------------------------

@@ -1557,6 +1557,9 @@ class ServeEngine:
         self._responses = {}
         self._tick_index = 0
         self._decode_errors = 0
+        # the newest contained backend exception (prefill or decode):
+        # containment keeps serving, callers that must fail read this
+        self.last_error: Optional[Exception] = None
         self._miss_ewma = 0.0
         self._draining = False
         # observed per-chunk decode latency (EWMA) — sizes the resident
@@ -1761,6 +1764,7 @@ class ServeEngine:
         """Admission failed in the backend (prefill raised): the request
         dies ``status="error"`` — the slot was returned to the free list
         and every other request keeps serving."""
+        self.last_error = exc
         get_registry().counter("resilience.slot_errors").inc()
         self.events.event("resilience", action="slot_error",
                           request=req.id, where="prefill",
@@ -2062,6 +2066,7 @@ class ServeEngine:
     def _on_decode_error(self, reg, exc: Exception, tick_idx: int,
                          finished: List[Response]) -> None:
         self._decode_errors += 1
+        self.last_error = exc
         reg.counter("resilience.decode_errors").inc()
         self.events.event("resilience", action="decode_error",
                           tick=tick_idx, consecutive=self._decode_errors,

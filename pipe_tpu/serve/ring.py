@@ -59,7 +59,6 @@ from ..inference.generate import (GenerationConfig, head_logits,
 from ..inference.quant import QuantLeaf, dequant_tree
 from ..obs.telemetry import get_registry
 from ..parallel.mesh import STAGE_AXIS
-from ..utils.compat import shard_map
 from .buckets import BucketSpec
 from .kvpool import (KvPool, copy_block, flat_row_index,
                      gather_block_cache, scatter_block_rows)
@@ -1100,7 +1099,7 @@ class RingSlotBackend:
                         S, S, S, S, P(), P(), P(), P())
             out_specs = (cache_spec, S, S, S, S, P())
             fn = self._decode_fn
-        return jax.jit(shard_map(fn, mesh=self.mesh, in_specs=in_specs,
+        return jax.jit(jax.shard_map(fn, mesh=self.mesh, in_specs=in_specs,
                                  out_specs=out_specs, check_vma=False))
 
     def prefill(self, slot: int, prompt: Sequence[int], seed: int,

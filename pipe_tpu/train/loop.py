@@ -59,8 +59,8 @@ class TrainerConfig:
     schedule: str = "gpipe"    # gpipe | 1f1b | zb-h1 | interleaved
                                # | interleaved-1f1b
     # Adam first-moment storage dtype: 'bfloat16' halves the m-moment HBM
-    # traffic — measured ~4% step-time win at the 520M bench scale
-    # (MFU_SWEEP_r04.jsonl, docs/mfu_roofline.md); None keeps f32.
+    # traffic (docs/mfu_roofline.md; its step-time effect is not measured
+    # on the current installation); None keeps f32.
     mu_dtype: Optional[str] = None
     interleave: int = 2        # virtual stages per device (interleaved only)
     # Directory for TensorBoard scalar event files (SURVEY §5 "stdout +

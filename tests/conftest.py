@@ -4,9 +4,9 @@ This is the TPU-build analogue of the reference's CPU-sentinel-stream trick
 (``AbstractStream`` admitting a CPU fallback, reference pipe.py:22,
 pipeline.py:22): every layer — scheduler, SPMD pipeline, ppermute rings,
 checkpointing — runs on plain CPU with a simulated 8-device mesh, so the full
-multi-"device" suite needs no TPUs and no cluster. See
-``pipe_tpu.utils.platform`` for why this is done via jax.config rather than
-env vars on this machine.
+multi-"device" suite needs no TPUs and no cluster. The suite forces the
+CPU whatever the machine holds: it says nothing about the chip, which
+``chip_smoke.py`` checks.
 """
 
 import os
@@ -19,6 +19,13 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 from pipe_tpu.utils.platform import force_cpu_platform
 
 force_cpu_platform(num_devices=8)
+
+# Hermetic: entry points the tests call in-process point JAX's persistent
+# compile cache into the checkout (utils.platform.configure_compile_cache);
+# the suite neither reads nor writes one.
+import jax  # noqa: E402
+
+jax.config.update("jax_enable_compilation_cache", False)
 
 
 # ---------------------------------------------------------------------------

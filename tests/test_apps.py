@@ -173,3 +173,28 @@ def test_generate_cli_context_shards(capsys):
     # indivisible prompt rejected cleanly
     assert generate.main(["--tiny", "--prompt", "1,2,3",
                           "--context-shards", "4"]) == 2
+
+
+def test_serve_cli_exit_code_reports_engine_errors(monkeypatch, capsys):
+    """The engine contains a backend failure to the requests it hit; the
+    driver's exit code still says a request ended in an engine error."""
+    import functools
+    import json
+
+    import pipe_tpu.serve as serve_pkg
+    from pipe_tpu.apps import serve
+    from pipe_tpu.resilience import ChaosPlan, Fault
+
+    # every prefill admitted in tick 0 raises inside the engine
+    monkeypatch.setattr(serve_pkg, "ServeEngine", functools.partial(
+        serve_pkg.ServeEngine,
+        chaos=ChaosPlan([Fault("backend_raise", step=0)])))
+    rc = serve.main(["--tiny", "--requests", "2", "--max-new", "2",
+                     "--slots", "2"])
+    out, err = capsys.readouterr()
+    assert rc == 1
+    lines = [json.loads(ln) for ln in out.strip().splitlines()]
+    assert [ln["status"] for ln in lines[:-1]] == ["error", "error"]
+    assert lines[-1]["summary"]["errors"] == 2
+    assert "2 request(s) ended in an engine error" in err
+    assert "ChaosError" in err

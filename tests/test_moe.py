@@ -13,7 +13,6 @@ from pipe_tpu.core.partition import StageCtx
 from pipe_tpu.ops.moe import moe_capacity, moe_ffn_apply, moe_ffn_init, \
     moe_ffn_specs
 from pipe_tpu.parallel.mesh import MODEL_AXIS, make_mesh
-from pipe_tpu.utils.compat import shard_map
 
 D, FF, E, ROWS, SEQ = 8, 16, 4, 2, 8
 
@@ -38,7 +37,7 @@ def test_moe_ffn_matches_unsharded(k):
         return jax.value_and_grad(
             lambda p: loss_of(p, h, MODEL_AXIS))(p)
 
-    run = shard_map(device_program, mesh=mesh,
+    run = jax.shard_map(device_program, mesh=mesh,
                         in_specs=(specs, P()),
                         out_specs=(P(), specs), check_vma=False)
     l_ep, g_ep = jax.jit(run)(params, h)

@@ -76,3 +76,35 @@ def test_empty_and_whitespace_only():
     c = NativeCorpus.from_text("\n   \n\t\n")
     assert c.num_tokens == 0
     assert c.vocab_size == 1  # just <unk>
+
+
+def test_stale_library_is_rebuilt_by_source_hash(tmp_path):
+    """Staleness is the hash of the source stored beside the library, not
+    mtimes: a copied tree can carry a library NEWER than a source it was
+    not built from."""
+    import ctypes
+    import os
+
+    from pipe_tpu.data.native import _build_lib
+
+    src, lib = str(tmp_path / "f.cpp"), str(tmp_path / "libf.so")
+
+    def value():
+        # load under a fresh name: dlopen caches by path
+        copy = str(tmp_path / f"load{len(os.listdir(tmp_path))}.so")
+        with open(lib, "rb") as a, open(copy, "wb") as b:
+            b.write(a.read())
+        return ctypes.CDLL(copy).f()
+
+    with open(src, "w") as f:
+        f.write('extern "C" int f() { return 1; }\n')
+    assert _build_lib(src, lib) == lib and value() == 1
+    built = os.path.getmtime(lib)
+    assert _build_lib(src, lib) == lib            # fresh: not rebuilt
+    assert os.path.getmtime(lib) == built
+
+    with open(src, "w") as f:
+        f.write('extern "C" int f() { return 2; }\n')
+    old = built - 3600
+    os.utime(src, (old, old))                     # source looks OLDER
+    assert _build_lib(src, lib) == lib and value() == 2

@@ -101,17 +101,25 @@ def test_dropout_requires_tpu_in_interpret_mode():
         flash_attention(q, k, v, dropout_rate=0.2, interpret=False)
 
 
-def test_mha_dropout_routes_xla_on_cpu():
-    """On CPU, a dropout-bearing train step must use the XLA path (flash
-    interpret mode has no PRNG) — this exercises the routing, not numerics."""
+def test_mha_flash_by_name_runs_or_raises():
+    """impl="flash" never takes the XLA path quietly: with dropout on the
+    CPU (interpret mode has no PRNG) or a sequence the tiling cannot cover
+    it raises, while impl="auto" may still choose XLA."""
     from pipe_tpu.core.partition import StageCtx
     from pipe_tpu.ops.layers import MultiHeadAttention
     x = jax.random.normal(jax.random.key(0), (2, 32, 64))
-    mha = MultiHeadAttention(64, 4, dropout=0.5, impl="flash")
-    p = mha.init(jax.random.key(1), x)
     ctx = StageCtx(key=jax.random.key(2), train=True)
-    out = mha.apply(p, x, ctx=ctx)  # would raise if routed to flash interpret
-    assert np.isfinite(np.asarray(out)).all()
+    flash = MultiHeadAttention(64, 4, dropout=0.5, impl="flash")
+    p = flash.init(jax.random.key(1), x)
+    with pytest.raises(ValueError, match="needs the TPU PRNG"):
+        flash.apply(p, x, ctx=ctx)
+    with pytest.raises(ValueError, match="cannot tile seq_len 20"):
+        flash.apply(p, x[:, :20], ctx=StageCtx(train=False))
+    # no dropout active: the kernel runs (interpreted here)
+    assert np.isfinite(np.asarray(
+        flash.apply(p, x, ctx=StageCtx(train=False)))).all()
+    auto = MultiHeadAttention(64, 4, dropout=0.5, impl="auto")
+    assert np.isfinite(np.asarray(auto.apply(p, x, ctx=ctx))).all()
 
 
 @pytest.mark.parametrize("causal", [False, True])

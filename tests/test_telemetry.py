@@ -450,10 +450,31 @@ def test_trainer_emits_events_and_step_reports(tmp_path, registry):
             round(bubble_fraction(cfg.chunks, cfg.n_stages), 4))
         assert r["tokens"] == cfg.batch_size * cfg.bptt
         assert r["unit"] == "tokens/s/chip"
-        assert r["mfu"] is not None and 0 <= r["mfu"] <= 1
+        # the CPU has no published peak: no utilization is reported
+        assert r["mfu"] is None and r["hfu"] is None
     assert reports[0]["compile_inclusive"] is True
     assert reports[-1]["compile_inclusive"] is False
     # the same run feeds the process registry + a final snapshot record
     assert registry.counter("train.steps").value == 3
     snaps = [r for r in records if r["kind"] == "metrics"]
     assert snaps and snaps[-1]["metrics"]["train.steps"] == 3
+
+
+def test_peak_flops_known_cpu_and_unknown_accelerator(monkeypatch):
+    """A CPU has no peak (None); a known chip has its published one; an
+    accelerator that is not in the table raises — never a v5e by default."""
+    import types
+
+    from pipe_tpu.obs.telemetry import peak_flops_per_chip
+
+    assert peak_flops_per_chip() is None          # the suite runs on CPU
+
+    def fake(platform, kind):
+        return lambda: [types.SimpleNamespace(platform=platform,
+                                              device_kind=kind)]
+
+    monkeypatch.setattr(jax, "devices", fake("tpu", "TPU v5 lite"))
+    assert peak_flops_per_chip() == 197e12
+    monkeypatch.setattr(jax, "devices", fake("tpu", "TPU v9 hypothetical"))
+    with pytest.raises(ValueError, match="no published bf16 peak"):
+        peak_flops_per_chip()

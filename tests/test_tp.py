@@ -21,7 +21,6 @@ from pipe_tpu.ops.tp_layers import (tp_block_apply, tp_block_init,
 from pipe_tpu.parallel.mesh import MODEL_AXIS, make_mesh
 from pipe_tpu.parallel.scheduled import ScheduledPipeline
 from pipe_tpu.parallel.spmd import stack_stage_params
-from pipe_tpu.utils.compat import shard_map
 
 D, HEADS, FF, SEQ, ROWS = 16, 4, 32, 8, 2
 
@@ -60,7 +59,7 @@ def test_tp_block_matches_unsharded():
             return jnp.sum(out ** 2)
         return jax.value_and_grad(loss)(p)
 
-    run = shard_map(device_program, mesh=mesh,
+    run = jax.shard_map(device_program, mesh=mesh,
                         in_specs=(specs, P()),
                         out_specs=(P(), grad_specs), check_vma=False)
     l_tp, g_tp = jax.jit(run)(params, h)

@@ -115,7 +115,7 @@ def test_moment_sharding_fallback_replicates_indivisible():
 def test_mu_dtype_bf16_composes_with_zero():
     """TrainerConfig(mu_dtype='bfloat16'): the Adam first-moment leaves
     are actually stored bf16, the step runs, and it composes with ZeRO-1
-    moment sharding (MFU_SWEEP_r04 knob)."""
+    moment sharding."""
     import dataclasses
     import math
 

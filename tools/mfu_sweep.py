@@ -1,9 +1,12 @@
-"""MFU sweep on the real chip: checkpoint x remat_policy on the bench
-workload (520M tutorial config, chunks=4, d=1 static 1f1b program).
+"""MFU sweep on the chip: checkpoint x remat_policy on the bench
+workload (520M tutorial config, chunks=4, d=1 static 1f1b program). No
+chip is an error (``bench.tutorial_config``).
 
 ``python tools/mfu_sweep.py [policy ...]`` — times ONLY the pipelined
 training step per configuration (no baselines/probes), printing one JSON
-line per config. Used to pick bench.py's default policy (VERDICT r2 #6).
+line per config; a config that fails (OOM included) prints its error and
+makes the exit code non-zero. Used to pick bench.py's default policy
+(VERDICT r2 #6).
 """
 
 from __future__ import annotations
@@ -20,17 +23,18 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 
 from bench import (CHUNKS, BATCH, make_step, peak_flops_per_chip,
-                   time_steps, train_flops_per_token, tutorial_config,
-                   with_retries)
+                   time_steps, train_flops_per_token, tutorial_config)
 from pipe_tpu.core import microbatch as mb
 from pipe_tpu.models.transformer_lm import PipelinedLM
 from pipe_tpu.parallel.mesh import make_mesh
 from pipe_tpu.parallel.scheduled import ScheduledPipeline
 from pipe_tpu.parallel.spmd import stack_stage_params
+from pipe_tpu.utils.platform import configure_compile_cache
 from pipe_tpu.utils.rng import make_key
 
 
-def main(configs):
+def main(configs) -> int:
+    configure_compile_cache()
     platform = jax.default_backend()
     cfg = tutorial_config(platform)
     mesh = make_mesh(1, 1, devices=jax.devices()[:1])
@@ -45,6 +49,7 @@ def main(configs):
     key = make_key(2)
     peak = peak_flops_per_chip()
     tokens_per_step = BATCH * cfg.seq_len
+    failed = 0
 
     for checkpoint, policy_name in configs:
         policy = (getattr(jax.checkpoint_policies, policy_name)
@@ -55,21 +60,19 @@ def main(configs):
             schedule="1f1b", remat_policy=policy)
         step = make_step(model, sched, tx)
 
-        def run():
-            p = (stack_stage_params(sp),
-                 jax.tree_util.tree_map(lambda a: jnp.array(a, copy=True),
-                                        prep),
-                 jax.tree_util.tree_map(lambda a: jnp.array(a, copy=True),
-                                        postp))
-            return time_steps(step, p, tx.init(p), (x, w, key))
-
+        p = (stack_stage_params(sp),
+             jax.tree_util.tree_map(lambda a: jnp.array(a, copy=True), prep),
+             jax.tree_util.tree_map(lambda a: jnp.array(a, copy=True), postp))
         try:
-            sec, _ = with_retries(run)
-        except Exception as e:
+            sec, _ = time_steps(step, p, tx.init(p), (x, w, key))
+        except Exception as e:  # noqa: BLE001 — a sweep point can OOM
+            failed += 1
             print(json.dumps({"checkpoint": checkpoint,
                               "policy": policy_name,
-                              "error": str(e)[:300]}))
+                              "error": str(e)[:300]}), flush=True)
             continue
+        finally:
+            del p
         tps = tokens_per_step / sec
         # MFU's numerator is the required (no-recompute) FLOPs — checkpoint
         # mode and policy never change it
@@ -80,6 +83,7 @@ def main(configs):
             "tok_s_chip": round(tps, 1),
             "mfu": round(req * tps / peak, 4),
         }), flush=True)
+    return 1 if failed else 0
 
 
 if __name__ == "__main__":
@@ -92,4 +96,4 @@ if __name__ == "__main__":
                    ("except_last", "dots_with_no_batch_dims_saveable"),
                    ("except_last", "none"),
                    ("never", "none")]
-    main(configs)
+    sys.exit(main(configs))

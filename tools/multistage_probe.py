@@ -1,7 +1,6 @@
-"""Real-TPU multi-stage probe: interleaved v>1 table programs at d=1.
+"""On-chip multi-stage probe: interleaved v>1 table programs at d=1.
 
-The headline bench (`bench.py`) runs n_stages=1 on the one available chip;
-multi-stage wall-clock has otherwise only existed as cpu8 proxies. But
+On one chip the headline bench (`bench.py`) runs n_stages=1. But
 interleaved placements (v virtual stages per device) put a REAL multi-stage
 table program on the single chip: the 16-layer tutorial model factors into
 v virtual stage bodies, the `interleaved-1f1b` op tables sequence
@@ -12,8 +11,9 @@ program, so the measured delta IS the table machinery + stash traffic
 
 ``python tools/multistage_probe.py --quick [n_stages chunks]`` instead runs
 the cpu8 bubble probe with the schedule + transport (serialized vs packed
-overlapped ppermute) comparison — no TPU needed; this is the subprocess
-bench.py embeds as ``measured_bubble_multistage``.
+overlapped ppermute) comparison — a CPU drill; this is the child process
+bench.py embeds as ``measured_bubble_multistage``. Without ``--quick`` no
+chip is an error (``bench.tutorial_config``).
 
 ``python tools/multistage_probe.py [v ...]`` (default: 1 2 4) — one JSON
 line per variant:
@@ -25,8 +25,8 @@ line per variant:
 
 All variants: 520M tutorial config, chunks=4, checkpoint=except_last,
 remat_policy=dots_saveable, bf16-mu Adam — the bench defaults — so numbers
-land next to `BENCH_r{N}.json`'s headline row. Committed artifact:
-`MULTISTAGE_TPU_r05.jsonl`.
+land next to `bench.py`'s headline row. A variant that fails (the static
+unroll can exceed HBM) prints its error and makes the exit code non-zero.
 """
 
 from __future__ import annotations
@@ -50,14 +50,14 @@ import jax.numpy as jnp
 import optax
 
 from bench import (BATCH, CHUNKS, make_step, peak_flops_per_chip,
-                   time_steps, train_flops_per_token, tutorial_config,
-                   with_retries)
+                   time_steps, train_flops_per_token, tutorial_config)
 from pipe_tpu.core import microbatch as mb
 from pipe_tpu.core.schedule import InterleavedOneFOneBSchedule
 from pipe_tpu.models.transformer_lm import PipelinedLM
 from pipe_tpu.parallel.interleaved import stack_interleaved_params
 from pipe_tpu.parallel.mesh import make_mesh
 from pipe_tpu.parallel.scheduled import ScheduledPipeline
+from pipe_tpu.utils.platform import configure_compile_cache
 from pipe_tpu.utils.rng import make_key
 
 
@@ -84,15 +84,12 @@ def probe_variant(cfg, v: int, static_unroll, tx, tokens, targets):
     key = make_key(2)
     step = make_step(model, sched, tx)
 
-    def run():
-        stacked = (stack_interleaved_params(sp, 1),
-                   jax.tree_util.tree_map(lambda a: jnp.array(a, copy=True),
-                                          prep),
-                   jax.tree_util.tree_map(lambda a: jnp.array(a, copy=True),
-                                          postp))
-        return time_steps(step, stacked, tx.init(stacked), (x, w, key))
-
-    sec, loss = with_retries(run)
+    stacked = (stack_interleaved_params(sp, 1),
+               jax.tree_util.tree_map(lambda a: jnp.array(a, copy=True),
+                                      prep),
+               jax.tree_util.tree_map(lambda a: jnp.array(a, copy=True),
+                                      postp))
+    sec, loss = time_steps(step, stacked, tx.init(stacked), (x, w, key))
     tokens_per_step = BATCH * cfg.seq_len
     tps = tokens_per_step / sec
     req_tok, _ = train_flops_per_token(cfg, "never", CHUNKS)
@@ -111,7 +108,8 @@ def probe_variant(cfg, v: int, static_unroll, tx, tokens, targets):
     }
 
 
-def main(vs):
+def main(vs) -> int:
+    configure_compile_cache()
     platform = jax.default_backend()
     cfg = tutorial_config(platform)
     header = {
@@ -131,6 +129,7 @@ def main(vs):
     targets = jnp.roll(tokens, -1, axis=-1)
 
     anchor = None
+    failed = 0
     for v in vs:
         if cfg.n_layers % v:
             print(json.dumps({"v": v, "skipped":
@@ -142,6 +141,7 @@ def main(vs):
             try:
                 r = probe_variant(cfg, v, static, tx, tokens, targets)
             except Exception as e:       # static unroll can exceed HBM
+                failed += 1
                 r = {"v": v,
                      "program": "static" if static else "dynamic",
                      "failed": str(e)[:200]}
@@ -152,6 +152,7 @@ def main(vs):
             if anchor is not None and "sec_per_step" in r:
                 r["overhead_vs_v1"] = round(r["sec_per_step"] / anchor, 4)
             print(json.dumps(r), flush=True)
+    return 1 if failed else 0
 
 
 def quick_main(n_stages: int = 4, chunks: int = 8):
@@ -172,4 +173,4 @@ if __name__ == "__main__":
         quick_main(*pos[:2])
     else:
         args = [int(a) for a in sys.argv[1:]] or [1, 2, 4]
-        main(args)
+        sys.exit(main(args))
