@@ -17,10 +17,12 @@ import jax
 import jax.numpy as jnp
 
 from ..core.partition import StageCtx
+from ..obs.events import LOSS, scoped
 
 __all__ = ["per_row_ce", "PipelinedTransformer"]
 
 
+@scoped(LOSS)
 def per_row_ce(logits, targets, weights=None):
     """Per-row cross-entropy from logits (f32 accumulation).
 

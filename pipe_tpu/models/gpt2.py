@@ -30,6 +30,7 @@ import jax.numpy as jnp
 
 from ..core.partition import StageCtx
 from ..extras.skip import pop, skippable, stash
+from ..obs.events import EMBED, HEAD, scoped
 from ..ops.layers import (Dropout, Linear, LayerNorm, Module, PreLNBlock,
                           Sequential, spec)
 from .common import PipelinedTransformer, per_row_ce
@@ -74,6 +75,7 @@ class GPT2Embed(Module):
                 kp, (cfg.seq_len, cfg.d_model), jnp.float32),
         }
 
+    @scoped(EMBED)
     def apply(self, params, tokens, ctx: StageCtx = StageCtx()):
         s = tokens.shape[-1]
         h = jnp.take(params["wte"], tokens, axis=0) + params["wpe"][:s]
@@ -94,6 +96,7 @@ class GPT2Head(Module):
         h = spec(h)
         return {"ln_f": self.ln.init(kl, h), "proj": self.proj.init(kp, h)}
 
+    @scoped(HEAD)
     def apply(self, params, h, ctx: StageCtx = StageCtx()):
         h = self.ln.apply(params["ln_f"], h.astype(jnp.float32), ctx=ctx)
         return self.proj.apply(params["proj"], h, ctx=ctx)
@@ -152,6 +155,7 @@ class PipelinedGPT2(PipelinedTransformer):
         logits = self.head.apply(post_params["head"], h, ctx=ctx)
         return per_row_ce(logits, x_mb["targets"])
 
+    @scoped(EMBED)
     def embed_at(self, pre_params, tokens, pos):
         """Embed tokens occupying positions ``[pos, pos+q)`` — for
         incremental decoding (inference: no dropout)."""

@@ -28,6 +28,7 @@ import jax
 import jax.numpy as jnp
 
 from ..core.partition import StageCtx
+from ..obs.events import EMBED, LOSS, scoped
 from ..ops.layers import (Decoder, Embedding, PositionalEncoding, Sequential,
                           TransformerEncoderLayer)
 from .common import PipelinedTransformer, per_row_ce
@@ -62,6 +63,7 @@ class LMConfig:
             seq_len=16, dropout=0.0)
 
 
+@scoped(LOSS)
 def cross_entropy(logits: jax.Array, targets: jax.Array) -> jax.Array:
     """Mean token cross-entropy, float32 accumulation.
 
@@ -126,6 +128,7 @@ class PipelinedLM(PipelinedTransformer):
         h = self.posenc.apply({}, h, ctx=ctx.fold(1))
         return h.astype(self.cfg.compute_dtype)
 
+    @scoped(EMBED)
     def embed_at(self, pre_params, tokens, pos):
         """Embed tokens occupying positions ``[pos, pos+q)`` — pre_fn with
         a position offset, for incremental decoding (inference: no
@@ -135,6 +138,7 @@ class PipelinedLM(PipelinedTransformer):
             self.posenc.pe, pos, tokens.shape[-1], axis=0)
         return (h + pe).astype(self.cfg.compute_dtype)
 
+    @scoped(EMBED)
     def embed_tree(self, pre_params, tokens, pos, depths):
         """Embed draft-TREE chunk rows: row r of ``tokens [b, Q]`` is a
         tree node at logical position ``pos + depths[r]`` (the root sits

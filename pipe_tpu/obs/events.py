@@ -1,12 +1,31 @@
-"""Structured JSONL event log with nested spans.
+"""The one vocabulary of spans and scopes, and the JSONL event log.
 
-The host-side counterpart of the trace scopes: where
-``meters.stage_scope`` names *device* work for the profiler
-(``chunk{i}-stage{j}`` in XLA op names), :class:`EventLog` records *host*
-structure — steps, compiles, evaluation, serving calls, and (on the
-emulator, which runs tasks in Python) per-stage/per-micro-batch task
-spans — as one JSON object per line, cheap enough to leave on in
-production loops.
+Everything the program says about where its time goes is declared here
+and opened where the work happens:
+
+* **host spans** (:data:`SPAN_KINDS`): :meth:`EventLog.span` /
+  :meth:`NullEventLog.span` (code that holds an event log) and the module
+  function :func:`span` (code that holds none, such as the serve slot
+  backends) each enter a ``jax.profiler.TraceAnnotation`` of the span's
+  kind, with the attributes as the event's stats. A call site makes one
+  call; the span lands in the JSONL log when there is one and in the
+  profiler's capture whenever a session is open, under the same name and
+  nesting, on the device trace's clock. With no session open the
+  annotation is a no-op of well under a microsecond: tracing is "on"
+  exactly while somebody profiles (``jax.profiler.start_trace``,
+  ``TrainerConfig.profile_every``, the benchmark's ``--trace 1``). There
+  is no switch.
+* **device scopes** (:data:`DEVICE_SCOPES`): :func:`device_scope` is
+  ``jax.named_scope`` over a fixed set of names, so every device operation
+  carries in its ``op_name`` metadata what it is for. Scopes are metadata
+  only: the compiled program is the same with them as without.
+  :func:`stage_scope` (``chunk{i}-stage{j}``) is the pipeline
+  executors' scope of the same kind.
+
+:class:`EventLog` records *host* structure — steps, compiles, evaluation,
+serving calls, and (on the emulator, which runs tasks in Python)
+per-stage/per-micro-batch task spans — as one JSON object per line, cheap
+enough to leave on in production loops.
 
 Record schema (one dict per line)::
 
@@ -28,20 +47,25 @@ returns them in file order and tests reconstruct the tree from
 
 ``NULL_EVENT_LOG`` is the disabled sink — same API, no file, no clock
 reads beyond the context-manager protocol — so call sites never branch.
+Its spans still reach the profiler, as :class:`EventLog`'s do.
 """
 
 from __future__ import annotations
 
 import contextlib
+import functools
 import json
 import os
 import threading
 import time
 from typing import Any, Dict, IO, List, Optional
 
+import jax
+
 __all__ = ["EventLog", "NullEventLog", "NULL_EVENT_LOG", "SPAN_KINDS",
            "STEP", "STAGE", "MICROBATCH", "COMM", "RECOMPUTE", "REQUEST",
-           "RECOVERY"]
+           "RECOVERY", "DEVICE_SCOPES", "REMAT_SCOPE", "device_scope",
+           "scoped", "stage_scope", "span", "SpanHandle"]
 
 STEP = "step"
 STAGE = "stage"
@@ -56,7 +80,104 @@ REQUEST = "request"
 # (stage_lost, replan, buddy_restore) — so a post-mortem can replay the
 # escalation from the event log alone
 RECOVERY = "recovery"
-SPAN_KINDS = (STEP, STAGE, MICROBATCH, COMM, RECOMPUTE, REQUEST)
+# train loop (train/loop.py train_epoch), inside STEP: what the host does
+# for a step besides the call; the call of the step program (enqueue, not
+# step time); each blocking read of a loss
+TRAIN_BATCH = "train.batch"
+TRAIN_DISPATCH = "train.dispatch"
+TRAIN_SYNC = "train.sync"
+# serve engine (serve/engine.py): ServeEngine.tick and its phases ...
+SERVE_TICK = "serve.tick"
+SERVE_REAP = "serve.reap"
+SERVE_ADMIT = "serve.admit"
+SERVE_DECODE = "serve.decode"
+SERVE_RETIRE = "serve.retire"
+# ... and the slot backend's calls under them: a prefill with the blocking
+# read of its first token; the dispatch of a decode or resident program,
+# the host's wait for it (the chunk count, then the token buffer), and a
+# zero-length record of the counts known only afterwards
+SERVE_PREFILL = "serve.prefill"
+SERVE_PREFILL_SYNC = "serve.prefill.sync"
+SERVE_DECODE_LAUNCH = "serve.decode.launch"
+SERVE_DECODE_SYNC = "serve.decode.sync"
+SERVE_DECODE_DONE = "serve.decode.done"
+SPAN_KINDS = (STEP, STAGE, MICROBATCH, COMM, RECOMPUTE, REQUEST,
+              TRAIN_BATCH, TRAIN_DISPATCH, TRAIN_SYNC,
+              SERVE_TICK, SERVE_REAP, SERVE_ADMIT, SERVE_DECODE,
+              SERVE_RETIRE, SERVE_PREFILL, SERVE_PREFILL_SYNC,
+              SERVE_DECODE_LAUNCH, SERVE_DECODE_SYNC, SERVE_DECODE_DONE)
+
+# Device scopes: what a device operation is for, readable from a capture
+# alone (the ``tf_op`` stat of a TPU op event is its op_name). A reader
+# takes the innermost of these names on an op's path.
+EMBED = "embed"
+ATTENTION = "attention"
+FFN = "ffn"
+HEAD = "head"
+LOSS = "loss"
+OPTIMIZER = "optimizer"
+KV_CACHE = "kv_cache"
+DEVICE_SCOPES = (EMBED, ATTENTION, FFN, HEAD, LOSS, OPTIMIZER, KV_CACHE)
+# Not a layer but a mark that cuts across them: a forward that runs again
+# for its backward. ``jax.checkpoint`` writes this name itself; the
+# scheduled executor's manual re-forward opens a scope of the same name.
+REMAT_SCOPE = "rematted_computation"
+
+
+def device_scope(name: str):
+    """``jax.named_scope`` for a name of :data:`DEVICE_SCOPES` (or
+    :data:`REMAT_SCOPE`): metadata on the ops traced inside, nothing at
+    run time."""
+    if name not in DEVICE_SCOPES and name != REMAT_SCOPE:
+        raise ValueError(f"{name!r} is not one of {DEVICE_SCOPES}")
+    return jax.named_scope(name)
+
+
+def scoped(name: str):
+    """Decorator: the function's whole body under :func:`device_scope`
+    ``(name)``, entered at each call (so at each trace)."""
+    def wrap(fn):
+        @functools.wraps(fn)
+        def inner(*args, **kwargs):
+            with device_scope(name):
+                return fn(*args, **kwargs)
+        return inner
+    return wrap
+
+
+def stage_scope(microbatch: Optional[int], stage: int):
+    """The pipeline executors' device scope: ``chunk{i}-stage{j}`` (the
+    reference's ``record_function("chunk%d-part%d")``), which
+    ``meters.stage_timeline_from_trace`` buckets by; ``stage{j}`` where the
+    micro-batch index is a traced value (``microbatch=None``)."""
+    if microbatch is None:
+        return jax.named_scope(f"stage{stage}")
+    return jax.named_scope(f"chunk{microbatch}-stage{stage}")
+
+
+def span(kind: str, **attrs: Any):
+    """A host span for code that holds no event log: a profiler
+    annotation named ``kind`` whose stats are ``attrs``. Nothing is
+    recorded unless a profiler session is open. Entered, it gives the
+    annotation, whose ``set_metadata(**attrs)`` adds what is known only
+    at the span's end."""
+    return jax.profiler.TraceAnnotation(kind, **attrs)
+
+
+class SpanHandle(int):
+    """What an event log's span gives when entered: the span's id (an
+    ``int``, as before) that also takes attributes known only at the
+    span's end, as the profiler's annotation does. They reach both the
+    log's record and the capture."""
+
+    def __new__(cls, span_id: int, attrs: Dict[str, Any], annotation):
+        self = super().__new__(cls, span_id)
+        self._attrs, self._annotation = attrs, annotation
+        return self
+
+    def set_metadata(self, **attrs: Any) -> None:
+        self._attrs.update(attrs)
+        self._annotation.set_metadata(**attrs)
 
 
 class EventLog:
@@ -146,7 +267,8 @@ class EventLog:
         stack.append(span_id)
         t0 = time.perf_counter()
         try:
-            yield span_id
+            with span(kind, **attrs) as annotation:
+                yield SpanHandle(span_id, attrs, annotation)
         finally:
             dur = time.perf_counter() - t0
             stack.pop()
@@ -218,7 +340,7 @@ class NullEventLog:
         pass
 
     def span(self, kind: str, **attrs: Any):
-        return contextlib.nullcontext(0)
+        return span(kind, **attrs)
 
     def step_report(self, report) -> None:
         pass

@@ -52,7 +52,7 @@ import time
 from collections import deque
 from typing import Any, Dict, List, Optional, Tuple
 
-from .events import EventLog
+from .events import EventLog, SpanHandle, span as profiler_span
 from .telemetry import Counter, EwmaTimer, Gauge, Histogram, \
     MetricsRegistry, get_registry, labelled
 
@@ -114,7 +114,8 @@ class TraceBuffer:
         stack.append(span_id)
         t0 = time.perf_counter()
         try:
-            yield span_id
+            with profiler_span(kind, **attrs) as annotation:
+                yield SpanHandle(span_id, attrs, annotation)
         finally:
             dur = time.perf_counter() - t0
             stack.pop()

@@ -4,9 +4,10 @@ Capability parity with the reference's two tracing mechanisms (SURVEY §5):
 
 * kernel-level spans ``record_function("chunk%d-part%d")`` around every task
   (reference ``pipeline.py:205-210``, removed by the local edit but
-  documented at ``README.md:263,408``) → :func:`stage_scope` emits
-  ``jax.named_scope("chunk{i}-stage{j}")``, which survives into XLA HLO op
-  names and Perfetto traces (the emulator already wraps every task in it);
+  documented at ``README.md:263,408``) → :func:`~.events.stage_scope`
+  emits ``jax.named_scope("chunk{i}-stage{j}")``, which survives into XLA
+  HLO op names and Perfetto traces (the emulator and ``hetero.py`` wrap
+  every task in it);
 * driver-level ``torch.profiler`` with TensorBoard handler
   (``main.py:196-204``) → :func:`profile_trace` wraps ``jax.profiler``;
 * CUDA memory-history snapshots (``main.py:263-271``) →
@@ -26,17 +27,13 @@ from typing import Dict, List, Optional, Tuple
 import jax
 
 from ..core.schedule import Schedule, bubble_fraction
+from .events import stage_scope
 from .xplane import load_trace_planes
 
 __all__ = ["stage_scope", "profile_trace", "device_memory_report",
            "BubbleMeter", "stage_busy_from_trace",
            "stage_timeline_from_trace", "measured_bubble_slope",
            "measured_bubble_two_point"]
-
-
-def stage_scope(microbatch: int, stage: int):
-    """Named scope attributing ops to (micro-batch, stage) in traces."""
-    return jax.named_scope(f"chunk{microbatch}-stage{stage}")
 
 
 @contextlib.contextmanager
@@ -109,6 +106,9 @@ class BubbleMeter:
                 f"analytic={self.analytic:.2%}")
 
 
+_OPS_LINE = "XLA Ops"     # one event per executed device operation
+
+
 def _merge_intervals(events: List[Tuple[float, float]]
                      ) -> List[Tuple[float, float]]:
     """Union of [start, end) intervals (events overlap across lines)."""
@@ -135,8 +135,11 @@ def stage_busy_from_trace(logdir: str) -> Dict[str, float]:
     :mod:`.xplane`) and merges the op-event intervals of every
     ``/device:*`` plane — the trace-driven counterpart of the reference
     author's TensorBoard-trace verification
-    (``/root/reference/README.md:559-567``). Returns ``{plane_name:
-    busy_sec}`` plus a ``"_span"`` key holding the whole trace's wall span
+    (``/root/reference/README.md:559-567``). Where a plane has a line
+    ``XLA Ops`` (a TPU's has, beside ``Steps`` and ``XLA Modules`` whose
+    events span whole programs, idle gaps included) only that line is
+    read; a plane without one is read whole. Returns ``{plane_name:
+    busy_sec}`` plus a ``"_span"`` key holding the read events' wall span
     in seconds. Device planes exist for real accelerators
     (``/device:TPU:0`` ...); the virtual CPU platform reports only host
     threads, for which :func:`measured_bubble_slope` is the fallback.
@@ -147,7 +150,8 @@ def stage_busy_from_trace(logdir: str) -> Dict[str, float]:
         if not plane.name.startswith("/device:"):
             continue
         events: List[Tuple[float, float]] = []
-        for line in plane.lines:
+        ops = [ln for ln in plane.lines if ln.name == _OPS_LINE]
+        for line in ops or plane.lines:
             for ev in line.events:
                 events.append((ev.start_ns, ev.end_ns))
                 lo, hi = min(lo, ev.start_ns), max(hi, ev.end_ns)
@@ -163,7 +167,8 @@ _SCOPE_RE = re.compile(r"chunk(\d+)-stage(\d+)")
 
 def stage_timeline_from_trace(logdir: str) -> Dict[str, object]:
     """Per-stage busy/idle attribution bucketed by the ``chunk{i}-stage{j}``
-    named scopes (:func:`stage_scope` — they survive into XLA op names).
+    named scopes (:func:`stage_scope` — they survive into XLA op names:
+    the event's name on the CPU backend, its ``tf_op`` stat on a TPU).
 
     Extends :func:`stage_busy_from_trace` from per-plane to per-stage: every
     event whose name carries a scope tag is credited to that (stage,
@@ -191,7 +196,10 @@ def stage_timeline_from_trace(logdir: str) -> Dict[str, object]:
                 continue
             for line in plane.lines:
                 for ev in line.events:
-                    m = _SCOPE_RE.search(ev.name)
+                    # a TPU op's name is its instruction's (fusion.106);
+                    # the scope path is in its metadata's tf_op stat
+                    m = _SCOPE_RE.search(ev.name) or _SCOPE_RE.search(
+                        str(ev.meta.get("tf_op", "")))
                     if not m:
                         continue
                     chunk, stage = int(m.group(1)), int(m.group(2))

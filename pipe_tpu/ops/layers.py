@@ -22,6 +22,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..core.partition import StageCtx
+from ..obs.events import (ATTENTION, EMBED, FFN, HEAD, KV_CACHE,
+                          device_scope, scoped)
 
 __all__ = [
     "Module", "Sequential", "Lambda", "Linear", "Embedding", "LayerNorm",
@@ -164,6 +166,7 @@ class Embedding(Module):
         table = jax.random.normal(key, (self.vocab, self.features), self.dtype)
         return {"table": table}
 
+    @scoped(EMBED)
     def apply(self, params, tokens, ctx: StageCtx = StageCtx()):
         y = jnp.take(params["table"], tokens, axis=0)
         if self.scale:
@@ -355,11 +358,16 @@ class MultiHeadAttention(Module):
         qh = proj(params["wq"], params["bq"])
         kh = proj(params["wk"], params["bk"])
         vh = proj(params["wv"], params["bv"])
-        ck = jax.lax.dynamic_update_slice(
-            cache["k"], kh.astype(cache["k"].dtype), (0, pos, 0, 0))
-        cv = jax.lax.dynamic_update_slice(
-            cache["v"], vh.astype(cache["v"].dtype), (0, pos, 0, 0))
-        logits = jnp.einsum("bqhd,bkhd->bhqk", qh, ck).astype(jnp.float32)
+        # the cache's update, and below its two reads (every cached row
+        # of k for the scores, of v for the mix): what a decode step pays
+        # for the cache, apart from the projections and the softmax
+        with device_scope(KV_CACHE):
+            ck = jax.lax.dynamic_update_slice(
+                cache["k"], kh.astype(cache["k"].dtype), (0, pos, 0, 0))
+            cv = jax.lax.dynamic_update_slice(
+                cache["v"], vh.astype(cache["v"].dtype), (0, pos, 0, 0))
+            logits = jnp.einsum("bqhd,bkhd->bhqk", qh, ck).astype(
+                jnp.float32)
         logits = logits / math.sqrt(hd)
         kpos = jnp.arange(ck.shape[1])[None, None, None, :]
         if tree is None:
@@ -375,8 +383,9 @@ class MultiHeadAttention(Module):
         logits = jnp.where(allowed, logits,
                            jnp.asarray(-1e30, logits.dtype))
         weights = jax.nn.softmax(logits, axis=-1).astype(x.dtype)
-        o = jnp.einsum("bhqk,bkhd->bqhd", weights, cv).reshape(
-            b, q, self.d_model)
+        with device_scope(KV_CACHE):
+            o = jnp.einsum("bhqk,bkhd->bqhd", weights, cv)
+        o = o.reshape(b, q, self.d_model)
         out = jnp.einsum("bsd,de->bse", o, params["wo"]) + params["bo"]
         return out, {"k": ck, "v": cv}
 
@@ -455,24 +464,30 @@ class TransformerEncoderLayer(_TransformerBlockBase):
         super().__init__(*args, name=name, **kwargs)
 
     def apply(self, params, x, ctx: StageCtx = StageCtx()):
-        a = self.attn.apply(params["attn"], x, ctx=ctx.fold(0))
-        a = self.drop.apply({}, a, ctx=ctx.fold(1))
-        x = self.ln1.apply(params["ln1"], x + a, ctx=ctx)
-        h = self.act(self.ff1.apply(params["ff1"], x, ctx=ctx))
-        h = self.drop.apply({}, h, ctx=ctx.fold(2))
-        h = self.ff2.apply(params["ff2"], h, ctx=ctx)
-        h = self.drop.apply({}, h, ctx=ctx.fold(3))
-        return self.ln2.apply(params["ln2"], x + h, ctx=ctx)
+        # each scope holds its branch whole: the sublayer, its dropout,
+        # the residual add and the norm that closes it
+        with device_scope(ATTENTION):
+            a = self.attn.apply(params["attn"], x, ctx=ctx.fold(0))
+            a = self.drop.apply({}, a, ctx=ctx.fold(1))
+            x = self.ln1.apply(params["ln1"], x + a, ctx=ctx)
+        with device_scope(FFN):
+            h = self.act(self.ff1.apply(params["ff1"], x, ctx=ctx))
+            h = self.drop.apply({}, h, ctx=ctx.fold(2))
+            h = self.ff2.apply(params["ff2"], h, ctx=ctx)
+            h = self.drop.apply({}, h, ctx=ctx.fold(3))
+            return self.ln2.apply(params["ln2"], x + h, ctx=ctx)
 
     def decode(self, params, x, cache, pos, tree=None):
         """Incremental :meth:`apply` (inference: no dropout) — same math on
         the new positions with attention served from the KV cache."""
-        a, cache = self.attn.decode(params["attn"], x, cache, pos,
-                                    tree=tree)
-        x = self.ln1.apply(params["ln1"], x + a)
-        h = self.act(self.ff1.apply(params["ff1"], x))
-        h = self.ff2.apply(params["ff2"], h)
-        return self.ln2.apply(params["ln2"], x + h), cache
+        with device_scope(ATTENTION):
+            a, cache = self.attn.decode(params["attn"], x, cache, pos,
+                                        tree=tree)
+            x = self.ln1.apply(params["ln1"], x + a)
+        with device_scope(FFN):
+            h = self.act(self.ff1.apply(params["ff1"], x))
+            h = self.ff2.apply(params["ff2"], h)
+            return self.ln2.apply(params["ln2"], x + h), cache
 
 
 class PreLNBlock(_TransformerBlockBase):
@@ -487,26 +502,30 @@ class PreLNBlock(_TransformerBlockBase):
         super().__init__(*args, name=name, activation=activation, **kwargs)
 
     def apply(self, params, x, ctx: StageCtx = StageCtx()):
-        a = self.attn.apply(params["attn"],
-                            self.ln1.apply(params["ln1"], x, ctx=ctx),
-                            ctx=ctx.fold(0))
-        x = x + self.drop.apply({}, a, ctx=ctx.fold(1))
-        h = self.act(self.ff1.apply(
-            params["ff1"], self.ln2.apply(params["ln2"], x, ctx=ctx),
-            ctx=ctx))
-        h = self.ff2.apply(params["ff2"], h, ctx=ctx)
-        return x + self.drop.apply({}, h, ctx=ctx.fold(2))
+        with device_scope(ATTENTION):
+            a = self.attn.apply(params["attn"],
+                                self.ln1.apply(params["ln1"], x, ctx=ctx),
+                                ctx=ctx.fold(0))
+            x = x + self.drop.apply({}, a, ctx=ctx.fold(1))
+        with device_scope(FFN):
+            h = self.act(self.ff1.apply(
+                params["ff1"], self.ln2.apply(params["ln2"], x, ctx=ctx),
+                ctx=ctx))
+            h = self.ff2.apply(params["ff2"], h, ctx=ctx)
+            return x + self.drop.apply({}, h, ctx=ctx.fold(2))
 
     def decode(self, params, x, cache, pos, tree=None):
         """Incremental :meth:`apply` (inference: no dropout) — same math on
         the new positions with attention served from the KV cache."""
-        a, cache = self.attn.decode(params["attn"],
-                                    self.ln1.apply(params["ln1"], x),
-                                    cache, pos, tree=tree)
-        x = x + a
-        h = self.act(self.ff1.apply(params["ff1"],
-                                    self.ln2.apply(params["ln2"], x)))
-        return x + self.ff2.apply(params["ff2"], h), cache
+        with device_scope(ATTENTION):
+            a, cache = self.attn.decode(params["attn"],
+                                        self.ln1.apply(params["ln1"], x),
+                                        cache, pos, tree=tree)
+            x = x + a
+        with device_scope(FFN):
+            h = self.act(self.ff1.apply(params["ff1"],
+                                        self.ln2.apply(params["ln2"], x)))
+            return x + self.ff2.apply(params["ff2"], h), cache
 
 
 class PositionalEncoding(Module):
@@ -528,6 +547,7 @@ class PositionalEncoding(Module):
     def init(self, key, x):
         return {}
 
+    @scoped(EMBED)
     def apply(self, params, x, ctx: StageCtx = StageCtx()):
         s = x.shape[-2]
         x = x + self.pe[:s]
@@ -545,5 +565,6 @@ class Decoder(Module):
     def init(self, key, x):
         return self.proj.init(key, x)
 
+    @scoped(HEAD)
     def apply(self, params, x, ctx: StageCtx = StageCtx()):
         return self.proj.apply(params, x, ctx=ctx)

@@ -46,6 +46,8 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 
+from ..obs.events import LOSS, scoped
+
 __all__ = ["streaming_xent"]
 
 
@@ -72,6 +74,7 @@ def streaming_xent(h, w, b, targets, block: int = 8192):
     return ce
 
 
+@scoped(LOSS)                        # head and loss fused: all of it
 def _forward(h, w, b, targets, block):
     wb, bb, nb, _ = _pad_blocks(w, b, block)
     # the bf16-vs-f32 tile matmul choice falls out of h's dtype: the weight
@@ -112,6 +115,7 @@ def _fwd(h, w, b, targets, block):
     return ce, (h, w, b, targets.astype(jnp.int32), lse)
 
 
+@scoped(LOSS)
 def _bwd(block, res, g):
     h, w, b, tgt, lse = res
     wb, bb, nb, pad = _pad_blocks(w, b, block)

@@ -18,12 +18,11 @@ from __future__ import annotations
 
 from typing import Any, List, Optional, Sequence
 
-import jax
-
 from ..core import microbatch as mb
 from ..core.partition import Stage, StageCtx
 from ..core.remat import apply_remat, checkpoint_stop, validate_mode
 from ..core.schedule import GPipeSchedule, Schedule
+from ..obs.events import stage_scope
 
 __all__ = ["run"]
 
@@ -60,7 +59,7 @@ def _compute_one(stage: Stage, params: Any, batch: mb.Batch, ctx: StageCtx,
             return call_payload(p, k, *inputs)
 
         task = apply_remat(task, enabled=remat, policy=remat_policy)
-        with jax.named_scope(f"chunk{ctx.microbatch}-stage{ctx.stage}"):
+        with stage_scope(ctx.microbatch, ctx.stage):
             return batch.call(lambda *inputs: task(params, key, *inputs))
 
     from ..extras.skip import SkipTracker
@@ -81,7 +80,7 @@ def _compute_one(stage: Stage, params: Any, batch: mb.Batch, ctx: StageCtx,
         return out, stash_vals, dict(local.accum)
 
     task = apply_remat(task, enabled=remat, policy=remat_policy)
-    with jax.named_scope(f"chunk{ctx.microbatch}-stage{ctx.stage}"):
+    with stage_scope(ctx.microbatch, ctx.stage):
         result, stash_vals, accums = task(params, key, pop_vals,
                                           *batch.values)
     for (ns, name), v in zip(stash_keys, stash_vals):

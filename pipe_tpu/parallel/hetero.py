@@ -64,6 +64,7 @@ from ..core.packing import PackPlan as _PackPlan, StageParamPack
 from ..core.partition import StageCtx
 from ..core.remat import apply_remat, checkpoint_stop, validate_mode
 from .mesh import DATA_AXIS, STAGE_AXIS
+from ..obs.events import stage_scope
 from ..utils.rng import make_key
 
 __all__ = ["HeteroSpmdPipeline"]
@@ -353,7 +354,7 @@ class HeteroSpmdPipeline:
                                data_axis=DATA_AXIS
                                if self.has_data and self.n_data > 1
                                else None)
-                with local.scope(0, s), jax.named_scope(f"stage{s}"):
+                with local.scope(0, s), stage_scope(None, s):
                     out = part.apply(p, *vals, ctx=ctx)
                 stash_vals = [local.load(0, ns, name) for ns, name in stashes]
                 # This stage's deferred-BN stat contributions (explicit remat

@@ -94,6 +94,7 @@ from ..core.schedule import (BWD, FWD, IDLE, WGRAD, GPipeSchedule,
                              overlap_joint_capacity, _times_by_code)
 from .buffers import pack_words, packed_words, unpack_words
 from .mesh import DATA_AXIS, MODEL_AXIS, STAGE_AXIS
+from ..obs.events import REMAT_SCOPE, device_scope
 from ..obs.telemetry import get_registry
 from ..utils.rng import make_key
 
@@ -1503,8 +1504,12 @@ class ScheduledPipeline:
                     continue
                 vjp_fn = res.pop((i, g), None)
                 if vjp_fn is None:
-                    _, vjp_fn = self._vjp_wrt(
-                        params_g, pre_params, h_in, x_mb, kis, s)
+                    # the manual re-forward, under the name jax.checkpoint
+                    # gives its own, so that a trace tells it from the
+                    # first forward
+                    with device_scope(REMAT_SCOPE):
+                        _, vjp_fn = self._vjp_wrt(
+                            params_g, pre_params, h_in, x_mb, kis, s)
                 gp, gpre, gh = vjp_fn(self._make_seed(seed_h, None))
                 if split_w:
                     # B/W split table (zb-h1): the weight/pre grads computed
@@ -1990,8 +1995,9 @@ class ScheduledPipeline:
                                      res_slot_for(i, g))(seed)
 
                 def apply_recomputed():
-                    _, vjp_fn = self._vjp_wrt(
-                        params_g, pre_params, h_in, x_mb, kis, s, pops)
+                    with device_scope(REMAT_SCOPE):
+                        _, vjp_fn = self._vjp_wrt(
+                            params_g, pre_params, h_in, x_mb, kis, s, pops)
                     return vjp_fn(seed)
 
                 def apply_policy_stored():

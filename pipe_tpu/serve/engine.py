@@ -52,6 +52,7 @@ from ..inference.draft import DraftSource, resolve_draft, tree_layout
 from ..inference.generate import (GenerationConfig, head_logits,
                                   sample_logits)
 from ..inference.quant import QuantLeaf, dequant_tree
+from ..obs import events as ev
 from ..obs.events import NULL_EVENT_LOG, REQUEST
 from ..obs.telemetry import get_registry, host_overhead_per_token
 from .buckets import BucketSpec
@@ -381,10 +382,11 @@ class SingleDeviceSlotBackend:
         m, gen = self.model, self.gen
         cd = m.cfg.compute_dtype
         get_registry().counter("serve.engine.prefill_traces").inc()
-        proto = m.block.attn.make_cache(1, self.max_len, dtype=cd)
-        temp0 = jax.tree_util.tree_map(
-            lambda a: jnp.zeros((self._n_layers,) + a.shape, a.dtype),
-            proto)
+        with ev.device_scope(ev.KV_CACHE):       # the temporary cache
+            proto = m.block.attn.make_cache(1, self.max_len, dtype=cd)
+            temp0 = jax.tree_util.tree_map(
+                lambda a: jnp.zeros((self._n_layers,) + a.shape, a.dtype),
+                proto)
         h = m.embed_at(pre, prompt, 0)                    # [1, B, d]
 
         def layer(h, inp):
@@ -393,10 +395,11 @@ class SingleDeviceSlotBackend:
             return h, cache
 
         h, temp = jax.lax.scan(layer, h, (block_stack, temp0))
-        caches = jax.tree_util.tree_map(
-            lambda big, rows: jax.lax.dynamic_update_slice(
-                big, rows, (0, slot) + (0,) * (rows.ndim - 2)),
-            caches, temp)
+        with ev.device_scope(ev.KV_CACHE):       # the slot's slab, whole
+            caches = jax.tree_util.tree_map(
+                lambda big, rows: jax.lax.dynamic_update_slice(
+                    big, rows, (0, slot) + (0,) * (rows.ndim - 2)),
+                caches, temp)
         h_last = jax.lax.dynamic_slice(
             h, (0, true_len - 1, 0), (1, 1, h.shape[-1]))
         key, sub = jax.random.split(key)
@@ -532,9 +535,10 @@ class SingleDeviceSlotBackend:
         flag the admitting prefill arms anyway)."""
         get_registry().counter("serve.kv.restore_traces").inc()
         out = dict(pool_kv)
-        for name, rows in payload.items():
-            out[name] = jax.lax.dynamic_update_slice_in_dim(
-                pool_kv[name], rows[:, None], dst, axis=1)
+        with ev.device_scope(ev.KV_CACHE):
+            for name, rows in payload.items():
+                out[name] = jax.lax.dynamic_update_slice_in_dim(
+                    pool_kv[name], rows[:, None], dst, axis=1)
         return out
 
     def _decode_paged_fn(self, block_stack, pre, post, pool_kv, tables,
@@ -1118,6 +1122,24 @@ class SingleDeviceSlotBackend:
         else:
             padded, p = list(prompt), len(prompt)
         B = len(padded)
+        with ev.span(ev.SERVE_PREFILL, slot=slot, prompt_len=p, bucket=B):
+            tok0 = self._prefill_slab(reg, slot, padded, p, seed)
+        self._count_prompt(reg, p, B)
+        self._hist_write(slot, prompt, tok0)
+        return tok0
+
+    @staticmethod
+    def _count_prompt(reg, prompt_len: int, padded_len: int) -> None:
+        """What the ``serve.prefill`` span carries, for operators without
+        a profiler: real prompt tokens and the rows the programs ran."""
+        reg.counter("serve.engine.prompt_tokens").inc(prompt_len)
+        reg.counter("serve.engine.padded_prompt_tokens").inc(padded_len)
+
+    def _prefill_slab(self, reg, slot: int, padded, p: int,
+                      seed: int) -> int:
+        """The bucket's program on the padded prompt, then the blocking
+        read of the first token."""
+        B = len(padded)
         run = self._prefill_programs.get(B)
         if run is None:
             reg.counter("serve.engine.prefill_program_misses").inc()
@@ -1133,7 +1155,7 @@ class SingleDeviceSlotBackend:
                     f"{len(self._prefill_programs)} distinct prefill "
                     f"programs with bucketing DISABLED — every new "
                     f"prompt length recompiles. Pass a BucketSpec to cap "
-                    f"the program cache.", RuntimeWarning, stacklevel=3)
+                    f"the program cache.", RuntimeWarning, stacklevel=4)
         else:
             reg.counter("serve.engine.prefill_program_hits").inc()
         arr = jnp.asarray(padded, jnp.int32)[None, :]
@@ -1142,12 +1164,12 @@ class SingleDeviceSlotBackend:
                                 self._caches, arr, jnp.int32(p),
                                 jnp.int32(slot), key)
         self._caches = caches
-        tok0 = int(tok0)
+        with ev.span(ev.SERVE_PREFILL_SYNC, slot=slot):
+            tok0 = int(tok0)
         self._tok = self._tok.at[slot].set(tok0)
         self._pos = self._pos.at[slot].set(p)
         self._key_data = self._key_data.at[slot].set(
             jax.random.key_data(key))
-        self._hist_write(slot, prompt, tok0)
         return tok0
 
     def _hist_write(self, slot: int, prompt: Sequence[int],
@@ -1174,51 +1196,57 @@ class SingleDeviceSlotBackend:
         failure mid-stream releases the reservation and unpublishes any
         half-written cache entries."""
         plen = len(prompt)
-        adm = self.pool.admit(slot, prompt, max_new_tokens,
-                              chunk=self.prefill_chunk)
-        try:
-            for dst, payload in adm.restores:
-                # offloaded prefix blocks this admission reuses come
-                # back from the host store BEFORE any fork/chunk writes;
-                # the regather armed below refreshes the decode views —
-                # no extra host decision per tick
-                self._pool_kv = self._restore_jit(
-                    self._pool_kv, jnp.int32(dst),
-                    {k: jnp.asarray(v) for k, v in payload.items()})
-            for src, dst in adm.cow_forks:
-                self._pool_kv = self._fork_jit(
-                    self._pool_kv, jnp.int32(src), jnp.int32(dst))
-            trow = jnp.asarray(adm.table)
-            C = self.prefill_chunk
-            pad = self.gen.pad_token_id
-            t = adm.resume_from
-            h_last = None
-            while t < plen:
-                toks = list(prompt[t:t + C])
-                toks += [pad] * (C - len(toks))
-                arr = jnp.asarray(toks, jnp.int32)[None, :]
-                self._pool_kv, h_last = self._chunk_jit(
-                    self._block_stack, self._pre, self._pool_kv, trow,
-                    arr, jnp.int32(t), jnp.int32(plen))
-                t += C
-            tok0, key = self._sample_jit(
-                self._post, h_last, jax.random.key(seed))
-        except Exception:
-            self.pool.release(slot, failed=True)
-            raise
-        tok0 = int(tok0)
-        self._tok = self._tok.at[slot].set(tok0)
-        self._pos = self._pos.at[slot].set(plen)
-        self._key_data = self._key_data.at[slot].set(
-            jax.random.key_data(key))
-        self._views_dirty = True       # this slot's table moved
-        if self.resident:
-            # arm the device-side regather flag — the ONE host gather
-            # decision per admission (counted here; steady-state
-            # resident ticks make zero)
-            self._regather = jnp.asarray(True)
-            get_registry().counter(
-                "serve.kv.regather_host_decisions").inc()
+        with ev.span(ev.SERVE_PREFILL, slot=slot, prompt_len=plen) as sp:
+            adm = self.pool.admit(slot, prompt, max_new_tokens,
+                                  chunk=self.prefill_chunk)
+            try:
+                for dst, payload in adm.restores:
+                    # offloaded prefix blocks this admission reuses come
+                    # back from the host store BEFORE any fork/chunk writes;
+                    # the regather armed below refreshes the decode views —
+                    # no extra host decision per tick
+                    self._pool_kv = self._restore_jit(
+                        self._pool_kv, jnp.int32(dst),
+                        {k: jnp.asarray(v) for k, v in payload.items()})
+                for src, dst in adm.cow_forks:
+                    self._pool_kv = self._fork_jit(
+                        self._pool_kv, jnp.int32(src), jnp.int32(dst))
+                trow = jnp.asarray(adm.table)
+                C = self.prefill_chunk
+                pad = self.gen.pad_token_id
+                t = adm.resume_from
+                # the rows the chunk program runs for this prompt
+                rows = -(-(plen - t) // C) * C
+                sp.set_metadata(bucket=rows)
+                h_last = None
+                while t < plen:
+                    toks = list(prompt[t:t + C])
+                    toks += [pad] * (C - len(toks))
+                    arr = jnp.asarray(toks, jnp.int32)[None, :]
+                    self._pool_kv, h_last = self._chunk_jit(
+                        self._block_stack, self._pre, self._pool_kv, trow,
+                        arr, jnp.int32(t), jnp.int32(plen))
+                    t += C
+                tok0, key = self._sample_jit(
+                    self._post, h_last, jax.random.key(seed))
+            except Exception:
+                self.pool.release(slot, failed=True)
+                raise
+            with ev.span(ev.SERVE_PREFILL_SYNC, slot=slot):
+                tok0 = int(tok0)
+            self._tok = self._tok.at[slot].set(tok0)
+            self._pos = self._pos.at[slot].set(plen)
+            self._key_data = self._key_data.at[slot].set(
+                jax.random.key_data(key))
+            self._views_dirty = True       # this slot's table moved
+            if self.resident:
+                # arm the device-side regather flag — the ONE host gather
+                # decision per admission (counted here; steady-state
+                # resident ticks make zero)
+                self._regather = jnp.asarray(True)
+                get_registry().counter(
+                    "serve.kv.regather_host_decisions").inc()
+        self._count_prompt(get_registry(), plen, rows)
         self._hist_write(slot, prompt, tok0)
         return tok0
 
@@ -1239,26 +1267,28 @@ class SingleDeviceSlotBackend:
         ``resident=True`` — that is the parity reference."""
         if self.resident and budgets is not None:
             return self._decode_resident(live, budgets, r_max)
-        if self.paged:
-            get_registry().counter(
-                "serve.kv.regather_host_decisions").inc()
-            pool_kv, tok, pos, kd, views, toks = self._decode_jit(
-                self._block_stack, self._pre, self._post, self._pool_kv,
-                jnp.asarray(self.pool.table), self._tok, self._pos,
-                self._key_data, self._views,
-                jnp.asarray(self._views_dirty))
-            self._pool_kv = pool_kv
-            self._views = views
-            self._views_dirty = False
-            if self.resident:
-                self._regather = jnp.asarray(False)  # views now current
-        else:
-            caches, tok, pos, kd, toks = self._decode_jit(
-                self._block_stack, self._pre, self._post, self._caches,
-                self._tok, self._pos, self._key_data)
-            self._caches = caches
+        with ev.span(ev.SERVE_DECODE_LAUNCH, chunks=1):
+            if self.paged:
+                get_registry().counter(
+                    "serve.kv.regather_host_decisions").inc()
+                pool_kv, tok, pos, kd, views, toks = self._decode_jit(
+                    self._block_stack, self._pre, self._post, self._pool_kv,
+                    jnp.asarray(self.pool.table), self._tok, self._pos,
+                    self._key_data, self._views,
+                    jnp.asarray(self._views_dirty))
+                self._pool_kv = pool_kv
+                self._views = views
+                self._views_dirty = False
+                if self.resident:
+                    self._regather = jnp.asarray(False)  # views now current
+            else:
+                caches, tok, pos, kd, toks = self._decode_jit(
+                    self._block_stack, self._pre, self._post, self._caches,
+                    self._tok, self._pos, self._key_data)
+                self._caches = caches
         self._tok, self._pos, self._key_data = tok, pos, kd
-        toks = np.asarray(toks)
+        with ev.span(ev.SERVE_DECODE_SYNC):
+            toks = np.asarray(toks)
         valid = np.broadcast_to(
             np.asarray(live, bool)[:, None], toks.shape)
         return toks, valid
@@ -1274,50 +1304,54 @@ class SingleDeviceSlotBackend:
         budget = jnp.asarray(np.asarray(budgets, np.int32))
         if self.spec_tokens is not None:
             self.decode_width = self._pick_spec_k(live)
-        if self.paged:
-            tables = jnp.asarray(self.pool.table)
-            if self.spec_tokens is not None:
-                (pool_kv, tok, pos, kd, views, regather, hist, buf,
-                 counts, k) = self._resident_spec_jits[self.decode_width](
-                    self._block_stack, self._pre, self._post,
-                    self._pool_kv, tables, self._tok, self._pos,
-                    self._key_data, self._views, self._regather,
-                    self._hist, live_d, budget, jnp.int32(rm))
-                self._hist = hist
-            else:
-                (pool_kv, tok, pos, kd, views, regather, buf, counts,
-                 k) = self._resident_jit(
-                    self._block_stack, self._pre, self._post,
-                    self._pool_kv, tables, self._tok, self._pos,
-                    self._key_data, self._views, self._regather,
-                    live_d, budget, jnp.int32(rm))
-            self._pool_kv = pool_kv
-            self._views = views
-            self._views_dirty = False
-            self._regather = regather          # cleared, never synced
-        else:
-            if self.spec_tokens is not None:
-                caches, tok, pos, kd, hist, buf, counts, k = \
-                    self._resident_spec_jits[self.decode_width](
+        with ev.span(ev.SERVE_DECODE_LAUNCH, chunks=rm):
+            if self.paged:
+                tables = jnp.asarray(self.pool.table)
+                if self.spec_tokens is not None:
+                    (pool_kv, tok, pos, kd, views, regather, hist, buf,
+                     counts, k) = self._resident_spec_jits[self.decode_width](
                         self._block_stack, self._pre, self._post,
-                        self._caches, self._tok, self._pos,
-                        self._key_data, self._hist, live_d, budget,
-                        jnp.int32(rm))
-                self._hist = hist
-            else:
-                caches, tok, pos, kd, buf, counts, k = \
-                    self._resident_jit(
+                        self._pool_kv, tables, self._tok, self._pos,
+                        self._key_data, self._views, self._regather,
+                        self._hist, live_d, budget, jnp.int32(rm))
+                    self._hist = hist
+                else:
+                    (pool_kv, tok, pos, kd, views, regather, buf, counts,
+                     k) = self._resident_jit(
                         self._block_stack, self._pre, self._post,
-                        self._caches, self._tok, self._pos,
-                        self._key_data, live_d, budget, jnp.int32(rm))
-            self._caches = caches
+                        self._pool_kv, tables, self._tok, self._pos,
+                        self._key_data, self._views, self._regather,
+                        live_d, budget, jnp.int32(rm))
+                self._pool_kv = pool_kv
+                self._views = views
+                self._views_dirty = False
+                self._regather = regather          # cleared, never synced
+            else:
+                if self.spec_tokens is not None:
+                    caches, tok, pos, kd, hist, buf, counts, k = \
+                        self._resident_spec_jits[self.decode_width](
+                            self._block_stack, self._pre, self._post,
+                            self._caches, self._tok, self._pos,
+                            self._key_data, self._hist, live_d, budget,
+                            jnp.int32(rm))
+                    self._hist = hist
+                else:
+                    caches, tok, pos, kd, buf, counts, k = \
+                        self._resident_jit(
+                            self._block_stack, self._pre, self._post,
+                            self._caches, self._tok, self._pos,
+                            self._key_data, live_d, budget, jnp.int32(rm))
+                self._caches = caches
         self._tok, self._pos, self._key_data = tok, pos, kd
-        k = int(k)                             # THE host sync
+        with ev.span(ev.SERVE_DECODE_SYNC):
+            k = int(k)                         # THE host sync
+            buf = np.asarray(buf)              # then the two fetches
+            counts = np.asarray(counts)
         if k < rm:
             reg.counter("serve.engine.device_exits").inc()
         W = self.decode_width
-        toks = np.asarray(buf)[:, :k * W]
-        counts = np.asarray(counts)[:, :k]
+        toks = buf[:, :k * W]
+        counts = counts[:, :k]
         valid = (np.arange(W)[None, None, :]
                  < counts[:, :, None]).reshape(self.num_slots, k * W)
         if self.spec_tokens is not None:
@@ -1801,6 +1835,12 @@ class ServeEngine:
         watchdog policies, admit into free slots, run one decode chunk,
         retire. Returns the requests that reached a terminal state
         during this tick."""
+        with self.events.span(ev.SERVE_TICK, tick=self._tick_index,
+                              live=self.live_slots,
+                              queued=self.queue.depth):
+            return self._tick()
+
+    def _tick(self) -> List[Response]:
         reg = get_registry()
         tick_idx = self._tick_index
         self._tick_index += 1
@@ -1812,55 +1852,58 @@ class ServeEngine:
         eos = self.backend.gen.eos_token_id
         wd = self.watchdog
 
-        # 0) drain — everything still queued goes back to its caller
-        if self._draining and self.queue.depth:
-            for req in self.queue.shed_lowest(self.queue.depth):
-                finished.append(self._shed_queued(req, "drain", now))
+        with self.events.span(ev.SERVE_REAP):
+            # 0) drain — everything still queued goes back to its caller
+            if self._draining and self.queue.depth:
+                for req in self.queue.shed_lowest(self.queue.depth):
+                    finished.append(self._shed_queued(req, "drain", now))
 
-        # 1) deaths — queued first (never cost a slot), then running
-        for req, reason in self.queue.reap(now):
-            finished.append(self._finish_queued(req, reason, now))
-        for slot in range(self.backend.num_slots):
-            st = self._slots[slot]
-            if st is None:
-                continue
-            if st.req.cancelled:
-                finished.append(
-                    self._retire(slot, "cancelled", "cancelled", now))
-            elif st.req.deadline is not None and now >= st.req.deadline:
-                finished.append(
-                    self._retire(slot, "timeout", "deadline", now))
-
-        # 1b) stuck slots — alive far past the ticks their token budget
-        # can possibly need; retire as errors instead of squatting
-        if wd is not None and wd.stuck_slack_ticks is not None:
-            chunk = getattr(self.backend, "decode_chunk", 1)
+            # 1) deaths — queued first (never cost a slot), then running
+            for req, reason in self.queue.reap(now):
+                finished.append(self._finish_queued(req, reason, now))
             for slot in range(self.backend.num_slots):
                 st = self._slots[slot]
                 if st is None:
                     continue
-                limit = wd.stuck_after(st.req.max_new_tokens, chunk)
-                if tick_idx - st.admitted_tick >= limit:
-                    reg.counter("resilience.stuck_slots").inc()
-                    wd.record_stuck()
-                    self.events.event("resilience", action="stuck_slot",
-                                      request=st.req.id, slot=slot,
-                                      age_ticks=tick_idx - st.admitted_tick)
-                    finished.append(self._retire(slot, "error", "stuck", now))
+                if st.req.cancelled:
+                    finished.append(
+                        self._retire(slot, "cancelled", "cancelled", now))
+                elif st.req.deadline is not None and now >= st.req.deadline:
+                    finished.append(
+                        self._retire(slot, "timeout", "deadline", now))
 
-        # 1c) degraded mode — shed lowest-priority queued work while the
-        # deadline-miss EWMA sits above the threshold
-        if wd is not None and wd.shed_ewma_threshold is not None \
-                and not self._draining \
-                and self._miss_ewma > wd.shed_ewma_threshold \
-                and self.queue.depth:
-            n = max(1, self.queue.depth // 2)
-            reg.counter("resilience.shed").inc(n)
-            self.events.event("resilience", action="shed", count=n,
-                              miss_ewma=self._miss_ewma,
-                              queued=self.queue.depth)
-            for req in self.queue.shed_lowest(n):
-                finished.append(self._shed_queued(req, "shed", now))
+            # 1b) stuck slots — alive far past the ticks their token budget
+            # can possibly need; retire as errors instead of squatting
+            if wd is not None and wd.stuck_slack_ticks is not None:
+                chunk = getattr(self.backend, "decode_chunk", 1)
+                for slot in range(self.backend.num_slots):
+                    st = self._slots[slot]
+                    if st is None:
+                        continue
+                    limit = wd.stuck_after(st.req.max_new_tokens, chunk)
+                    if tick_idx - st.admitted_tick >= limit:
+                        reg.counter("resilience.stuck_slots").inc()
+                        wd.record_stuck()
+                        self.events.event(
+                            "resilience", action="stuck_slot",
+                            request=st.req.id, slot=slot,
+                            age_ticks=tick_idx - st.admitted_tick)
+                        finished.append(
+                            self._retire(slot, "error", "stuck", now))
+
+            # 1c) degraded mode — shed lowest-priority queued work while the
+            # deadline-miss EWMA sits above the threshold
+            if wd is not None and wd.shed_ewma_threshold is not None \
+                    and not self._draining \
+                    and self._miss_ewma > wd.shed_ewma_threshold \
+                    and self.queue.depth:
+                n = max(1, self.queue.depth // 2)
+                reg.counter("resilience.shed").inc(n)
+                self.events.event("resilience", action="shed", count=n,
+                                  miss_ewma=self._miss_ewma,
+                                  queued=self.queue.depth)
+                for req in self.queue.shed_lowest(n):
+                    finished.append(self._shed_queued(req, "shed", now))
 
         # 2) admissions — prefill straight into the freed slots; a
         # backend failure here is attributable to ONE request: fail it,
@@ -1904,34 +1947,43 @@ class ServeEngine:
             self.queue.take(req.id)
             slot = self._free.pop()
             t_pre = self.clock()
-            try:
-                if self.chaos is not None and self.chaos.serve_fault(
-                        "backend_raise", tick_idx) is not None:
-                    from ..resilience.chaos import ChaosError
-                    raise ChaosError(
-                        f"injected backend fault at tick {tick_idx}")
-                tok0 = self.backend.prefill(
-                    slot, req.prompt, req.seed,
-                    **self._prefill_kwargs(req))
-            except Exception as e:           # noqa: BLE001 — containment
-                self._free.append(slot)
-                finished.append(self._fail_queued(req, e, self.clock()))
-                continue
-            device_sec += self.clock() - t_pre
-            t_first = self.clock()
-            st = _Slot(req, tok0, ttft=t_first - req.submitted_at,
-                       admitted_tick=tick_idx)
-            self._slots[slot] = st
-            reg.counter("serve.engine.admitted").inc()
-            reg.histogram("serve.engine.ttft_sec").observe(st.ttft)
-            self.events.event(REQUEST, request=req.id, stage="prefill",
-                              trace=req.trace_id, slot=slot, ttft=st.ttft,
-                              attempts=req.attempts,
-                              prompt_len=len(req.prompt))
-            if eos is not None and tok0 == eos:
-                finished.append(self._retire(slot, "ok", "eos", t_first))
-            elif req.max_new_tokens == 1:
-                finished.append(self._retire(slot, "ok", "length", t_first))
+            with self.events.span(
+                    ev.SERVE_ADMIT, request=req.id,
+                    trace=req.trace_id or "", slot=slot,
+                    prompt_len=len(req.prompt),
+                    queued_ms=1e3 * (t_pre - req.submitted_at)):
+                try:
+                    if self.chaos is not None and self.chaos.serve_fault(
+                            "backend_raise", tick_idx) is not None:
+                        from ..resilience.chaos import ChaosError
+                        raise ChaosError(
+                            f"injected backend fault at tick {tick_idx}")
+                    tok0 = self.backend.prefill(
+                        slot, req.prompt, req.seed,
+                        **self._prefill_kwargs(req))
+                except Exception as e:       # noqa: BLE001 — containment
+                    self._free.append(slot)
+                    finished.append(
+                        self._fail_queued(req, e, self.clock()))
+                    continue
+                device_sec += self.clock() - t_pre
+                t_first = self.clock()
+                st = _Slot(req, tok0, ttft=t_first - req.submitted_at,
+                           admitted_tick=tick_idx)
+                self._slots[slot] = st
+                reg.counter("serve.engine.admitted").inc()
+                reg.histogram("serve.engine.ttft_sec").observe(st.ttft)
+                self.events.event(REQUEST, request=req.id,
+                                  stage="prefill", trace=req.trace_id,
+                                  slot=slot, ttft=st.ttft,
+                                  attempts=req.attempts,
+                                  prompt_len=len(req.prompt))
+                if eos is not None and tok0 == eos:
+                    finished.append(
+                        self._retire(slot, "ok", "eos", t_first))
+                elif req.max_new_tokens == 1:
+                    finished.append(
+                        self._retire(slot, "ok", "length", t_first))
 
         # 3) decode — one fixed-shape chunk for every slot. A failure is
         # NOT attributable (all slots share the program): skip the tick
@@ -1940,19 +1992,26 @@ class ServeEngine:
         live = np.array([s is not None for s in self._slots])
         decode_sec = 0.0
         if live.any():
+            n_live = int(live.sum())
+            # rows the launch's first step attends over: each live slot's
+            # prompt and the tokens sampled so far
+            rows = sum(len(s.req.prompt) + len(s.tokens)
+                       for s in self._slots if s is not None)
+            r_max = 1
             t0 = self.clock()
             try:
                 reg.counter("serve.engine.host_syncs").inc()
-                if getattr(self.backend, "resident", False):
-                    budgets = np.array(
-                        [0 if s is None else
-                         max(s.req.max_new_tokens - len(s.tokens), 0)
-                         for s in self._slots], np.int32)
-                    toks, valid = self.backend.decode(
-                        live, budgets=budgets,
-                        r_max=self._resident_horizon(now))
-                else:
-                    toks, valid = self.backend.decode(live)
+                with self.events.span(ev.SERVE_DECODE, live=n_live):
+                    if getattr(self.backend, "resident", False):
+                        budgets = np.array(
+                            [0 if s is None else
+                             max(s.req.max_new_tokens - len(s.tokens), 0)
+                             for s in self._slots], np.int32)
+                        r_max = self._resident_horizon(now)
+                        toks, valid = self.backend.decode(
+                            live, budgets=budgets, r_max=r_max)
+                    else:
+                        toks, valid = self.backend.decode(live)
             except Exception as e:           # noqa: BLE001 — containment
                 self._on_decode_error(reg, e, tick_idx, finished)
             else:
@@ -1968,24 +2027,37 @@ class ServeEngine:
                 self._chunk_ewma = per if self._chunk_ewma is None \
                     else 0.8 * self._chunk_ewma + 0.2 * per
                 emitted = 0
-                for slot in range(self.backend.num_slots):
-                    st = self._slots[slot]
-                    if st is None:
-                        continue
-                    for k in range(toks.shape[1]):
-                        if not valid[slot, k]:
+                n_before = len(finished)
+                with self.events.span(ev.SERVE_RETIRE) as retire:
+                    for slot in range(self.backend.num_slots):
+                        st = self._slots[slot]
+                        if st is None:
                             continue
-                        t = int(toks[slot, k])
-                        st.tokens.append(t)
-                        emitted += 1
-                        if eos is not None and t == eos:
-                            finished.append(
-                                self._retire(slot, "ok", "eos", t1))
-                            break
-                        if len(st.tokens) >= st.req.max_new_tokens:
-                            finished.append(
-                                self._retire(slot, "ok", "length", t1))
-                            break
+                        for k in range(toks.shape[1]):
+                            if not valid[slot, k]:
+                                continue
+                            t = int(toks[slot, k])
+                            st.tokens.append(t)
+                            emitted += 1
+                            if eos is not None and t == eos:
+                                finished.append(
+                                    self._retire(slot, "ok", "eos", t1))
+                                break
+                            if len(st.tokens) >= st.req.max_new_tokens:
+                                finished.append(
+                                    self._retire(slot, "ok", "length", t1))
+                                break
+                    retire.set_metadata(finished=len(finished) - n_before)
+                # the launch's counts, known only now; the counters say
+                # the same to an operator without a profiler
+                steps = int(toks.shape[1])
+                with self.events.span(
+                        ev.SERVE_DECODE_DONE, steps=steps, chunks=chunks,
+                        live=n_live, rows=rows, emitted=emitted,
+                        early_exit=int(chunks < r_max)):
+                    pass
+                reg.counter("serve.engine.decode_launches").inc()
+                reg.counter("serve.engine.decode_steps").inc(steps)
                 if emitted:
                     reg.counter("serve.engine.tokens").inc(emitted)
                     reg.histogram("serve.engine.token_sec").observe(
@@ -2005,7 +2077,6 @@ class ServeEngine:
             max(dur - device_sec, 0.0))
         reg.gauge("serve.engine.host_overhead_per_token").set(
             host_overhead_per_token(reg))
-        reg.gauge("resilience.tick_sec").set(dur)
         if wd is not None and wd.record_tick(dur):
             reg.counter("resilience.watchdog_slow_ticks").inc()
             self.events.event("resilience", action="slow_tick",

@@ -84,6 +84,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..obs.events import KV_CACHE, device_scope
 from ..obs.telemetry import get_registry
 
 __all__ = ["KvPool", "PoolExhausted", "Admission", "HostKvStore",
@@ -968,11 +969,12 @@ def gather_block_cache(pool_layer, table_row, *, block_size: int,
         rows = jnp.take(pool_layer[name], table_row[:mb], axis=0)
         return rows.reshape((mb * block_size,) + rows.shape[2:])
 
-    if "k_scale" in pool_layer:
-        return {name: (g(name).astype(jnp.float32) *
-                       g(name + "_scale")).astype(compute_dtype)[None]
-                for name in ("k", "v")}
-    return {name: g(name)[None] for name in ("k", "v")}
+    with device_scope(KV_CACHE):
+        if "k_scale" in pool_layer:
+            return {name: (g(name).astype(jnp.float32) *
+                           g(name + "_scale")).astype(compute_dtype)[None]
+                    for name in ("k", "v")}
+        return {name: g(name)[None] for name in ("k", "v")}
 
 
 def scatter_block_rows(pool_layer, flat_idx, rows):
@@ -987,17 +989,18 @@ def scatter_block_rows(pool_layer, flat_idx, rows):
     def flat(a):
         return a.reshape((a.shape[0] * a.shape[1],) + a.shape[2:])
 
-    for name in ("k", "v"):
-        a = pool_layer[name]
-        if int8:
-            q, s = quantize_kv_rows(rows[name])
-            out[name] = flat(a).at[flat_idx].set(q).reshape(a.shape)
-            sa = pool_layer[name + "_scale"]
-            out[name + "_scale"] = flat(sa).at[flat_idx].set(s).reshape(
-                sa.shape)
-        else:
-            out[name] = flat(a).at[flat_idx].set(
-                rows[name].astype(a.dtype)).reshape(a.shape)
+    with device_scope(KV_CACHE):
+        for name in ("k", "v"):
+            a = pool_layer[name]
+            if int8:
+                q, s = quantize_kv_rows(rows[name])
+                out[name] = flat(a).at[flat_idx].set(q).reshape(a.shape)
+                sa = pool_layer[name + "_scale"]
+                out[name + "_scale"] = flat(sa).at[flat_idx].set(
+                    s).reshape(sa.shape)
+            else:
+                out[name] = flat(a).at[flat_idx].set(
+                    rows[name].astype(a.dtype)).reshape(a.shape)
     return out
 
 
@@ -1010,4 +1013,5 @@ def copy_block(pool, src, dst, *, block_axis: int = 1):
         return jax.lax.dynamic_update_slice_in_dim(a, blk, dst,
                                                    axis=block_axis)
 
-    return jax.tree_util.tree_map(cp, pool)
+    with device_scope(KV_CACHE):
+        return jax.tree_util.tree_map(cp, pool)

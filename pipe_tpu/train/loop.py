@@ -524,10 +524,11 @@ class Trainer:
         if inject is not None:
             from ..resilience.chaos import apply_train_faults
             loss, grads = apply_train_faults(inject, magnitude, loss, grads)
-        updates, opt_state = self.tx.update(grads, state.opt_state,
-                                            state.params)
-        updates = jax.tree_util.tree_map(lambda u: -lr * u, updates)
-        params = optax.apply_updates(state.params, updates)
+        with ev.device_scope(ev.OPTIMIZER):       # the clip is in tx
+            updates, opt_state = self.tx.update(grads, state.opt_state,
+                                                state.params)
+            updates = jax.tree_util.tree_map(lambda u: -lr * u, updates)
+            params = optax.apply_updates(state.params, updates)
         if self.cfg.zero:
             # ZeRO-1 layout pins: new moments stay data-sharded (XLA then
             # partitions the Adam update over the data axis), new params
@@ -546,7 +547,18 @@ class Trainer:
                 params, self._param_shardings)
         return params, opt_state, loss, grads
 
+    def _sync(self, loss, step: int) -> float:
+        """A blocking read of a loss: the host waits for that step."""
+        with self.events.span(ev.TRAIN_SYNC, step=step):
+            return float(loss)
+
+    def _count_step_trace(self):
+        """Runs at trace time only (as the serve programs' ``*_traces``
+        do): how often a step program was traced, retraces included."""
+        self.registry.counter("train.step_traces").inc()
+
     def _train_step(self, state: TrainState, x, w, key, lr):
+        self._count_step_trace()
         params, opt_state, loss, _ = self._compute_update(state, x, w,
                                                           key, lr)
         return TrainState(params=params, opt_state=opt_state,
@@ -565,6 +577,7 @@ class Trainer:
         from ..resilience.chaos import inject_scope
         from ..resilience.detect import step_guard
 
+        self._count_step_trace()
         rc = self.cfg.resilience
         ewma, consec, total = aux
         with inject_scope(inject):
@@ -602,6 +615,7 @@ class Trainer:
         from ..resilience.chaos import inject_scope, kill_scope
         from ..resilience.detect import stage_heartbeat, step_guard
 
+        self._count_step_trace()
         rc = self.cfg.resilience
         ewma, consec, total, hb = aux
         with inject_scope(inject), kill_scope(kill):
@@ -715,7 +729,6 @@ class Trainer:
         # disabled registry hands back no-ops); StepReports and spans go to
         # the JSONL event log only when telemetry_dir is configured.
         telemetry_on = self.events is not ev.NULL_EVENT_LOG
-        step_timer = self.registry.timer("train.step_sec")
         steps_ctr = self.registry.counter("train.steps")
         tokens_ctr = self.registry.counter("train.tokens")
         tps_gauge = self.registry.gauge("train.tokens_per_sec")
@@ -757,41 +770,42 @@ class Trainer:
             # chaos index); i counts this call's iterations (compile
             # sync, steady-state timing).
             b = start_step + i
-            x, mask = self._make_x(data, target)
-            # Row count is constant until the tail-batch break, so the valid-
-            # row mask is too — build it once, not per step.
-            w = mask if w is None else w
             tracing = bool(telemetry_on and cfg.profile_every
                            and (b + 1) % cfg.profile_every == 0)
             t_step = time.perf_counter()
             with contextlib.ExitStack() as scopes:
-                scopes.enter_context(self.events.span(ev.STEP, step=b,
-                                                      epoch=epoch))
                 if tracing:
                     trace_dir = os.path.join(cfg.telemetry_dir,
                                              f"trace_step{b + 1}")
                     scopes.enter_context(profile_trace(trace_dir))
-                if elastic is not None:
-                    inject, mag = (self.chaos.train_inject(b)
-                                   if self.chaos is not None else (0, 1.0))
-                    from ..resilience.chaos import KILL_NONE
-                    kill = (self.chaos.train_kill(b)
-                            if self.chaos is not None else KILL_NONE)
-                    state, loss, aux = self._step_fn(
-                        state, aux, x, w, jax.random.fold_in(key, b),
-                        jnp.float32(lr), jnp.int32(inject),
-                        jnp.float32(mag), jnp.int32(kill))
-                elif rc is not None:
-                    inject, mag = (self.chaos.train_inject(b)
-                                   if self.chaos is not None else (0, 1.0))
-                    state, loss, aux = self._step_fn(
-                        state, aux, x, w, jax.random.fold_in(key, b),
-                        jnp.float32(lr), jnp.int32(inject),
-                        jnp.float32(mag))
-                else:
-                    state, loss = self._step_fn(state, x, w,
-                                                jax.random.fold_in(key, b),
-                                                jnp.float32(lr))
+                scopes.enter_context(self.events.span(ev.STEP, step=b,
+                                                      epoch=epoch))
+                # everything the host does for a step besides the call
+                with self.events.span(ev.TRAIN_BATCH, step=b):
+                    x, mask = self._make_x(data, target)
+                    # Row count is constant until the tail-batch break, so
+                    # the valid-row mask is too — build it once, not per
+                    # step.
+                    w = mask if w is None else w
+                    args = (x, w, jax.random.fold_in(key, b),
+                            jnp.float32(lr))
+                    if rc is not None:
+                        inject, mag = (self.chaos.train_inject(b)
+                                       if self.chaos is not None
+                                       else (0, 1.0))
+                        args += (jnp.int32(inject), jnp.float32(mag))
+                    if elastic is not None:
+                        from ..resilience.chaos import KILL_NONE
+                        kill = (self.chaos.train_kill(b)
+                                if self.chaos is not None else KILL_NONE)
+                        args += (jnp.int32(kill),)
+                # the call: enqueue time on an asynchronous backend, not
+                # step time
+                with self.events.span(ev.TRAIN_DISPATCH, step=b):
+                    if rc is not None:
+                        state, loss, aux = self._step_fn(state, aux, *args)
+                    else:
+                        state, loss = self._step_fn(state, *args)
                 # Virtual-CPU platform: serialize steps (see
                 # sync_if_forced_cpu — interleaved async runs livelock the
                 # collective rendezvous there). No-op on real TPU.
@@ -799,7 +813,6 @@ class Trainer:
                 if tracing:
                     jax.block_until_ready(loss)  # capture the whole step
             wall = time.perf_counter() - t_step
-            step_timer.observe(wall)
             steps_ctr.inc()
             tokens_ctr.inc(tokens_per_step)
             if wall > 0:
@@ -819,7 +832,7 @@ class Trainer:
                     tokens=tokens_per_step, n_stages=cfg.n_stages,
                     chunks=cfg.chunks, checkpoint=cfg.checkpoint,
                     schedule=cfg.schedule,
-                    loss=float(loss) if at_log else None,
+                    loss=self._sync(loss, b) if at_log else None,
                     model_cfg=self.model_cfg,
                     analytic_bubble=self.analytic_bubble(),
                     memory=(device_memory_peaks()
@@ -849,10 +862,10 @@ class Trainer:
                 self._autosave(state, log_fn)
                 break
             if i == 0:
-                float(loss)               # sync out the compile
+                self._sync(loss, b)       # sync out the compile
                 t0 = time.perf_counter()  # steady-state timing from step 2
             if at_log:
-                l = float(losses[-1])
+                l = self._sync(losses[-1], b)
                 # Steady-state ms/batch from step 2 on; the step-1 line has no
                 # steady-state sample yet, so it reports the compile-inclusive
                 # first-step time instead of a meaningless ~0.
@@ -876,7 +889,8 @@ class Trainer:
                     self.tb.add_scalar("pipeline/bubble",
                                        self.analytic_bubble(), gstep)
                     self.tb.flush()  # visible live; crash loses nothing
-        final = float(losses[-1]) if losses else float("nan")
+        final = (self._sync(losses[-1], start_step + len(losses) - 1)
+                 if losses else float("nan"))
         if self.tb is not None and losses:
             self.tb.add_scalar("train/epoch_loss", final, int(state.step))
             self.tb.flush()

@@ -39,12 +39,22 @@ def compile_cache_dir(environ: Mapping[str, str] = os.environ
 
 
 def configure_compile_cache() -> None:
-    """Point JAX's persistent compile cache at :func:`compile_cache_dir`.
-    Entry points call this first, before anything compiles."""
+    """Point JAX's persistent compile cache at :func:`compile_cache_dir`,
+    and key it with the operations' metadata. Entry points call this first,
+    before anything compiles.
+
+    JAX leaves metadata (``op_name``, source lines) out of the key by
+    default. The named scopes a profiler reads (``obs.events.DEVICE_SCOPES``)
+    are metadata: a cache filled before a scope existed, or by a checkout
+    without it, would go on serving executables whose captures show none,
+    and nothing would say so. Keyed with the metadata, a capture shows what
+    the code says; the price is that an edit which moves lines in a traced
+    function compiles again."""
+    import jax
+
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
     path = compile_cache_dir()
     if path is not None:
-        import jax
-
         jax.config.update("jax_compilation_cache_dir", path)
 
 
