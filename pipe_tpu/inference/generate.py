@@ -237,11 +237,15 @@ class Generator:
 
     ``layer_scan=False`` unrolls the per-layer loop inside the decode
     step and carries the KV caches as two stacked arrays in the OUTER
-    scan, updated in place per layer — avoiding the inner ``lax.scan``'s
-    xs->ys round-trip of the full cache every token (measured 1.16x at
-    the 520M scale, batch 32, where decode is cache-traffic-bound). Same
-    math; float reduction order differs, so greedy ties can resolve
-    differently on near-flat (e.g. untrained) logits.
+    scan — no inner ``lax.scan`` with the full cache as scanned input
+    and stacked output every token. It still takes each layer out of
+    the stack and writes the whole layer back (``a.at[l].set``); the
+    path that writes only the new rows into a carried slab is the serve
+    engine's (``SingleDeviceSlotBackend._run_layers``, the slab form of
+    ``block.decode``). Its effect on decode time: not measured on the
+    current installation. Same math; float reduction order differs, so
+    greedy ties can resolve differently on near-flat (e.g. untrained)
+    logits.
     """
 
     def __init__(self, model, gen_cfg: GenerationConfig = GenerationConfig(),

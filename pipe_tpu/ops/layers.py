@@ -328,7 +328,7 @@ class MultiHeadAttention(Module):
         dt = dtype if dtype is not None else self.dtype
         return {"k": jnp.zeros(shape, dt), "v": jnp.zeros(shape, dt)}
 
-    def decode(self, params, x, cache, pos, tree=None):
+    def decode(self, params, x, cache, pos, tree=None, layer=None):
         """Incremental self-attention with a KV cache (inference only).
 
         ``x``: the new tokens' hidden states ``[b, q, d]`` occupying
@@ -345,6 +345,15 @@ class MultiHeadAttention(Module):
         but query row j attends cache rows strictly before ``pos`` plus
         the within-chunk rows where ``tree[j, r]`` (its ancestors-or-
         self). ``tree=None`` keeps the linear causal mask unchanged.
+
+        ``layer`` (optional traced index): the SLAB form, for a loop
+        over layers that carries every layer's cache. ``cache`` is then
+        the stacked ``{"k","v"}`` of ``[L, S, T, H, D]``, ``x`` is
+        ``[S, q, d]`` (a row per slot) and ``pos`` a per-slot vector
+        ``[S]``. Only the ``S x q`` new rows are written, at ``(layer,
+        s, pos[s])``, and only ``cache[layer]`` is read, so the slab
+        stays one buffer updated in place. Same math as the batch form
+        vmapped over slots. Returns ``(out [S, q, d], slab)``.
         """
         if not self.causal:
             raise ValueError("KV-cache decode requires causal attention")
@@ -362,32 +371,52 @@ class MultiHeadAttention(Module):
         # of k for the scores, of v for the mix): what a decode step pays
         # for the cache, apart from the projections and the softmax
         with device_scope(KV_CACHE):
-            ck = jax.lax.dynamic_update_slice(
-                cache["k"], kh.astype(cache["k"].dtype), (0, pos, 0, 0))
-            cv = jax.lax.dynamic_update_slice(
-                cache["v"], vh.astype(cache["v"].dtype), (0, pos, 0, 0))
+            rows = {"k": kh.astype(cache["k"].dtype),
+                    "v": vh.astype(cache["v"].dtype)}
+            if layer is None:
+                cache = {n: jax.lax.dynamic_update_slice(
+                    cache[n], rows[n], (0, pos, 0, 0)) for n in rows}
+                ck, cv = cache["k"], cache["v"]
+            else:
+                cache = {n: _write_slab_rows(cache[n], rows[n], layer, pos)
+                         for n in rows}
+                ck, cv = (jax.lax.dynamic_index_in_dim(
+                    cache[n], layer, 0, keepdims=False) for n in ("k", "v"))
             logits = jnp.einsum("bqhd,bkhd->bhqk", qh, ck).astype(
                 jnp.float32)
         logits = logits / math.sqrt(hd)
-        kpos = jnp.arange(ck.shape[1])[None, None, None, :]
+        # a scalar pos is every row's; the slab form's is one per row
+        rel = (jnp.arange(ck.shape[1])[None, :]
+               - jnp.reshape(pos, (-1, 1)))                # [1|b, K_cache]
         if tree is None:
-            qpos = pos + jnp.arange(q)[None, None, :, None]
-            allowed = kpos <= qpos
+            allowed = (rel[:, None, :]
+                       <= jnp.arange(q)[None, :, None])    # [1|b, q, K]
         else:
-            rel = jnp.arange(ck.shape[1]) - pos            # [K_cache]
             in_chunk = (rel >= 0) & (rel < q)
-            within = jnp.asarray(tree)[
-                :, jnp.clip(rel, 0, q - 1)]                # [q, K_cache]
-            allowed = ((rel < 0) | (in_chunk & within))[
-                None, None, :, :]
-        logits = jnp.where(allowed, logits,
+            within = jnp.moveaxis(jnp.asarray(tree)[
+                :, jnp.clip(rel, 0, q - 1)], 0, 1)         # [1|b, q, K]
+            allowed = (rel < 0)[:, None, :] | (in_chunk[:, None, :]
+                                               & within)
+        logits = jnp.where(allowed[:, None], logits,
                            jnp.asarray(-1e30, logits.dtype))
         weights = jax.nn.softmax(logits, axis=-1).astype(x.dtype)
         with device_scope(KV_CACHE):
             o = jnp.einsum("bhqk,bkhd->bqhd", weights, cv)
         o = o.reshape(b, q, self.d_model)
         out = jnp.einsum("bsd,de->bse", o, params["wo"]) + params["bo"]
-        return out, {"k": ck, "v": cv}
+        return out, cache
+
+
+def _write_slab_rows(slab, rows, layer, pos):
+    """``rows [S, q, H, D]`` into ``slab [L, S, T, H, D]`` at ``(layer,
+    s, pos[s])``: one ``dynamic_update_slice`` a slot (its clamping is
+    the batch form's), each on the buffer the last one left. Unrolled
+    over the slots on purpose: on the v5e one scatter of ``S`` windows
+    runs as a loop and cost four times as much (PERF.md, PR 26)."""
+    for s in range(rows.shape[0]):
+        slab = jax.lax.dynamic_update_slice(
+            slab, rows[s][None, None], (layer, s, pos[s], 0, 0))
+    return slab
 
 
 # "gelu" is the EXACT erf form (torch.nn.TransformerEncoderLayer's
@@ -477,12 +506,13 @@ class TransformerEncoderLayer(_TransformerBlockBase):
             h = self.drop.apply({}, h, ctx=ctx.fold(3))
             return self.ln2.apply(params["ln2"], x + h, ctx=ctx)
 
-    def decode(self, params, x, cache, pos, tree=None):
+    def decode(self, params, x, cache, pos, tree=None, layer=None):
         """Incremental :meth:`apply` (inference: no dropout) — same math on
-        the new positions with attention served from the KV cache."""
+        the new positions with attention served from the KV cache
+        (``layer``: the slab form of :meth:`MultiHeadAttention.decode`)."""
         with device_scope(ATTENTION):
             a, cache = self.attn.decode(params["attn"], x, cache, pos,
-                                        tree=tree)
+                                        tree=tree, layer=layer)
             x = self.ln1.apply(params["ln1"], x + a)
         with device_scope(FFN):
             h = self.act(self.ff1.apply(params["ff1"], x))
@@ -514,13 +544,14 @@ class PreLNBlock(_TransformerBlockBase):
             h = self.ff2.apply(params["ff2"], h, ctx=ctx)
             return x + self.drop.apply({}, h, ctx=ctx.fold(2))
 
-    def decode(self, params, x, cache, pos, tree=None):
+    def decode(self, params, x, cache, pos, tree=None, layer=None):
         """Incremental :meth:`apply` (inference: no dropout) — same math on
-        the new positions with attention served from the KV cache."""
+        the new positions with attention served from the KV cache
+        (``layer``: the slab form of :meth:`MultiHeadAttention.decode`)."""
         with device_scope(ATTENTION):
             a, cache = self.attn.decode(params["attn"],
                                         self.ln1.apply(params["ln1"], x),
-                                        cache, pos, tree=tree)
+                                        cache, pos, tree=tree, layer=layer)
             x = x + a
         with device_scope(FFN):
             h = self.act(self.ff1.apply(params["ff1"],

@@ -372,6 +372,31 @@ class SingleDeviceSlotBackend:
 
     # -- device programs ---------------------------------------------------
 
+    def _run_layers(self, block_stack, h, caches, pos, tree=None):
+        """THE layer loop of every decode program (single-chunk and
+        resident, slab and paged views alike, and the speculative
+        verify): ``h [S, q, d]`` through all layers at per-slot
+        positions ``pos [S]``. The stacked cache ``[L, S, T, H, D]`` is
+        the loop's CARRY, never a scanned input or a stacked output —
+        each layer writes its ``S x q`` new rows into it and reads its
+        own layer of it (the slab form of ``block.decode``), so the
+        compiler keeps one buffer through the layer loop, the chunk scan
+        and the resident ``while`` instead of slicing a layer out,
+        stacking it back and copying the whole slab every step."""
+        m = self.model
+        cd = m.cfg.compute_dtype
+
+        def layer(carry, inp):
+            h, caches = carry
+            bp, l = inp
+            return m.block.decode(dequant_tree(bp, cd), h, caches, pos,
+                                  tree=tree, layer=l), None
+
+        (h, caches), _ = jax.lax.scan(
+            layer, (h, caches),
+            (block_stack, jnp.arange(self._n_layers, dtype=jnp.int32)))
+        return h, caches
+
     def _prefill_fn(self, block_stack, pre, post, caches, prompt,
                     true_len, slot, key):
         """One bucket-length-B prefill: runs the padded prompt through
@@ -410,12 +435,11 @@ class SingleDeviceSlotBackend:
     def _decode_fn(self, block_stack, pre, post, caches, tok, pos,
                    key_data):
         """THE decode step: ``decode_chunk`` tokens for all S slots in
-        one fixed-shape program. Per-slot positions ride a ``vmap`` over
-        the layer decode (the scalar-pos cache write becomes a batched
-        scatter). Traced exactly once — the counter below increments at
-        trace time only, pinning the zero-recompile claim."""
+        one fixed-shape program. Per-slot positions ride the slab form
+        of the layer decode (:meth:`_run_layers`). Traced exactly once —
+        the counter below increments at trace time only, pinning the
+        zero-recompile claim."""
         m, gen = self.model, self.gen
-        cd = m.cfg.compute_dtype
         get_registry().counter("serve.engine.decode_traces").inc()
         eos = gen.eos_token_id
 
@@ -429,20 +453,7 @@ class SingleDeviceSlotBackend:
                 caches, tok, pos, key_data, done = carry
             h = jax.vmap(embed_one)(tok, pos)              # [S, 1, d]
 
-            def layer(h, inp):
-                bp, cache = inp
-                bpd = dequant_tree(bp, cd)
-
-                def one(hh, cc, pp):
-                    out, cc2 = m.block.decode(
-                        bpd, hh[None],
-                        jax.tree_util.tree_map(lambda a: a[None], cc), pp)
-                    return out[0], jax.tree_util.tree_map(
-                        lambda a: a[0], cc2)
-
-                return jax.vmap(one)(h, cache, pos)
-
-            h, caches = jax.lax.scan(layer, h, (block_stack, caches))
+            h, caches = self._run_layers(block_stack, h, caches, pos)
             logits = head_logits(m, post, h)[:, 0, :]      # [S, V]
             keys = jax.random.wrap_key_data(key_data)
             ks = jax.vmap(jax.random.split)(keys)          # [S, 2] keys
@@ -588,21 +599,7 @@ class SingleDeviceSlotBackend:
                 views, tok, pos, key_data, done = carry
             h = jax.vmap(embed_one)(tok, pos)              # [S, 1, d]
 
-            def layer(h, inp):
-                bp, view_l = inp
-                bpd = dequant_tree(bp, cd)
-
-                def one(hh, cache_l, pp):
-                    cache = {name: cache_l[name][None]
-                             for name in ("k", "v")}
-                    out, c2 = m.block.decode(bpd, hh[None], cache, pp)
-                    return out[0], {name: c2[name][0]
-                                    for name in ("k", "v")}
-
-                h, view_l = jax.vmap(one)(h, view_l, pos)
-                return h, view_l
-
-            h, views = jax.lax.scan(layer, h, (block_stack, views))
+            h, views = self._run_layers(block_stack, h, views, pos)
             logits = head_logits(m, post, h)[:, 0, :]      # [S, V]
             keys = jax.random.wrap_key_data(key_data)
             ks = jax.vmap(jax.random.split)(keys)          # [S, 2] keys
@@ -643,11 +640,11 @@ class SingleDeviceSlotBackend:
     #
     # The resident loop is a `lax.while_loop` over the SAME per-chunk
     # math as the single-chunk programs above (the step bodies are
-    # duplicated, not refactored, so the non-resident paths stay
-    # byte-for-byte untouched). The carry adds three things the host
-    # used to own: a per-slot `done` mask (eos/length), a per-slot
-    # token `budget` (remaining max_new_tokens), and — paged — the
-    # `regather` flag, consumed and cleared on device. The loop exits
+    # duplicated around the one shared layer loop, `_run_layers`). The
+    # carry adds three things the host used to own: a per-slot `done`
+    # mask (eos/length), a per-slot token `budget` (remaining
+    # max_new_tokens), and — paged — the `regather` flag, consumed and
+    # cleared on device. The loop exits
     # early when any LIVE slot goes done (a slot freed: host admission
     # can change the slot set) or after `r_max` chunks (the deadline
     # horizon). One host sync per launch: the chunk count `k`, which
@@ -655,12 +652,11 @@ class SingleDeviceSlotBackend:
     # bitwise the single-chunk chain; tokens past a slot's eos/budget
     # are pad and the host's readout break reaches them never.
 
-    def _resident_step(self, block_stack, pre, post, carry, paged):
+    def _resident_step(self, block_stack, pre, post, carry):
         """One decode step shared by the two non-spec resident bodies:
         the exact `_decode_fn`/`_decode_paged_fn` step with the done
         mask extended by the token budget."""
         m, gen = self.model, self.gen
-        cd = m.cfg.compute_dtype
         eos = gen.eos_token_id
         caches, tok, pos, key_data, done, budget = carry
 
@@ -669,28 +665,7 @@ class SingleDeviceSlotBackend:
 
         h = jax.vmap(embed_one)(tok, pos)                  # [S, 1, d]
 
-        def layer(h, inp):
-            bp, cache = inp
-            bpd = dequant_tree(bp, cd)
-
-            if paged:
-                def one(hh, cache_l, pp):
-                    cache = {name: cache_l[name][None]
-                             for name in ("k", "v")}
-                    out, c2 = m.block.decode(bpd, hh[None], cache, pp)
-                    return out[0], {name: c2[name][0]
-                                    for name in ("k", "v")}
-            else:
-                def one(hh, cc, pp):
-                    out, cc2 = m.block.decode(
-                        bpd, hh[None],
-                        jax.tree_util.tree_map(lambda a: a[None], cc), pp)
-                    return out[0], jax.tree_util.tree_map(
-                        lambda a: a[0], cc2)
-
-            return jax.vmap(one)(h, cache, pos)
-
-        h, caches = jax.lax.scan(layer, h, (block_stack, caches))
+        h, caches = self._run_layers(block_stack, h, caches, pos)
         logits = head_logits(m, post, h)[:, 0, :]          # [S, V]
         keys = jax.random.wrap_key_data(key_data)
         ks = jax.vmap(jax.random.split)(keys)              # [S, 2] keys
@@ -729,7 +704,7 @@ class SingleDeviceSlotBackend:
             caches, tok, pos, key_data, done, budget, buf, k = state
             carry, toks = jax.lax.scan(
                 lambda c, _: self._resident_step(
-                    block_stack, pre, post, c, False),
+                    block_stack, pre, post, c),
                 (caches, tok, pos, key_data, done, budget), None, length=C)
             caches, tok, pos, key_data, done, budget = carry
             buf = jax.lax.dynamic_update_slice(
@@ -784,7 +759,7 @@ class SingleDeviceSlotBackend:
             pos0 = pos
             carry, toks = jax.lax.scan(
                 lambda c, _: self._resident_step(
-                    block_stack, pre, post, c, True),
+                    block_stack, pre, post, c),
                 (views, tok, pos, key_data, done, budget), None, length=C)
             views, tok, pos, key_data, done, budget = carry
             ridx = jax.vmap(lambda tr, p0: flat_row_index(
@@ -853,7 +828,6 @@ class SingleDeviceSlotBackend:
         the canonical positions before the round returns, so the next
         round's chunk reads them like any linear prefix."""
         m, gen = self.model, self.gen
-        cd = m.cfg.compute_dtype
         eos = gen.eos_token_id
         caches, tok, pos, key_data, hist, done, budget = carry
         S = tok.shape[0]
@@ -882,30 +856,7 @@ class SingleDeviceSlotBackend:
                 lambda xs, p: m.embed_tree(pre, xs[None], p, depths)[0])(
                     x, pos)
 
-        def layer(h, inp):
-            bp, cache = inp
-            bpd = dequant_tree(bp, cd)
-
-            if paged:
-                def one(hh, cache_l, pp):
-                    cache = {name: cache_l[name][None]
-                             for name in ("k", "v")}
-                    out, c2 = m.block.decode(bpd, hh[None], cache, pp,
-                                             tree=anc)
-                    return out[0], {name: c2[name][0]
-                                    for name in ("k", "v")}
-            else:
-                def one(hh, cc, pp):
-                    out, cc2 = m.block.decode(
-                        bpd, hh[None],
-                        jax.tree_util.tree_map(lambda a: a[None], cc),
-                        pp, tree=anc)
-                    return out[0], jax.tree_util.tree_map(
-                        lambda a: a[0], cc2)
-
-            return jax.vmap(one)(h, cache, pos)
-
-        h, caches = jax.lax.scan(layer, h, (block_stack, caches))
+        h, caches = self._run_layers(block_stack, h, caches, pos, tree=anc)
         logits = head_logits(m, post, h)                   # [S, Q, V]
 
         # 3) the sequential key chain, unrolled K deep: carries[i] is

@@ -511,3 +511,129 @@ def test_spec_headroom_tightens_validate(model_and_params):
                           max_len=24, resident=True,
                           buckets=BucketSpec.of(16))
     ServeEngine(plain).submit(list(range(1, 16)), max_new_tokens=8)
+
+
+# ---------------------------------------------------------------------------
+# the slab in the carry: the slab form of block.decode, and where the
+# decode programs keep the cache
+
+
+def _tree_mask(K, B):
+    from pipe_tpu.inference.draft import tree_layout
+    return jnp.asarray(tree_layout(K, B)[1])
+
+
+SLAB_FORM_CASES = [
+    ("post_ln", 1, None), ("post_ln", 3, None), ("post_ln", 5, (3, 2)),
+    ("pre_ln", 1, None), ("pre_ln", 3, None), ("pre_ln", 5, (3, 2)),
+]
+
+
+@pytest.mark.parametrize(
+    "family,q,tree", SLAB_FORM_CASES,
+    ids=[f"{f}-q{q}-{'tree' if t else 'linear'}"
+         for f, q, t in SLAB_FORM_CASES])
+def test_slab_form_matches_batch_form_over_slots(family, q, tree):
+    """``block.decode(..., layer=l)`` on the stacked ``[L, S, T, H, D]``
+    cache is bitwise the batch-1 form vmapped over the slots of layer
+    ``l`` (what the engine ran before the slab rode the carry): same
+    output, same rows written, every other layer untouched — per-slot
+    positions all different, ``q`` 1 and ``q`` > 1, linear and tree."""
+    from pipe_tpu.ops.layers import PreLNBlock, TransformerEncoderLayer
+    L, S, T, d, nh = 3, 4, 16, 32, 4
+    cls = TransformerEncoderLayer if family == "post_ln" else PreLNBlock
+    block = cls(d, nh, 64, 0.0)
+    ks = jax.random.split(jax.random.key(11), 4)
+    x = jax.random.normal(ks[0], (S, q, d))
+    bp = block.init(ks[1], x)
+    slab = {"k": jax.random.normal(ks[2], (L, S, T, nh, d // nh)),
+            "v": jax.random.normal(ks[3], (L, S, T, nh, d // nh))}
+    pos = jnp.asarray([0, 3, 7, 11], jnp.int32)     # 11 + 5 == T
+    anc = _tree_mask(*tree) if tree else None
+
+    def batch_form(x, slab, pos, l):
+        def one(hh, cc, pp):
+            out, cc2 = block.decode(
+                bp, hh[None],
+                jax.tree_util.tree_map(lambda a: a[None], cc), pp,
+                tree=anc)
+            return out[0], jax.tree_util.tree_map(lambda a: a[0], cc2)
+
+        layer_l = jax.tree_util.tree_map(lambda a: a[l], slab)
+        out, new_l = jax.vmap(one)(x, layer_l, pos)
+        return out, jax.tree_util.tree_map(
+            lambda a, n: a.at[l].set(n), slab, new_l)
+
+    def slab_form(x, slab, pos, l):
+        return block.decode(bp, x, slab, pos, tree=anc, layer=l)
+
+    for l in (0, L - 1):
+        want = jax.jit(batch_form)(x, slab, pos, jnp.int32(l))
+        got = jax.jit(slab_form)(x, slab, pos, jnp.int32(l))
+        np.testing.assert_array_equal(np.asarray(got[0]),
+                                      np.asarray(want[0]))
+        for name in ("k", "v"):
+            np.testing.assert_array_equal(np.asarray(got[1][name]),
+                                          np.asarray(want[1][name]))
+            assert not np.array_equal(np.asarray(got[1][name][l]),
+                                      np.asarray(slab[name][l]))
+
+
+def _scans(jaxpr):
+    """Every ``scan`` equation of a jaxpr, nested ones included."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "scan":
+            yield eqn
+        for v in eqn.params.values():
+            for sub in (v if isinstance(v, (list, tuple)) else (v,)):
+                inner = getattr(sub, "jaxpr", sub)
+                if hasattr(inner, "eqns"):
+                    yield from _scans(inner)
+
+
+def _slab_program(name, backend):
+    """(traced function, its arguments) of one of the slab backend's
+    decode programs."""
+    S = backend.num_slots
+    head = (backend._block_stack, backend._pre, backend._post,
+            backend._caches, backend._tok, backend._pos,
+            backend._key_data)
+    live, budget = jnp.ones((S,), bool), jnp.full((S,), 4, jnp.int32)
+    if name == "decode":
+        return backend._decode_fn, head
+    if name == "resident":
+        return backend._resident_fn, head + (live, budget, jnp.int32(2))
+    K = backend.spec_tokens
+    return (lambda *a: backend._resident_spec_fn(K, *a),
+            head + (backend._hist, live, budget, jnp.int32(2)))
+
+
+@pytest.mark.parametrize("program", ["decode", "resident", "spec"])
+def test_slab_rides_the_layer_loops_carry(program, model_and_params):
+    """The structural pin of the in-place cache: in every slab decode
+    program the ``[L, S, T, H, D]`` slab is a CARRY of the scan over
+    layers, and no scan anywhere takes it as a scanned input or gives it
+    back as a stacked output — the round trip (slice a layer out, stack
+    it back, copy the whole slab once a step) cannot return unseen."""
+    model, params = model_and_params
+    gen_cfg = GenerationConfig(max_new_tokens=6, temperature=0.0)
+    backend = _make_backend(
+        "single", model, params, gen_cfg, resident=True,
+        resident_chunks=2,
+        **({"spec_tokens": 3} if program == "spec" else {}))
+    slab_shape = backend._caches["k"].shape
+    assert len(slab_shape) == 5 and slab_shape[0] == CFG.n_layers
+    fn, args = _slab_program(program, backend)
+    scans = list(_scans(jax.make_jaxpr(fn)(*args).jaxpr))
+    carried = 0
+    for eqn in scans:
+        nc, nk = eqn.params["num_consts"], eqn.params["num_carry"]
+        xs = [v.aval.shape for v in eqn.invars[nc + nk:]]
+        ys = [v.aval.shape for v in eqn.outvars[nk:]]
+        assert slab_shape not in xs, "the slab is a scanned input"
+        assert slab_shape not in ys, "the slab is a stacked output"
+        carry = [v.aval.shape for v in eqn.invars[nc:nc + nk]]
+        if eqn.params["length"] == CFG.n_layers and slab_shape in carry:
+            assert carry.count(slab_shape) == 2          # k and v
+            carried += 1
+    assert carried >= 1, "no scan over layers carries the slab"
