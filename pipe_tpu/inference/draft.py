@@ -35,8 +35,6 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from .quant import dequant_tree
-
 __all__ = ["DraftSource", "NgramDraft", "TruncatedDraft", "TreeDraft",
            "tree_layout", "resolve_draft"]
 
@@ -71,13 +69,17 @@ class DraftSource:
     for each slot, ``branches`` candidate continuations of the current
     token. The caches come back because prefix drafters write real KV
     rows at positions >= ``pos`` — all of them re-written by the verify
-    chunk before any unmasked read (the rollback-overwrite law)."""
+    chunk before any unmasked read (the rollback-overwrite law).
+    ``run_layers(block_stack, h, caches, pos)`` is the engine's layer
+    loop over the carried cache (``SingleDeviceSlotBackend._run_layers``:
+    ``[L, S, T, C]``, heads folded, slab and paged views alike); a
+    drafter that runs layers runs them through it."""
 
     name = "?"
     branches = 1
 
-    def propose(self, m, gen, pre, block_stack, caches, tok, pos, hist,
-                K: int, paged: bool):
+    def propose(self, run_layers, m, pre, block_stack, caches, tok, pos,
+                hist, K: int):
         raise NotImplementedError
 
     def draft_cost_frac(self, K: int, n_layers: int) -> float:
@@ -97,8 +99,8 @@ class NgramDraft(DraftSource):
 
     name = "ngram"
 
-    def propose(self, m, gen, pre, block_stack, caches, tok, pos, hist,
-                K, paged):
+    def propose(self, run_layers, m, pre, block_stack, caches, tok, pos,
+                hist, K):
         H = hist.shape[1]
         idx = jnp.arange(H, dtype=jnp.int32)
 
@@ -127,50 +129,24 @@ def _tied_logits(m, pre, h):
     return h.astype(jnp.float32) @ table.T
 
 
-def _draft_step(m, dstack, dcaches, pre, tok, pos, paged):
+def _draft_step(run_layers, m, dstack, caches, pre, tok, pos):
     """One q=1 greedy step through the draft-layer prefix: embeds
     ``tok`` at ``pos``, writes KV row ``pos`` in every draft layer,
     returns the tied-head hidden state ``[S, d]`` and updated caches.
-    Mirrors the verify chunk's per-layer vmap exactly (same
-    ``block.decode``), so draft rows are bitwise what the verify would
-    write for the same (token, position)."""
-    cd = m.cfg.compute_dtype
+    ``run_layers`` is the engine's own layer loop over the carried
+    cache ``[L, S, T, C]`` (the slab form of ``block.decode``) run
+    on the ``Ld`` layers of ``dstack``: it writes the first ``Ld``
+    layers of ``caches`` in place and leaves the rest alone, so draft
+    rows are bitwise what the verify would write for the same (token,
+    position), and nothing is sliced out of the cache or joined back."""
     h = jax.vmap(
         lambda t, p: m.embed_at(pre, t[None, None], p)[0])(tok, pos)
-
-    def layer(h, inp):
-        bp, cache = inp
-        bpd = dequant_tree(bp, cd)
-
-        if paged:
-            def one(hh, cache_l, pp):
-                cache = {name: cache_l[name][None]
-                         for name in ("k", "v")}
-                out, c2 = m.block.decode(bpd, hh[None], cache, pp)
-                return out[0], {name: c2[name][0]
-                                for name in ("k", "v")}
-        else:
-            def one(hh, cc, pp):
-                out, cc2 = m.block.decode(
-                    bpd, hh[None],
-                    jax.tree_util.tree_map(lambda a: a[None], cc), pp)
-                return out[0], jax.tree_util.tree_map(
-                    lambda a: a[0], cc2)
-
-        return jax.vmap(one)(h, cache, pos)
-
-    h, dcaches = jax.lax.scan(layer, h, (dstack, dcaches))
-    return h[:, 0], dcaches
+    h, caches = run_layers(dstack, h, caches, pos)
+    return h[:, 0], caches
 
 
 def _slice_draft(tree, Ld):
     return jax.tree_util.tree_map(lambda a: a[:Ld], tree)
-
-
-def _merge_draft(dcaches, caches, Ld):
-    return jax.tree_util.tree_map(
-        lambda d, full: jnp.concatenate([d, full[Ld:]], axis=0),
-        dcaches, caches)
 
 
 class TruncatedDraft(DraftSource):
@@ -186,22 +162,20 @@ class TruncatedDraft(DraftSource):
                 f"{draft_layers}")
         self.draft_layers = draft_layers
 
-    def propose(self, m, gen, pre, block_stack, caches, tok, pos, hist,
-                K, paged):
-        Ld = self.draft_layers
-        dstack = _slice_draft(block_stack, Ld)
-        dcaches = _slice_draft(caches, Ld)
+    def propose(self, run_layers, m, pre, block_stack, caches, tok, pos,
+                hist, K):
+        dstack = _slice_draft(block_stack, self.draft_layers)
         cur, p = tok, pos
         outs = []
         for _ in range(K - 1):
-            h, dcaches = _draft_step(m, dstack, dcaches, pre, cur, p,
-                                     paged)
+            h, caches = _draft_step(run_layers, m, dstack, caches, pre,
+                                    cur, p)
             cur = jnp.argmax(_tied_logits(m, pre, h),
                              axis=-1).astype(jnp.int32)
             outs.append(cur)
             p = p + 1
         drafts = jnp.stack(outs, axis=1)                   # [S, K-1]
-        return drafts[:, None, :], _merge_draft(dcaches, caches, Ld)
+        return drafts[:, None, :], caches
 
     def draft_cost_frac(self, K, n_layers):
         d = (K - 1) * self.draft_layers
@@ -227,28 +201,28 @@ class TreeDraft(DraftSource):
         self.branches = branches
         self.draft_layers = draft_layers
 
-    def propose(self, m, gen, pre, block_stack, caches, tok, pos, hist,
-                K, paged):
+    def propose(self, run_layers, m, pre, block_stack, caches, tok, pos,
+                hist, K):
         Ld, B = self.draft_layers, self.branches
         S = tok.shape[0]
         dstack = _slice_draft(block_stack, Ld)
-        dcaches = _slice_draft(caches, Ld)
         # shared root step: writes row `pos` in the real draft caches
-        h, dcaches = _draft_step(m, dstack, dcaches, pre, tok, pos,
-                                 paged)
+        h, caches = _draft_step(run_layers, m, dstack, caches, pre, tok,
+                                pos)
         first = jax.lax.top_k(_tied_logits(m, pre, h), B)[1] \
             .astype(jnp.int32)                              # [S, B]
         if K > 2:
-            # per-branch private rollouts: tile the draft caches along
-            # the slot axis (S*B pseudo-slots) and reuse the same step
+            # per-branch private rollouts: tile the draft layers' caches
+            # along the slot axis (S*B pseudo-slots), reuse the same step
             bcaches = jax.tree_util.tree_map(
-                lambda a: jnp.repeat(a, B, axis=1), dcaches)
+                lambda a: jnp.repeat(a, B, axis=1),
+                _slice_draft(caches, Ld))
             cur = first.reshape(-1)
             p = jnp.repeat(pos + 1, B)
             outs = [cur]
             for _ in range(K - 2):
-                h, bcaches = _draft_step(m, dstack, bcaches, pre, cur,
-                                         p, paged)
+                h, bcaches = _draft_step(run_layers, m, dstack, bcaches,
+                                         pre, cur, p)
                 cur = jnp.argmax(_tied_logits(m, pre, h),
                                  axis=-1).astype(jnp.int32)
                 outs.append(cur)
@@ -256,7 +230,7 @@ class TreeDraft(DraftSource):
             drafts = jnp.stack(outs, axis=1).reshape(S, B, K - 1)
         else:
             drafts = first[:, :, None]                      # [S, B, 1]
-        return drafts, _merge_draft(dcaches, caches, Ld)
+        return drafts, caches
 
     def draft_cost_frac(self, K, n_layers):
         steps = 1 + self.branches * max(K - 2, 0)

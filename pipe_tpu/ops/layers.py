@@ -29,6 +29,7 @@ __all__ = [
     "Module", "Sequential", "Lambda", "Linear", "Embedding", "LayerNorm",
     "Dropout", "MultiHeadAttention", "TransformerEncoderLayer",
     "PreLNBlock", "PositionalEncoding", "Decoder", "spec",
+    "slab_width", "fold_heads", "unfold_heads",
 ]
 
 
@@ -328,6 +329,15 @@ class MultiHeadAttention(Module):
         dt = dtype if dtype is not None else self.dtype
         return {"k": jnp.zeros(shape, dt), "v": jnp.zeros(shape, dt)}
 
+    def make_slab(self, layers: int, slots: int, max_len: int, dtype=None):
+        """Zeroed stacked KV cache for the SLAB form of :meth:`decode`
+        (``layer=``): ``{"k","v"}`` of ``[layers, slots, max_len, C]``,
+        a cache row the ``nhead * head_dim`` values of :func:`fold_heads`."""
+        shape = (layers, slots, max_len,
+                 slab_width(self.nhead, self.head_dim))
+        dt = dtype if dtype is not None else self.dtype
+        return {"k": jnp.zeros(shape, dt), "v": jnp.zeros(shape, dt)}
+
     def decode(self, params, x, cache, pos, tree=None, layer=None):
         """Incremental self-attention with a KV cache (inference only).
 
@@ -348,12 +358,32 @@ class MultiHeadAttention(Module):
 
         ``layer`` (optional traced index): the SLAB form, for a loop
         over layers that carries every layer's cache. ``cache`` is then
-        the stacked ``{"k","v"}`` of ``[L, S, T, H, D]``, ``x`` is
+        :meth:`make_slab`'s stacked ``{"k","v"}`` of ``[L, S, T, C]``:
+        a cache row is its heads folded into one axis and zero-padded
+        to whole lane tiles (:func:`fold_heads`; ``C`` 1664 for
+        gpt2-xl's 25 x 64), the rows second to last. ``x`` is
         ``[S, q, d]`` (a row per slot) and ``pos`` a per-slot vector
         ``[S]``. Only the ``S x q`` new rows are written, at ``(layer,
         s, pos[s])``, and only ``cache[layer]`` is read, so the slab
-        stays one buffer updated in place. Same math as the batch form
-        vmapped over slots. Returns ``(out [S, q, d], slab)``.
+        stays one buffer updated in place.
+
+        Why this shape (PERF.md, PR 29). A TPU tiles an array's two
+        minor dimensions, 16 x 128 for bf16. Rows of ``[H, D]`` (the
+        batch form's, and the slab's until PR 29) end in 25 x 64, which
+        pads to 32 x 128: 2.6x the bytes on every read of the cache.
+        ``T x C`` is whole tiles (4% of padding), one cache row is 13
+        tiles side by side, so the row write touches 13 and not the 100
+        it touches when the rows are the lanes, and the slab as the
+        program's argument, the loops' carry and the operand of the two
+        reads have one layout, with no relayout around a launch. The
+        reads are matrix products over the folded axis: the scores
+        ``[q*H, C] x [T, C]^T`` with each head's query in its own block
+        of ``C`` and zeros elsewhere, the mix ``[q*H, T] x [T, C]`` of
+        which each head keeps its own block. The zeros cost the matrix
+        unit ``H`` times the products and the memory nothing, and a
+        decode step is bound by the memory. Same math as the batch form
+        vmapped over slots (a sum gains exact zeros), whose cache stays
+        ``[b, T, H, D]``. Returns ``(out [S, q, d], slab)``.
         """
         if not self.causal:
             raise ValueError("KV-cache decode requires causal attention")
@@ -380,13 +410,17 @@ class MultiHeadAttention(Module):
             else:
                 cache = {n: _write_slab_rows(cache[n], rows[n], layer, pos)
                          for n in rows}
-                ck, cv = (jax.lax.dynamic_index_in_dim(
+                ck, cv = (jax.lax.dynamic_index_in_dim(          # [S, T, C]
                     cache[n], layer, 0, keepdims=False) for n in ("k", "v"))
-            logits = jnp.einsum("bqhd,bkhd->bhqk", qh, ck).astype(
-                jnp.float32)
+            if layer is None:
+                logits = jnp.einsum("bqhd,bkhd->bhqk", qh, ck)
+            else:
+                logits = jnp.einsum("bqhc,bkc->bhqk",
+                                    _own_blocks(qh, ck.shape[-1]), ck)
+            logits = logits.astype(jnp.float32)
         logits = logits / math.sqrt(hd)
         # a scalar pos is every row's; the slab form's is one per row
-        rel = (jnp.arange(ck.shape[1])[None, :]
+        rel = (jnp.arange(logits.shape[-1])[None, :]
                - jnp.reshape(pos, (-1, 1)))                # [1|b, K_cache]
         if tree is None:
             allowed = (rel[:, None, :]
@@ -401,21 +435,75 @@ class MultiHeadAttention(Module):
                            jnp.asarray(-1e30, logits.dtype))
         weights = jax.nn.softmax(logits, axis=-1).astype(x.dtype)
         with device_scope(KV_CACHE):
-            o = jnp.einsum("bhqk,bkhd->bqhd", weights, cv)
+            if layer is None:
+                o = jnp.einsum("bhqk,bkhd->bqhd", weights, cv)
+            else:
+                o = _own_blocks_of(
+                    jnp.einsum("bhqk,bkc->bqhc", weights, cv), hd)
         o = o.reshape(b, q, self.d_model)
         out = jnp.einsum("bsd,de->bse", o, params["wo"]) + params["bo"]
         return out, cache
 
 
+def slab_width(nhead: int, head_dim: int) -> int:
+    """Width ``C`` of a cache row in the slab form: ``nhead * head_dim``
+    rounded up to whole lane tiles of 128. The rounding is what makes
+    the folded axis the TPU's minor-most: of an array whose last two
+    extents are both whole tiles the compiler keeps the order written,
+    and otherwise it turns the array so that the padding is least
+    (``[.., 640, 1600]`` it lays out rows minor-most; PERF.md, PR 29)."""
+    return -(-nhead * head_dim // 128) * 128
+
+
+def fold_heads(rows):
+    """``[..., H, D]`` -> ``[..., C]``: a cache row as the slab form
+    keeps it, the heads folded into one axis and zero-padded to
+    :func:`slab_width`. The padding takes part in nothing: the scores
+    multiply it by the zeros of :func:`_own_blocks`, and the mix's is
+    cut off (:func:`unfold_heads`, :func:`_own_blocks_of`)."""
+    h, hd = rows.shape[-2:]
+    flat = rows.reshape(rows.shape[:-2] + (h * hd,))
+    pad = slab_width(h, hd) - h * hd
+    return jnp.pad(flat, ((0, 0),) * (flat.ndim - 1) + ((0, pad),))
+
+
+def unfold_heads(rows, nhead: int, head_dim: int):
+    """``[..., C]`` -> ``[..., H, D]``: :func:`fold_heads` undone."""
+    return rows[..., :nhead * head_dim].reshape(
+        rows.shape[:-1] + (nhead, head_dim))
+
+
+def _own_blocks(qh, width: int):
+    """``qh [b, q, H, D]`` -> ``[b, q, H, C]``: head ``h``'s query in
+    block ``h`` of the folded axis and zeros in every other, so that one
+    product with folded cache rows ``[T, C]`` gives each head the scores
+    of its own keys."""
+    b, q, h, hd = qh.shape
+    eye = jnp.eye(h, dtype=qh.dtype)
+    blocks = qh[:, :, :, None, :] * eye[None, None, :, :, None]
+    return jnp.pad(blocks.reshape(b, q, h, h * hd),
+                   ((0, 0),) * 3 + ((0, width - h * hd),))
+
+
+def _own_blocks_of(o, head_dim: int):
+    """``o [b, q, H, C]`` (each head's weights mixed over every head's
+    folded values) -> ``[b, q, H, D]``: head ``h`` keeps block ``h``."""
+    b, q, h, _ = o.shape
+    blocks = o[..., :h * head_dim].reshape(b, q, h, h, head_dim)
+    return jnp.einsum("bqhgd,hg->bqhd", blocks, jnp.eye(h, dtype=o.dtype))
+
+
 def _write_slab_rows(slab, rows, layer, pos):
-    """``rows [S, q, H, D]`` into ``slab [L, S, T, H, D]`` at ``(layer,
-    s, pos[s])``: one ``dynamic_update_slice`` a slot (its clamping is
-    the batch form's), each on the buffer the last one left. Unrolled
-    over the slots on purpose: on the v5e one scatter of ``S`` windows
-    runs as a loop and cost four times as much (PERF.md, PR 26)."""
+    """``rows [S, q, H, D]`` into ``slab [L, S, T, C]`` at ``(layer, s,
+    pos[s])``, folded (:func:`fold_heads`): one ``dynamic_update_slice``
+    a slot (its clamping is the batch form's), each on the buffer the
+    last one left. Unrolled over the slots on purpose: on the v5e one
+    scatter of ``S`` windows runs as a loop and cost four times as much
+    (PERF.md, PR 26)."""
+    rows = fold_heads(rows)                                # [S, q, C]
     for s in range(rows.shape[0]):
         slab = jax.lax.dynamic_update_slice(
-            slab, rows[s][None, None], (layer, s, pos[s], 0, 0))
+            slab, rows[s][None, None], (layer, s, pos[s], 0))
     return slab
 
 

@@ -55,6 +55,7 @@ from ..inference.quant import QuantLeaf, dequant_tree
 from ..obs import events as ev
 from ..obs.events import NULL_EVENT_LOG, REQUEST
 from ..obs.telemetry import get_registry, host_overhead_per_token
+from ..ops.layers import fold_heads, unfold_heads
 from .buckets import BucketSpec
 from .kvpool import (HostKvStore, KvPool, PoolExhausted, block_demand,
                      copy_block, flat_row_index, gather_block_cache,
@@ -260,12 +261,9 @@ class SingleDeviceSlotBackend:
             # the decode program re-gathers from the (always-current)
             # pool. Compute dtype even for int8 pools: the view is the
             # dequantized working set.
-            R = self.pool.max_blocks * kbs
-            self._views = {
-                name: jnp.zeros(
-                    (self._n_layers, num_slots, R) + proto[name].shape[2:],
-                    cd)
-                for name in ("k", "v")}
+            self._views = model.block.attn.make_slab(
+                self._n_layers, num_slots, self.pool.max_blocks * kbs,
+                dtype=cd)
             self._views_dirty = True
         else:
             if kv_dtype is not None:
@@ -279,10 +277,8 @@ class SingleDeviceSlotBackend:
             self.kv_offload = False
             self._kv_store = None
             self.pool = None
-            self._caches = jax.tree_util.tree_map(
-                lambda a: jnp.zeros(
-                    (self._n_layers, num_slots) + a.shape[1:], a.dtype),
-                proto)
+            self._caches = model.block.attn.make_slab(
+                self._n_layers, num_slots, max_len, dtype=cd)
             self._decode_jit = jax.jit(self._decode_fn, donate_argnums=(3,))
         self._tok = jnp.zeros((num_slots,), jnp.int32)
         self._pos = jnp.zeros((num_slots,), jnp.int32)
@@ -374,15 +370,25 @@ class SingleDeviceSlotBackend:
 
     def _run_layers(self, block_stack, h, caches, pos, tree=None):
         """THE layer loop of every decode program (single-chunk and
-        resident, slab and paged views alike, and the speculative
-        verify): ``h [S, q, d]`` through all layers at per-slot
-        positions ``pos [S]``. The stacked cache ``[L, S, T, H, D]`` is
-        the loop's CARRY, never a scanned input or a stacked output —
-        each layer writes its ``S x q`` new rows into it and reads its
-        own layer of it (the slab form of ``block.decode``), so the
-        compiler keeps one buffer through the layer loop, the chunk scan
-        and the resident ``while`` instead of slicing a layer out,
-        stacking it back and copying the whole slab every step."""
+        resident, slab and paged views alike, the speculative verify
+        and the truncated drafters): ``h [S, q, d]`` through all layers
+        at per-slot positions ``pos [S]``. The stacked cache
+        ``[L, S, T, C]`` (``attn.make_slab``: a cache row is its heads
+        folded into one axis of whole lane tiles) is the loop's CARRY,
+        never a scanned input or a stacked output — each layer writes
+        its ``S x q`` new rows into it and reads its own layer of it
+        (the slab form of ``block.decode``), so the compiler keeps one
+        buffer through the layer loop, the chunk scan and the resident
+        ``while`` instead of slicing a layer out, stacking it back and
+        copying the whole slab every step. Everything carried here has
+        this one shape (slab, paged views, the tree drafter's copies:
+        one layout, chosen by nothing): ``T x C`` is whole tiles on the
+        TPU, so the slab as the program's argument, the carry and the
+        two reads of a layer share one layout with 4% of padding where
+        rows of ``[H, D]`` had 2.6x, no launch relays it, and a row
+        write touches 13 tiles (``MultiHeadAttention.decode``; PERF.md,
+        PR 29). ``block_stack`` may hold fewer layers than the cache:
+        the loop then runs the first ones (a truncated drafter)."""
         m = self.model
         cd = m.cfg.compute_dtype
 
@@ -392,18 +398,22 @@ class SingleDeviceSlotBackend:
             return m.block.decode(dequant_tree(bp, cd), h, caches, pos,
                                   tree=tree, layer=l), None
 
+        n = jax.tree_util.tree_leaves(block_stack)[0].shape[0]
         (h, caches), _ = jax.lax.scan(
             layer, (h, caches),
-            (block_stack, jnp.arange(self._n_layers, dtype=jnp.int32)))
+            (block_stack, jnp.arange(n, dtype=jnp.int32)))
         return h, caches
 
     def _prefill_fn(self, block_stack, pre, post, caches, prompt,
                     true_len, slot, key):
         """One bucket-length-B prefill: runs the padded prompt through
-        every layer against a fresh full-length temp cache, then writes
-        the ENTIRE slot slab (previous occupant's rows are gone, not
-        merely masked) and samples the first token with the exact
-        batch-1 Generator key chain."""
+        every layer against a fresh full-length temp cache (the batch
+        form's ``[L, 1, T, H, D]``: a prompt's rows are a matrix, and
+        the batch form reads and writes them as one), then writes the
+        ENTIRE slot slab (previous occupant's rows are gone, not merely
+        masked), folding its heads once on the way into the carried
+        layout ``[L, S, T, C]``, and samples the first token with the
+        exact batch-1 Generator key chain."""
         m, gen = self.model, self.gen
         cd = m.cfg.compute_dtype
         get_registry().counter("serve.engine.prefill_traces").inc()
@@ -423,7 +433,7 @@ class SingleDeviceSlotBackend:
         with ev.device_scope(ev.KV_CACHE):       # the slot's slab, whole
             caches = jax.tree_util.tree_map(
                 lambda big, rows: jax.lax.dynamic_update_slice(
-                    big, rows, (0, slot) + (0,) * (rows.ndim - 2)),
+                    big, fold_heads(rows), (0, slot, 0, 0)),
                 caches, temp)
         h_last = jax.lax.dynamic_slice(
             h, (0, true_len - 1, 0), (1, 1, h.shape[-1]))
@@ -552,6 +562,49 @@ class SingleDeviceSlotBackend:
                     pool_kv[name], rows[:, None], dst, axis=1)
         return out
 
+    def _gather_views(self, pool_kv, tables, views, regather):
+        """The per-slot block views ``[L, S, R, C]`` (the carried
+        layout of :meth:`_run_layers`, heads folded): gathered afresh
+        from the pool through each slot's first ``max_blocks`` table
+        entries iff ``regather`` (traced), else the carried ones. The
+        gather is a whole copy anyway; the fold rides it."""
+        bs = self.pool.block_size
+        cd = self.model.cfg.compute_dtype
+        view_t = tables[:, :self.pool.max_blocks + 1]
+
+        def gather_layer(pool_l):
+            out = jax.vmap(lambda tr: gather_block_cache(
+                pool_l, tr, block_size=bs, compute_dtype=cd))(view_t)
+            return {name: fold_heads(a[:, 0])
+                    for name, a in out.items()}            # [S, R, C]
+
+        return jax.lax.cond(
+            regather, lambda v: jax.vmap(gather_layer)(pool_kv),
+            lambda v: v, views)
+
+    def _scatter_view_rows(self, pool_kv, views, tables, pos0, n):
+        """The ``n`` rows every slot wrote into its view from ``pos0``
+        on, unfolded, back into the pool through the FULL-width
+        tables."""
+        bs = self.pool.block_size
+        attn = self.model.block.attn
+        ridx = jax.vmap(lambda tr, p0: flat_row_index(
+            tr, p0 + jnp.arange(n, dtype=jnp.int32), bs))(tables, pos0)
+
+        def scat_layer(_, inp):
+            pool_l, view_l = inp
+            rows = {}
+            for name in ("k", "v"):
+                new = jax.vmap(
+                    lambda v, p0: jax.lax.dynamic_slice_in_dim(v, p0, n))(
+                        view_l[name], pos0)                # [S, n, C]
+                rows[name] = unfold_heads(                 # [S*n, H, D]
+                    new.reshape(-1, new.shape[-1]), attn.nhead,
+                    attn.head_dim)
+            return 0, scatter_block_rows(pool_l, ridx.reshape(-1), rows)
+
+        return jax.lax.scan(scat_layer, 0, (pool_kv, views))[1]
+
     def _decode_paged_fn(self, block_stack, pre, post, pool_kv, tables,
                          tok, pos, key_data, views, regather):
         """The paged decode step: each slot's block view — its first
@@ -570,27 +623,15 @@ class SingleDeviceSlotBackend:
         reallocated block. Traced once; the same counter as the slab
         path pins zero steady-state recompiles."""
         m, gen = self.model, self.gen
-        cd = m.cfg.compute_dtype
         get_registry().counter("serve.engine.decode_traces").inc()
         eos = gen.eos_token_id
-        bs = self.pool.block_size
         C = self.decode_chunk
-        S = tok.shape[0]
         pos0 = pos
 
         def embed_one(t, p):
             return m.embed_at(pre, t[None, None], p)[0]    # [1, d]
 
-        view_t = tables[:, :self.pool.max_blocks + 1]
-
-        def gather_layer(pool_l):
-            out = jax.vmap(lambda tr: gather_block_cache(
-                pool_l, tr, block_size=bs, compute_dtype=cd))(view_t)
-            return {name: a[:, 0] for name, a in out.items()}  # [S, R, .]
-
-        views = jax.lax.cond(
-            regather, lambda v: jax.vmap(gather_layer)(pool_kv),
-            lambda v: v, views)                        # [L, S, R, ...]
+        views = self._gather_views(pool_kv, tables, views, regather)
 
         def step(carry, _):
             if eos is None:
@@ -620,20 +661,7 @@ class SingleDeviceSlotBackend:
         views, tok, pos, key_data = carry[:4]
 
         # rows written this chunk, back through the full-width tables
-        ridx = jax.vmap(lambda tr, p0: flat_row_index(
-            tr, p0 + jnp.arange(C, dtype=jnp.int32), bs))(tables, pos0)
-
-        def scat_layer(_, inp):
-            pool_l, view_l = inp
-            rows = {name: jax.vmap(
-                lambda v, p0: jax.lax.dynamic_slice(
-                    v, (p0,) + (0,) * (v.ndim - 1),
-                    (C,) + v.shape[1:]))(view_l[name], pos0).reshape(
-                        (S * C,) + view_l[name].shape[2:])
-                for name in ("k", "v")}
-            return 0, scatter_block_rows(pool_l, ridx.reshape(-1), rows)
-
-        _, pool_kv = jax.lax.scan(scat_layer, 0, (pool_kv, views))
+        pool_kv = self._scatter_view_rows(pool_kv, views, tables, pos0, C)
         return pool_kv, tok, pos, key_data, views, jnp.moveaxis(toks, 0, 1)
 
     # -- resident device programs ------------------------------------------
@@ -736,23 +764,11 @@ class SingleDeviceSlotBackend:
         flag and performs zero host-driven gather decisions. The
         2-branch cond is a role conditional (both branches produce the
         same view shape), not a dispatch."""
-        m = self.model
-        cd = m.cfg.compute_dtype
         get_registry().counter("serve.engine.resident_traces").inc()
-        bs = self.pool.block_size
         C = self.decode_chunk
         R = self.resident_chunks
         S = tok.shape[0]
-        view_t = tables[:, :self.pool.max_blocks + 1]
-
-        def gather_layer(pool_l):
-            out = jax.vmap(lambda tr: gather_block_cache(
-                pool_l, tr, block_size=bs, compute_dtype=cd))(view_t)
-            return {name: a[:, 0] for name, a in out.items()}
-
-        views = jax.lax.cond(
-            regather, lambda v: jax.vmap(gather_layer)(pool_kv),
-            lambda v: v, views)                            # [L, S, R, ...]
+        views = self._gather_views(pool_kv, tables, views, regather)
 
         def body(state):
             pool_kv, views, tok, pos, key_data, done, budget, buf, k = state
@@ -762,20 +778,8 @@ class SingleDeviceSlotBackend:
                     block_stack, pre, post, c),
                 (views, tok, pos, key_data, done, budget), None, length=C)
             views, tok, pos, key_data, done, budget = carry
-            ridx = jax.vmap(lambda tr, p0: flat_row_index(
-                tr, p0 + jnp.arange(C, dtype=jnp.int32), bs))(tables, pos0)
-
-            def scat_layer(_, inp):
-                pool_l, view_l = inp
-                rows = {name: jax.vmap(
-                    lambda v, p0: jax.lax.dynamic_slice(
-                        v, (p0,) + (0,) * (v.ndim - 1),
-                        (C,) + v.shape[1:]))(view_l[name], pos0).reshape(
-                            (S * C,) + view_l[name].shape[2:])
-                    for name in ("k", "v")}
-                return 0, scatter_block_rows(pool_l, ridx.reshape(-1), rows)
-
-            _, pool_kv = jax.lax.scan(scat_layer, 0, (pool_kv, views))
+            pool_kv = self._scatter_view_rows(pool_kv, views, tables,
+                                              pos0, C)
             buf = jax.lax.dynamic_update_slice(
                 buf, jnp.moveaxis(toks, 0, 1), (0, k * C))
             return (pool_kv, views, tok, pos, key_data, done, budget,
@@ -812,7 +816,7 @@ class SingleDeviceSlotBackend:
     # consumes exactly n_emit splits, so accepted tokens are bitwise
     # the sequential Generator chain.
 
-    def _spec_round(self, K, block_stack, pre, post, carry, paged):
+    def _spec_round(self, K, block_stack, pre, post, carry):
         """One draft/verify round (shared by the slab/paged spec
         bodies) at ladder depth ``K``. Carry: (caches-or-views, tok,
         pos, key_data, hist, done, budget); returns the updated carry
@@ -832,12 +836,12 @@ class SingleDeviceSlotBackend:
         caches, tok, pos, key_data, hist, done, budget = carry
         S = tok.shape[0]
         B = self._drafter.branches
-        Q = 1 + B * (K - 1) if B > 1 else K
         ar = jnp.arange(K, dtype=jnp.int32)
 
         # 1) draft: [S, B, K-1] candidate continuations of tok
         drafts, caches = self._drafter.propose(
-            m, gen, pre, block_stack, caches, tok, pos, hist, K, paged)
+            self._run_layers, m, pre, block_stack, caches, tok, pos, hist,
+            K)
 
         # 2) verify: ONE fixed-shape q=Q teacher-forced decode. Linear
         # (B=1) keeps the PR 11 chunk byte-for-byte; tree embeds each
@@ -913,16 +917,17 @@ class SingleDeviceSlotBackend:
             # advanced pos' are junk-allowed (causally masked, and the
             # next round's Q-row write covers them), so the whole
             # branch copies unconditionally.
-            arr = jnp.arange(K - 1, dtype=jnp.int32)
-
-            def rl(a):          # [L, S, rows, ...] (slab slab-rows or
-                def ps(al, p, sb):              # paged view-rows alike)
-                    src = p + 1 + sb * (K - 1) + arr
-                    rows = jnp.take(al, src, axis=1)
-                    return jax.lax.dynamic_update_slice(
-                        al, rows, (0, p + 1) + (0,) * (al.ndim - 2))
-                return jax.vmap(ps, in_axes=(1, 0, 0),
-                                out_axes=1)(a, pos, bsel)
+            # In place, a slot at a time, as a layer writes its rows
+            # (slab rows and paged view rows alike): no copy of the
+            # carried cache is made or transposed.
+            def rl(a):                              # [L, S, rows, C]
+                for s in range(S):
+                    rows = jax.lax.dynamic_slice(
+                        a, (0, s, pos[s] + 1 + bsel[s] * (K - 1), 0),
+                        (a.shape[0], 1, K - 1, a.shape[3]))
+                    a = jax.lax.dynamic_update_slice(
+                        a, rows, (0, s, pos[s] + 1, 0))
+                return a
 
             caches = jax.tree_util.tree_map(rl, caches)
 
@@ -962,7 +967,7 @@ class SingleDeviceSlotBackend:
             (caches, tok, pos, key_data, hist, done, budget, toks,
              n_emit) = self._spec_round(
                 K, block_stack, pre, post,
-                (caches, tok, pos, key_data, hist, done, budget), False)
+                (caches, tok, pos, key_data, hist, done, budget))
             buf = jax.lax.dynamic_update_slice(buf, toks, (0, k * K))
             nacc = jax.lax.dynamic_update_slice(
                 nacc, n_emit[:, None], (0, k))
@@ -991,24 +996,12 @@ class SingleDeviceSlotBackend:
         scatter back through the full-width tables (rejected/dead rows
         route to the sacrificial block exactly like dead-slot
         decode)."""
-        m = self.model
-        cd = m.cfg.compute_dtype
         get_registry().counter("serve.engine.resident_traces").inc()
-        bs = self.pool.block_size
         B = self._drafter.branches
         Q = 1 + B * (K - 1) if B > 1 else K
         R = self.resident_chunks
         S = tok.shape[0]
-        view_t = tables[:, :self.pool.max_blocks + 1]
-
-        def gather_layer(pool_l):
-            out = jax.vmap(lambda tr: gather_block_cache(
-                pool_l, tr, block_size=bs, compute_dtype=cd))(view_t)
-            return {name: a[:, 0] for name, a in out.items()}
-
-        views = jax.lax.cond(
-            regather, lambda v: jax.vmap(gather_layer)(pool_kv),
-            lambda v: v, views)
+        views = self._gather_views(pool_kv, tables, views, regather)
 
         def body(state):
             pool_kv, views, tok, pos, key_data, hist, done, budget, \
@@ -1017,21 +1010,9 @@ class SingleDeviceSlotBackend:
             (views, tok, pos, key_data, hist, done, budget, toks,
              n_emit) = self._spec_round(
                 K, block_stack, pre, post,
-                (views, tok, pos, key_data, hist, done, budget), True)
-            ridx = jax.vmap(lambda tr, p0: flat_row_index(
-                tr, p0 + jnp.arange(Q, dtype=jnp.int32), bs))(tables, pos0)
-
-            def scat_layer(_, inp):
-                pool_l, view_l = inp
-                rows = {name: jax.vmap(
-                    lambda v, p0: jax.lax.dynamic_slice(
-                        v, (p0,) + (0,) * (v.ndim - 1),
-                        (Q,) + v.shape[1:]))(view_l[name], pos0).reshape(
-                            (S * Q,) + view_l[name].shape[2:])
-                    for name in ("k", "v")}
-                return 0, scatter_block_rows(pool_l, ridx.reshape(-1), rows)
-
-            _, pool_kv = jax.lax.scan(scat_layer, 0, (pool_kv, views))
+                (views, tok, pos, key_data, hist, done, budget))
+            pool_kv = self._scatter_view_rows(pool_kv, views, tables,
+                                              pos0, Q)
             buf = jax.lax.dynamic_update_slice(buf, toks, (0, k * K))
             nacc = jax.lax.dynamic_update_slice(
                 nacc, n_emit[:, None], (0, k))

@@ -285,9 +285,15 @@ def test_speculative_decode_matches_generator(layout, temp,
     assert rounds > 0 and emitted > rounds   # acceptance rate > 0
 
 
+# The last three since PR 29: every drafter on both carried caches (the
+# slab and the paged views, rows last alike), so the views' gather and
+# scatter, the tree's branch relocation and the truncated drafter's run
+# through the engine's layer loop are each held to the Generator's tokens.
 DRAFT_CASES = [
     ("truncated", None, "slab", 0.0), ("truncated", None, "paged", 0.8),
     ("tree", 2, "slab", 0.8), ("tree", 3, "paged", 0.0),
+    ("truncated", None, "paged", 0.0), ("tree", 2, "paged", 0.8),
+    ("tree", 3, "slab", 0.0),
 ]
 DRAFT_IDS = [f"{d}{b or ''}-{l}-{'greedy' if t == 0.0 else 'sampled'}"
              for d, b, l, t in DRAFT_CASES]
@@ -534,20 +540,26 @@ SLAB_FORM_CASES = [
     ids=[f"{f}-q{q}-{'tree' if t else 'linear'}"
          for f, q, t in SLAB_FORM_CASES])
 def test_slab_form_matches_batch_form_over_slots(family, q, tree):
-    """``block.decode(..., layer=l)`` on the stacked ``[L, S, T, H, D]``
-    cache is bitwise the batch-1 form vmapped over the slots of layer
-    ``l`` (what the engine ran before the slab rode the carry): same
-    output, same rows written, every other layer untouched — per-slot
+    """``block.decode(..., layer=l)`` on the stacked heads-folded
+    ``[L, S, T, C]`` cache is the batch-1 form vmapped over the slots
+    of layer ``l`` through a fold of the slab (the batch form keeps
+    ``[b, T, H, D]``): the rows written bitwise, every other layer and
+    the row's padding untouched, and the output to a float32 ulp or two
+    (the slab form's sums run over the folded axis and gain exact
+    zeros, which a backend may add up in another order) — per-slot
     positions all different, ``q`` 1 and ``q`` > 1, linear and tree."""
-    from pipe_tpu.ops.layers import PreLNBlock, TransformerEncoderLayer
+    from pipe_tpu.ops.layers import (PreLNBlock, TransformerEncoderLayer,
+                                     fold_heads, slab_width, unfold_heads)
     L, S, T, d, nh = 3, 4, 16, 32, 4
     cls = TransformerEncoderLayer if family == "post_ln" else PreLNBlock
     block = cls(d, nh, 64, 0.0)
     ks = jax.random.split(jax.random.key(11), 4)
     x = jax.random.normal(ks[0], (S, q, d))
     bp = block.init(ks[1], x)
-    slab = {"k": jax.random.normal(ks[2], (L, S, T, nh, d // nh)),
-            "v": jax.random.normal(ks[3], (L, S, T, nh, d // nh))}
+    assert slab_width(nh, d // nh) == 128 and slab_width(25, 64) == 1664
+    slab = {n: fold_heads(jax.random.normal(k, (L, S, T, nh, d // nh)))
+            for n, k in (("k", ks[2]), ("v", ks[3]))}
+    assert slab["k"].shape == block.attn.make_slab(L, S, T)["k"].shape
     pos = jnp.asarray([0, 3, 7, 11], jnp.int32)     # 11 + 5 == T
     anc = _tree_mask(*tree) if tree else None
 
@@ -559,10 +571,11 @@ def test_slab_form_matches_batch_form_over_slots(family, q, tree):
                 tree=anc)
             return out[0], jax.tree_util.tree_map(lambda a: a[0], cc2)
 
-        layer_l = jax.tree_util.tree_map(lambda a: a[l], slab)
+        layer_l = jax.tree_util.tree_map(          # [S, T, H, D]
+            lambda a: unfold_heads(a[l], nh, d // nh), slab)
         out, new_l = jax.vmap(one)(x, layer_l, pos)
         return out, jax.tree_util.tree_map(
-            lambda a, n: a.at[l].set(n), slab, new_l)
+            lambda a, n: a.at[l].set(fold_heads(n)), slab, new_l)
 
     def slab_form(x, slab, pos, l):
         return block.decode(bp, x, slab, pos, tree=anc, layer=l)
@@ -570,25 +583,28 @@ def test_slab_form_matches_batch_form_over_slots(family, q, tree):
     for l in (0, L - 1):
         want = jax.jit(batch_form)(x, slab, pos, jnp.int32(l))
         got = jax.jit(slab_form)(x, slab, pos, jnp.int32(l))
-        np.testing.assert_array_equal(np.asarray(got[0]),
-                                      np.asarray(want[0]))
+        np.testing.assert_allclose(np.asarray(got[0]), np.asarray(want[0]),
+                                   rtol=0, atol=2e-6)
         for name in ("k", "v"):
             np.testing.assert_array_equal(np.asarray(got[1][name]),
                                           np.asarray(want[1][name]))
             assert not np.array_equal(np.asarray(got[1][name][l]),
                                       np.asarray(slab[name][l]))
+            assert not np.asarray(got[1][name][..., d:]).any()
 
 
-def _scans(jaxpr):
-    """Every ``scan`` equation of a jaxpr, nested ones included."""
+def _eqns(jaxpr, in_loop=False):
+    """Every equation of a jaxpr, nested ones included, each with
+    whether a ``scan`` or ``while`` body holds it (a ``cond`` or ``pjit``
+    inside a loop body is inside the loop)."""
     for eqn in jaxpr.eqns:
-        if eqn.primitive.name == "scan":
-            yield eqn
+        yield eqn, in_loop
+        inside = in_loop or eqn.primitive.name in ("scan", "while")
         for v in eqn.params.values():
             for sub in (v if isinstance(v, (list, tuple)) else (v,)):
                 inner = getattr(sub, "jaxpr", sub)
                 if hasattr(inner, "eqns"):
-                    yield from _scans(inner)
+                    yield from _eqns(inner, inside)
 
 
 def _slab_program(name, backend):
@@ -608,23 +624,45 @@ def _slab_program(name, backend):
             head + (backend._hist, live, budget, jnp.int32(2)))
 
 
-@pytest.mark.parametrize("program", ["decode", "resident", "spec"])
+SLAB_PROGRAMS = {
+    "decode": {}, "resident": {}, "spec": {"spec_tokens": 3},
+    "spec-truncated": {"spec_tokens": 3, "draft": "truncated"},
+    "spec-tree": {"spec_tokens": 3, "draft": "tree", "spec_branches": 2},
+}
+
+
+@pytest.mark.parametrize("program", list(SLAB_PROGRAMS))
 def test_slab_rides_the_layer_loops_carry(program, model_and_params):
     """The structural pin of the in-place cache: in every slab decode
-    program the ``[L, S, T, H, D]`` slab is a CARRY of the scan over
-    layers, and no scan anywhere takes it as a scanned input or gives it
-    back as a stacked output — the round trip (slice a layer out, stack
-    it back, copy the whole slab once a step) cannot return unseen."""
+    program the heads-folded ``[L, S, T, C]`` slab is a CARRY of the
+    scan over layers, no scan anywhere takes it as a scanned input or
+    gives it back as a stacked output — the round trip (slice a layer
+    out, stack it back, copy the whole slab once a step) cannot return
+    unseen — and no loop body transposes the slab or a layer of it: the
+    carried layout is the one the two reads of a layer use. The
+    truncated and tree drafters run the same loop over their first
+    layers on the same carry; they neither slice the slab nor join it
+    back, and the tree's branch relocation moves rows inside it."""
     model, params = model_and_params
     gen_cfg = GenerationConfig(max_new_tokens=6, temperature=0.0)
     backend = _make_backend(
         "single", model, params, gen_cfg, resident=True,
-        resident_chunks=2,
-        **({"spec_tokens": 3} if program == "spec" else {}))
+        resident_chunks=2, **SLAB_PROGRAMS[program])
     slab_shape = backend._caches["k"].shape
-    assert len(slab_shape) == 5 and slab_shape[0] == CFG.n_layers
-    fn, args = _slab_program(program, backend)
-    scans = list(_scans(jax.make_jaxpr(fn)(*args).jaxpr))
+    assert slab_shape == backend._caches["v"].shape == (
+        CFG.n_layers, backend.num_slots, backend.max_len,
+        128)                       # [L, S, T, C]: 4 heads of 8, one lane tile
+    fn, args = _slab_program(program.split("-")[0], backend)
+    jaxpr = jax.make_jaxpr(fn)(*args).jaxpr
+    whole = (slab_shape, slab_shape[1:])
+    eqns = list(_eqns(jaxpr))
+    moved = [eqn for eqn, in_loop in eqns if in_loop and (
+        (eqn.primitive.name == "transpose"
+         and eqn.invars[0].aval.shape in whole)
+        or (eqn.primitive.name == "concatenate"
+            and eqn.outvars[0].aval.shape in whole))]
+    assert not moved, f"a loop body transposes or rejoins the slab: {moved}"
+    scans = [eqn for eqn, _ in eqns if eqn.primitive.name == "scan"]
     carried = 0
     for eqn in scans:
         nc, nk = eqn.params["num_consts"], eqn.params["num_carry"]
@@ -637,3 +675,113 @@ def test_slab_rides_the_layer_loops_carry(program, model_and_params):
             assert carry.count(slab_shape) == 2          # k and v
             carried += 1
     assert carried >= 1, "no scan over layers carries the slab"
+
+
+# ---------------------------------------------------------------------------
+# tools/hlo_audit.py --slab: where a compiled program moves the slab about
+
+
+def _hlo_audit():
+    import importlib
+    import os
+    import sys
+    tools = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "tools")
+    if tools not in sys.path:
+        sys.path.insert(0, tools)
+    return importlib.import_module("hlo_audit")
+
+
+@pytest.mark.parametrize("shape,want", [
+    ("bf16[48,8,640,25,64]{4,3,2,1,0:T(8,128)(2,1)}", 48 * 8 * 640 * 32 * 128 * 2),
+    ("bf16[48,8,640,25,64]{2,4,3,1,0:T(8,128)(2,1)}", 48 * 8 * 640 * 25 * 64 * 2),
+    ("bf16[48,8,1600,640]{3,2,1,0:T(8,128)(2,1)}", 48 * 8 * 1600 * 640 * 2),
+    ("bf16[48,8,1600,640]{2,3,1,0:T(8,128)(2,1)S(1)}", 48 * 8 * 1664 * 640 * 2),
+    ("f32[8,25]{1,0:T(8,128)}", 8 * 128 * 4),
+    ("s32[7,3]", 84),
+], ids=["rows-first-padded", "rows-minor", "folded", "folded-heads-minor",
+        "f32-tile", "no-layout"])
+def test_hlo_audit_counts_a_tiled_layouts_bytes(shape, want):
+    """The padding arithmetic PERF.md's layout findings rest on: PR 26's
+    2.0 GB against 0.79 GB of one slab, read off a shape's layout."""
+    assert _hlo_audit()._tiled_bytes(shape) == want
+
+
+def test_hlo_audit_finds_slab_moves_in_and_around_loops():
+    """``_slab_census`` on a hand-made module: a relayout of the whole
+    slab before the ``while`` (PR 26's ``copy.33``), a layer-sized
+    transpose inside a fusion its body calls, and nothing for arrays of
+    another size or type."""
+    hlo = """HloModule m
+
+%fused.1 (p0: bf16[2,3,8,16]) -> bf16[3,16,8] {
+  %p0 = bf16[2,3,8,16]{3,2,1,0} parameter(0)
+  %sl = bf16[1,3,8,16]{3,2,1,0} slice(%p0), slice={[0:1], [0:3], [0:8], [0:16]}
+  %bc = bf16[3,8,16]{2,1,0} bitcast(%sl)
+  ROOT %tr = bf16[3,16,8]{2,1,0} transpose(%bc), dimensions={0,2,1}
+}
+
+%body (st: (bf16[2,3,8,16], s32[])) -> (bf16[2,3,8,16], s32[]) {
+  %st = (bf16[2,3,8,16]{3,2,1,0}, s32[]) parameter(0)
+  %slab = bf16[2,3,8,16]{3,2,1,0} get-tuple-element(%st), index=0
+  %f = bf16[3,16,8]{2,1,0} fusion(%slab), kind=kLoop, calls=%fused.1
+  %small = bf16[3,8]{1,0} copy(%other)
+  ROOT %t = (bf16[2,3,8,16]{3,2,1,0}, s32[]) tuple(%slab, %i)
+}
+
+%cond (st: (bf16[2,3,8,16], s32[])) -> pred[] {
+  %st = (bf16[2,3,8,16]{3,2,1,0}, s32[]) parameter(0)
+  ROOT %lt = pred[] compare(%a, %b), direction=LT
+}
+
+ENTRY %main (a: bf16[2,3,8,16]) -> bf16[2,3,8,16] {
+  %a = bf16[2,3,8,16]{1,3,2,0} parameter(0)
+  %copy.33 = bf16[2,3,8,16]{3,2,1,0} copy(%a)
+  %f32s = f32[2,3,8,16]{3,2,1,0} copy(%z)
+  %w = (bf16[2,3,8,16]{3,2,1,0}, s32[]) while(%init), condition=%cond, body=%body
+  ROOT %out = bf16[2,3,8,16]{3,2,1,0} get-tuple-element(%w), index=0
+}
+"""
+    moves, forms = _hlo_audit()._slab_census(hlo, 2 * 3 * 8 * 16, 2, "bf16")
+    assert [(m["name"], m["what"]) for m in moves["outside_loops"]] == [
+        ("copy.33", "slab")]
+    assert [(m["name"], m["op"], m["what"], m["computation"])
+            for m in moves["in_loops"]] == [
+                ("tr", "transpose", "layer", "fused.1")]
+    assert set(forms) == {"bf16[2,3,8,16]{3,2,1,0}",
+                          "bf16[2,3,8,16]{1,3,2,0}"}
+
+
+@pytest.fixture(scope="module")
+def described_v5e():
+    """Skips where this installation cannot describe a v5e (the TPU's
+    compiler missing). Called from a test only, never at import: one
+    process at a time may load the TPU's library."""
+    from jax.experimental import topologies
+    try:
+        topologies.get_topology_desc(platform="tpu",
+                                     topology_name="v5e:2x2")
+    except Exception as e:                                # noqa: BLE001
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return "v5e:2x2"
+
+
+def test_the_cells_resident_program_moves_no_slab_on_a_described_v5e(
+        described_v5e):
+    """The compile-only check of PR 29, kept: the REAL ``_resident_fn``
+    at the ``gpt2xl-serve-closed8`` cell's sizes, compiled for a
+    described v5e (no device, no weights; about ten seconds), carries
+    the slab at its own bytes (0.82 GB a tensor: 25 x 64 folded into
+    1664, 4% of padding, and none the compiler adds), holds no slab- or
+    layer-sized ``copy``/``transpose`` in any loop body or around the
+    ``while``, and needs under 2 GB of temporaries (the rows-first
+    carry of PR 26 needed 5.81 GB: two slabs padded 2.6x). A cache
+    layout the TPU's compiler pads or relays again fails here, before
+    any chip time is spent."""
+    out = _hlo_audit().slab(topology=described_v5e)
+    assert out["slab_shape"] == [48, 8, 640, 1664]
+    res = out["programs"]["resident"]
+    assert res["slab_moves"] == {"in_loops": [], "outside_loops": []}
+    assert set(res["slab_forms_bytes"].values()) == {out["slab_data_bytes"]}
+    assert res["memory"]["temp_size_in_bytes"] < 2 * 2**30
+    assert out["ok"], out["violations"]

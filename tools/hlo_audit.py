@@ -14,11 +14,17 @@ virtual cpu8 mesh, then reports per-program:
 * cycles in the schedule table, so overhead can be attributed per cycle.
 
 Prints one JSON line; also used by docs/architecture.md's overhead table.
+
+``--slab [--num-slots=8] [--topology=v5e:2x2] ...`` is the odd one out: it
+compiles the serve engine's real resident and prefill programs at the
+benchmark cell's sizes for a DESCRIBED TPU (no device, no weights) and
+lists where the KV slab is copied, transposed or padded (:func:`slab`).
 """
 
 from __future__ import annotations
 
 import json
+import math
 import os
 import re
 import sys
@@ -209,8 +215,8 @@ def _called(body: str):
     out = set()
     for key in ("to_apply", "body", "condition", "true_computation",
                 "false_computation", "branch_computations", "calls"):
-        for m in re.finditer(rf"{key}=\{{?([^,)\}}]+(?:,\s*[^,)\}}]+)*)\}}?",
-                             body):
+        for m in re.finditer(
+                rf"{key}=\{{?([^,)\}}\s]+(?:,\s*[^,)\}}\s]+)*)\}}?", body):
             for nm in m.group(1).split(","):
                 out.add(nm.strip().lstrip("%"))
     return out
@@ -235,9 +241,8 @@ def _conditional_census(text: str):
     return dispatch, role
 
 
-def _region_census(hlo: str, roots):
-    """Op census over ``roots`` computations plus everything they call."""
-    comps = _hlo_computations(hlo)
+def _reachable(comps, roots):
+    """``roots`` (computation names) plus everything they call."""
     seen = set()
     frontier = [r for r in roots if r in comps]
     while frontier:
@@ -247,7 +252,13 @@ def _region_census(hlo: str, roots):
         seen.add(nm)
         frontier.extend(c for c in _called(comps[nm])
                         if c in comps and c not in seen)
-    text = "\n".join(comps[nm] for nm in seen)
+    return seen
+
+
+def _region_census(hlo: str, roots):
+    """Op census over ``roots`` computations plus everything they call."""
+    comps = _hlo_computations(hlo)
+    text = "\n".join(comps[nm] for nm in _reachable(comps, roots))
     dispatch, role = _conditional_census(text)
     return {
         # indexed (≥3-branch) HLO conditional — what lax.switch lowers to:
@@ -431,7 +442,9 @@ def resident(num_slots: int = 2, max_len: int = 16,
         if spec:
             args.append(b._hist)
         args += [live, budget, jnp.int32(resident_chunks)]
-        return b._resident_jit.lower(*args).compile().as_text()
+        run = (b._resident_spec_jits[spec_tokens] if spec
+               else b._resident_jit)
+        return run.lower(*args).compile().as_text()
 
     def ring(layout):
         sp, pre, post = params
@@ -446,10 +459,9 @@ def resident(num_slots: int = 2, max_len: int = 16,
         kind = "resident_paged" if b.paged else "resident"
         n = b.n
         args = [b._stage_params, b._pre, b._post, b._caches, b._h,
-                b._tok_ring, b._pos_local, jnp.int32(0),
+                b._tok_ring, b._pos_local, b._key_local, jnp.int32(0),
                 jnp.asarray(b._admit), jnp.zeros((n,), jnp.int32),
-                jnp.asarray(b._tok_inject), jnp.asarray(b._plen),
-                jnp.asarray(b._key_data)]
+                jnp.asarray(b._tok_inject)]
         if b.paged:
             args.append(jnp.asarray(b.pool.table))
         args += [jnp.full((n,), gen.max_new_tokens, jnp.int32),
@@ -496,6 +508,166 @@ def resident(num_slots: int = 2, max_len: int = 16,
     return out
 
 
+_HLO_ITEMSIZE = {"bf16": 2, "f16": 2, "f32": 4, "s8": 1, "u8": 1,
+                 "s32": 4, "u32": 4, "pred": 1}
+
+
+def _tiled_bytes(shape: str) -> int:
+    """Bytes of an HLO shape string with a TPU tiled layout, e.g.
+    ``bf16[48,8,1600,640]{3,2,1,0:T(8,128)(2,1)}``: the two minor-most
+    dimensions (by the layout's minor-to-major order) are padded up to
+    the tile, ``T(a,b)`` times the sub-tile ``(c,1)`` that packs a
+    narrow type into rows. No layout or no tile: the plain bytes."""
+    m = re.match(r"(\w+)\[([\d,]*)\](?:\{([\d,]*)(?::([^}]*))?\})?", shape)
+    dims = [int(d) for d in m.group(2).split(",") if d]
+    order = ([int(d) for d in m.group(3).split(",") if d]
+             if m.group(3) else list(range(len(dims) - 1, -1, -1)))
+    tile = re.search(r"T\((\d+),(\d+)\)(?:\((\d+),1\))?", m.group(4) or "")
+    if tile and len(order) >= 2:
+        rows = int(tile.group(1)) * int(tile.group(3) or 1)
+        lanes = int(tile.group(2))
+        dims[order[0]] = -(-dims[order[0]] // lanes) * lanes
+        dims[order[1]] = -(-dims[order[1]] // rows) * rows
+    return _HLO_ITEMSIZE[m.group(1)] * math.prod(dims)
+
+
+def _slab_census(hlo: str, slab_elems: int, layers: int, dtype: str):
+    """Where a compiled decode program moves the KV slab about: every
+    ``copy`` or ``transpose`` whose result is a whole slab tensor or one
+    layer of one (by element count and type, so a folded or unfolded
+    form counts alike), split by whether a ``while`` body holds it
+    (however deeply: fused computations and nested loops included) or
+    the straight-line rest (the entry computation and what it calls
+    outside any loop); and the distinct shapes-with-layout the slab
+    takes anywhere, with their tiled bytes."""
+    comps = _hlo_computations(hlo)
+    in_loop = _reachable(comps, [
+        mt.group(1) for body in comps.values()
+        for mt in re.finditer(r"(?:body|condition)=%?([\w.\-]+)", body)])
+    sizes = {slab_elems: "slab", slab_elems // layers: "layer"}
+    moves = {"in_loops": [], "outside_loops": []}
+    forms = {}
+    for nm, body in comps.items():
+        for line in body.splitlines():
+            mt = re.match(r"\s*(?:ROOT )?%?([\w.\-]+) = "
+                          r"((\w+)\[([\d,]*)\](?:\{[^}]*\})?) ([\w\-]+)\(",
+                          line)
+            if not mt or mt.group(3) != dtype:
+                continue
+            n = math.prod(int(d) for d in mt.group(4).split(",") if d)
+            if n not in sizes:
+                continue
+            if sizes[n] == "slab":
+                forms[mt.group(2)] = _tiled_bytes(mt.group(2))
+            if mt.group(5) in ("copy", "transpose"):
+                moves["in_loops" if nm in in_loop else "outside_loops"
+                      ].append({"name": mt.group(1), "op": mt.group(5),
+                                "what": sizes[n], "shape": mt.group(2),
+                                "computation": nm})
+    return moves, forms
+
+
+def slab(num_slots: int = 8, max_len: int = 640, decode_chunk: int = 4,
+         resident_chunks: int = 8, n_layers: int = 48, d_model: int = 1600,
+         nhead: int = 25, d_ff: int = 6400, vocab: int = 50257,
+         prefill_bucket: int = 512, topology: str = "v5e:2x2") -> dict:
+    """Where the KV slab lives in the serve engine's compiled programs,
+    for a DESCRIBED TPU (no device; nothing runs): the check a cache
+    layout PR makes before it spends chip time (PERF.md, PRs 26, 29).
+
+    Builds ``SingleDeviceSlotBackend`` over a GPT-2 of the given sizes
+    (defaults: the ``gpt2xl-serve-closed8`` cell's) under
+    ``jax.eval_shape``, so no weight is ever made, and compiles its
+    real ``_resident_fn`` and one ``_prefill_fn`` bucket for one chip
+    of ``topology``. Reports, per program: every slab- or layer-sized
+    ``copy``/``transpose`` inside a ``while`` body and outside one, the
+    layouts the slab takes with their tiled bytes beside the data's own,
+    and ``memory_analysis()``. ``ok`` asks what PR 29 asked: no such
+    instruction anywhere in the resident program, and no form of the
+    slab over 1.05x its data. Exits non-zero otherwise."""
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    os.environ.setdefault("JAX_PLATFORMS", "cpu")
+
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    from pipe_tpu.inference import GenerationConfig
+    from pipe_tpu.models.gpt2 import GPT2Config, PipelinedGPT2
+    from pipe_tpu.serve import BucketSpec, SingleDeviceSlotBackend
+
+    cfg = GPT2Config(vocab=vocab, d_model=d_model, nhead=nhead, d_ff=d_ff,
+                     n_layers=n_layers, dropout=0.0,
+                     seq_len=max(max_len, 1024), compute_dtype=jnp.bfloat16)
+    model = PipelinedGPT2(cfg, 1)
+    gen = GenerationConfig(max_new_tokens=max_len - prefill_bucket,
+                           temperature=0.0)
+    made = []
+
+    def build():
+        b = SingleDeviceSlotBackend(
+            model, model.init(jax.random.key(0)), num_slots=num_slots,
+            max_len=max_len, gen=gen, buckets=BucketSpec.of(prefill_bucket),
+            decode_chunk=decode_chunk, resident=True,
+            resident_chunks=resident_chunks)
+        made.append(b)
+        return (b._block_stack, b._pre, b._post, b._caches, b._tok,
+                b._pos, b._key_data)
+
+    shapes = jax.eval_shape(build)        # the backend's arrays, unmade
+    b = made[0]
+    chip = SingleDeviceSharding(
+        topologies.get_topology_desc(platform="tpu",
+                                     topology_name=topology).devices[0])
+
+    def on_chip(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+
+    stack, pre, post, caches, tok, pos, key_data = jax.tree_util.tree_map(
+        lambda a: on_chip(a.shape, a.dtype), shapes)
+    S = num_slots
+    programs = {
+        "resident": lambda: b._resident_jit.lower(
+            stack, pre, post, caches, tok, pos, key_data,
+            on_chip((S,), jnp.bool_), on_chip((S,), jnp.int32),
+            on_chip((), jnp.int32)),
+        f"prefill{prefill_bucket}": lambda: jax.jit(
+            b._prefill_fn, donate_argnums=(3,)).lower(
+                stack, pre, post, caches,
+                on_chip((1, prefill_bucket), jnp.int32),
+                on_chip((), jnp.int32), on_chip((), jnp.int32),
+                jax.eval_shape(lambda: jax.random.key(0))),
+    }
+    k = caches["k"]
+    elems = math.prod(k.shape)
+    data = elems * k.dtype.itemsize
+    out = {"topology": topology, "num_slots": num_slots, "max_len": max_len,
+           "slab_shape": list(k.shape), "slab_data_bytes": data,
+           "programs": {}}
+    violations = []
+    for name, lower in programs.items():
+        compiled = lower().compile()
+        moves, forms = _slab_census(compiled.as_text(), elems, k.shape[0],
+                                    "bf16")
+        ma = compiled.memory_analysis()
+        out["programs"][name] = {
+            "slab_moves": moves, "slab_forms_bytes": forms,
+            "memory": {f: getattr(ma, f) for f in (
+                "argument_size_in_bytes", "output_size_in_bytes",
+                "alias_size_in_bytes", "temp_size_in_bytes")}}
+        fat = {f: n for f, n in forms.items() if n > 1.05 * data}
+        if name == "resident" and (moves["in_loops"]
+                                   or moves["outside_loops"] or fat):
+            violations.append(
+                f"{name}: {len(moves['in_loops'])} slab moves in loops, "
+                f"{len(moves['outside_loops'])} outside, padded forms "
+                f"{fat}")
+    out["violations"] = violations
+    out["ok"] = not violations
+    return out
+
+
 if __name__ == "__main__":
     kw = {}
     mode = audit
@@ -509,11 +681,14 @@ if __name__ == "__main__":
         if a == "--resident":
             mode = resident
             continue
+        if a == "--slab":
+            mode = slab
+            continue
         k, v = a.lstrip("-").split("=", 1)
         k = k.replace("-", "_")
         kw[k] = tuple(v.split(",")) if k == "schedules" else (
-            v if k == "checkpoint" else int(v))
+            v if k in ("checkpoint", "topology") else int(v))
     res = mode(**kw)
     print(json.dumps(res))
-    if mode in (phases, resident) and not res["ok"]:
+    if mode in (phases, resident, slab) and not res["ok"]:
         sys.exit(1)
