@@ -196,8 +196,8 @@ def serve_phase(phase: Phase, model_cfg: LMConfig, *, n_requests: int = 6,
         model, params, num_slots=slots, max_len=buckets.max_len + max_new,
         gen=gen_cfg, buckets=buckets, decode_chunk=4, resident="auto",
         resident_chunks=8)
-    phase.check(backend.resident, "resident='auto' chose the "
-                                  "lax.while_loop program")
+    phase.check(backend.resident_chunks == 8,
+                "resident='auto' gave the decode launch its 8-chunk horizon")
     eng = ServeEngine(backend, RequestQueue(capacity=64, policy="fifo"))
 
     reg = get_registry()

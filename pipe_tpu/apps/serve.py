@@ -154,17 +154,17 @@ def build_argparser() -> argparse.ArgumentParser:
                         "revolutions per tick)")
     p.add_argument("--resident", choices=["auto", "on", "off"],
                    default="auto",
-                   help="fused steady-state device loop: run up to "
+                   help="the decode launch's horizon: on runs up to "
                         "--resident-chunks decode chunks per launch "
-                        "with on-device done-masking and early exit "
-                        "(auto: on for accelerators, off on cpu)")
+                        "(on-device done-masking and early exit), off "
+                        "one (auto: on for accelerators, off on cpu)")
     p.add_argument("--resident-chunks", type=int, default=8,
                    help="max decode chunks per resident launch (ring: "
                         "revolutions)")
     p.add_argument("--spec-tokens", type=int, default=None,
                    help="speculative decode: K-token draft/verify per "
-                        "resident round (needs --resident on/auto-on; "
-                        "works on both backends)")
+                        "round (both backends; the ring needs "
+                        "--resident on/auto-on)")
     p.add_argument("--draft", choices=["ngram", "truncated", "tree"],
                    default="ngram",
                    help="draft source for --spec-tokens: prompt-history "

@@ -14,15 +14,23 @@ position, PRNG key) triple. A host **tick** is:
    per prompt-length bucket (:class:`~.buckets.BucketSpec`) writes the
    slot's cache rows and samples the first token (TTFT is measured
    here);
-3. run the **one** decode step for all S slots — finished/empty slots
-   decode garbage into rows the next prefill overwrites, the same
+3. launch the **one** decode program for all S slots — finished/empty
+   slots write rows the next prefill overwrites, the same
    sacrificial-write trick as the pipelined generators — and retire
    slots on EOS / per-request ``max_new_tokens``.
 
+The decode program of :class:`SingleDeviceSlotBackend` is one
+``lax.while_loop`` with two seams: the cache store (slab or paged pool)
+and the round (``decode_chunk`` plain steps, or one speculative
+draft/verify round). ``resident`` sets only its horizon: how many
+rounds one launch may run before the host looks again.
+
 Zero steady-state recompiles is a pinned invariant, not an aspiration:
-the decode program body increments ``serve.engine.decode_traces`` at
-trace time (traces happen once per compile), and ``tests/test_serve.py``
-asserts the counter stays at 1 across staggered mixed-length traffic.
+the decode program body increments ``serve.engine.decode_traces`` (a
+one-chunk horizon) or ``serve.engine.resident_traces`` (a longer one)
+at trace time (traces happen once per compile), and
+``tests/test_serve.py`` asserts the counter stays at 1 across staggered
+mixed-length traffic.
 
 Token parity is the other pin: because each slot carries the exact
 (prefill -> split -> sample -> split -> sample...) key chain of a
@@ -32,7 +40,7 @@ served through the engine produces bitwise the tokens of a one-shot
 ``Generator.generate`` on its prompt — regardless of what the other
 slots are doing.
 
-``decode_chunk > 1`` runs K decode steps per tick inside a ``lax.scan``
+``decode_chunk > 1`` runs K decode steps per round inside a ``lax.scan``
 (one host round-trip per K tokens — the host-sync amortization knob);
 the carry chain is identical however it is chopped, so parity holds.
 The cost is retirement lag: a slot finishing mid-chunk wastes at most
@@ -41,8 +49,9 @@ K-1 slot-steps before the host sees it.
 
 from __future__ import annotations
 
+import functools
 import time
-from typing import Callable, List, Optional, Sequence
+from typing import (Callable, List, NamedTuple, Optional, Sequence)
 
 import jax
 import jax.numpy as jnp
@@ -82,6 +91,121 @@ class _Slot:
         self.tokens: List[int] = [first_token]
         self.ttft = ttft
         self.admitted_tick = admitted_tick
+
+
+class _Round(NamedTuple):
+    """Seam B of the decode program: what one loop iteration emits.
+    ``run(block_stack, pre, post, slots) -> slots, toks [S, width],
+    n_emit [S]`` over ``slots = (rows, tok, pos, key_data, hist, done,
+    budget)``, writing ``rows`` cache rows a slot. ``counted``: ``n_emit``
+    varies (the accepted length) and the loop records it; otherwise it
+    is None, and every live slot emits ``width``."""
+    run: Callable
+    width: int
+    rows: int
+    counted: bool
+
+
+class _SlabStore:
+    """Seam A of the decode program, the slab: the program's KV
+    argument is the carried ``[L, S, T, C]`` rows themselves, and
+    nothing stands behind them."""
+
+    def __init__(self, backend):
+        self.b = backend
+
+    # host side: the program's donated KV and kept arguments; its KV back
+    def take(self):
+        return self.b._caches, None
+
+    def put(self, kv):
+        self.b._caches = kv
+
+    # device side: ``enter`` gives the loop its (rows, what is behind
+    # them), ``commit`` takes the n rows each slot wrote from pos0 on
+    # behind, ``leave`` gives back the program's KV
+    def enter(self, kv, aux):
+        return kv, None
+
+    def commit(self, back, rows, aux, pos0, n):
+        return back
+
+    def leave(self, rows, back):
+        return rows
+
+
+class _PoolStore:
+    """Seam A of the decode program, the paged pool. The carried rows
+    are each slot's block view — its first ``max_blocks`` table entries,
+    covering every row it can read or write (``rows_needed <=
+    max_len``), exactly the slab's attention footprint — and the pool
+    stands behind them. The views are gathered ONLY when the device-side
+    ``regather`` flag says a prefill moved a table since the last
+    launch; otherwise the ones carried from that launch are the same
+    rows bitwise, because every round's rows are committed to the pool.
+    The program returns the flag CLEARED, so a no-prefill tick makes
+    zero host-driven gather decisions."""
+
+    def __init__(self, backend):
+        self.b = backend
+
+    def take(self):
+        b = self.b
+        return ((b._pool_kv, b._views),
+                (jnp.asarray(b.pool.table), b._regather))
+
+    def put(self, kv):
+        self.b._pool_kv, self.b._views, self.b._regather = kv
+
+    def enter(self, kv, aux):
+        """The views ``[L, S, R, C]`` (the carried layout of
+        ``_run_layers``, heads folded: the gather is a whole copy
+        anyway, so the fold rides it). The 2-branch cond is a role
+        conditional (both branches give the same shape), not a dispatch."""
+        pool_kv, views = kv
+        tables, regather = aux
+        pool = self.b.pool
+        cd = self.b.model.cfg.compute_dtype
+        view_t = tables[:, :pool.max_blocks + 1]
+
+        def gather_layer(pool_l):
+            out = jax.vmap(lambda tr: gather_block_cache(
+                pool_l, tr, block_size=pool.block_size,
+                compute_dtype=cd))(view_t)
+            return {name: fold_heads(a[:, 0])
+                    for name, a in out.items()}            # [S, R, C]
+
+        return jax.lax.cond(
+            regather, lambda v: jax.vmap(gather_layer)(pool_kv),
+            lambda v: v, views), pool_kv
+
+    def commit(self, pool_kv, views, aux, pos0, n):
+        """The ``n`` rows every slot wrote into its view from ``pos0``
+        on, unfolded, back into the pool through the FULL-width tables,
+        whose sacrificial clamp routes overshoot/dead-slot writes into
+        block 0 — a dead slot can never corrupt a reallocated block."""
+        tables = aux[0]
+        bs = self.b.pool.block_size
+        attn = self.b.model.block.attn
+        ridx = jax.vmap(lambda tr, p0: flat_row_index(
+            tr, p0 + jnp.arange(n, dtype=jnp.int32), bs))(tables, pos0)
+
+        def scat_layer(_, inp):
+            pool_l, view_l = inp
+            rows = {}
+            for name in ("k", "v"):
+                new = jax.vmap(
+                    lambda v, p0: jax.lax.dynamic_slice_in_dim(v, p0, n))(
+                        view_l[name], pos0)                # [S, n, C]
+                rows[name] = unfold_heads(                 # [S*n, H, D]
+                    new.reshape(-1, new.shape[-1]), attn.nhead,
+                    attn.head_dim)
+            return 0, scatter_block_rows(pool_l, ridx.reshape(-1), rows)
+
+        return jax.lax.scan(scat_layer, 0, (pool_kv, views))[1]
+
+    def leave(self, views, pool_kv):
+        return pool_kv, views, jnp.zeros((), jnp.bool_)
 
 
 class SingleDeviceSlotBackend:
@@ -131,9 +255,9 @@ class SingleDeviceSlotBackend:
         self.buckets = buckets
         self.decode_chunk = decode_chunk
         self.shape_cache_warn = shape_cache_warn
-        # resident tri-state: the fused multi-chunk loop pays off where
-        # launch/sync overhead does (accelerators); "auto" keeps the cpu
-        # default on the byte-for-byte single-chunk path.
+        # resident is a horizon and nothing else: off is one chunk a
+        # launch. "auto" is on where a launch and its sync cost what a
+        # chunk costs (accelerators), off on the cpu.
         if resident not in ("auto", True, False):
             raise ValueError(
                 f"resident must be 'auto', True or False, got {resident!r}")
@@ -143,19 +267,15 @@ class SingleDeviceSlotBackend:
         if resident_chunks < 1:
             raise ValueError(
                 f"resident_chunks must be >= 1, got {resident_chunks}")
-        self.resident_chunks = resident_chunks
+        self.resident_chunks = resident_chunks if self.resident else 1
         spec = spec_tokens if spec_tokens is not None else gen.spec_tokens
         if spec is not None and spec < 2:
             raise ValueError(
                 f"spec_tokens must be >= 2, got {spec}")
-        if spec is not None and not self.resident:
-            raise ValueError(
-                "spec_tokens needs the resident loop (the draft/verify "
-                "round IS the resident chunk body); pass resident=True")
         self.spec_tokens = spec
-        # tokens per resident iteration: the readout stride of the token
-        # buffer the resident program returns. Spec mode re-sets this
-        # per launch to the adaptive ladder rung that ran.
+        # tokens per round: the readout stride of the token buffer the
+        # decode program returns. Spec mode re-sets this per launch to
+        # the adaptive ladder rung that ran.
         self.decode_width = spec if spec is not None else decode_chunk
 
         stage_params, pre_params, post_params = params
@@ -254,17 +374,15 @@ class SingleDeviceSlotBackend:
             self._chunk_jit = jax.jit(self._chunk_fn, donate_argnums=(2,))
             self._sample_jit = jax.jit(self._sample_fn)
             self._fork_jit = jax.jit(self._fork_fn, donate_argnums=(0,))
-            self._decode_jit = jax.jit(self._decode_paged_fn,
-                                       donate_argnums=(3, 8))
-            # per-slot gathered views carried across decode chunks —
-            # valid until a prefill moves a table (_views_dirty), when
-            # the decode program re-gathers from the (always-current)
-            # pool. Compute dtype even for int8 pools: the view is the
-            # dequantized working set.
+            # per-slot gathered views carried across launches, and the
+            # device-side flag that has the decode program gather them
+            # again (:class:`_PoolStore`): prefill arms it, the one host
+            # decision, counted. Compute dtype even for int8 pools: the
+            # view is the dequantized working set.
             self._views = model.block.attn.make_slab(
                 self._n_layers, num_slots, self.pool.max_blocks * kbs,
                 dtype=cd)
-            self._views_dirty = True
+            self._regather = jnp.asarray(True)
         else:
             if kv_dtype is not None:
                 raise ValueError(
@@ -279,52 +397,35 @@ class SingleDeviceSlotBackend:
             self.pool = None
             self._caches = model.block.attn.make_slab(
                 self._n_layers, num_slots, max_len, dtype=cd)
-            self._decode_jit = jax.jit(self._decode_fn, donate_argnums=(3,))
         self._tok = jnp.zeros((num_slots,), jnp.int32)
         self._pos = jnp.zeros((num_slots,), jnp.int32)
         kd0 = jax.random.key_data(jax.random.key(0))
         self._key_data = jnp.broadcast_to(kd0, (num_slots,) + kd0.shape)
 
-        if self.resident:
-            if self.paged:
-                # the regather flag lives ON DEVICE in resident mode —
-                # prefill arms it (the one host decision, counted), the
-                # resident program consumes and clears it in its carry
-                self._regather = jnp.asarray(True)
-                if self.spec_tokens is None:
-                    self._resident_jit = jax.jit(
-                        self._resident_paged_fn, donate_argnums=(3, 8))
-                else:
-                    # one jit per ladder rung: K is closure-bound so the
-                    # donated positions line up with the un-curried
-                    # signature; every rung traces once, then the steady
-                    # state is rung selection over compiled programs
-                    self._resident_spec_jits = {
-                        k: jax.jit(
-                            (lambda *a, _k=k:
-                             self._resident_spec_paged_fn(_k, *a)),
-                            donate_argnums=(3, 8, 10))
-                        for k in self._spec_ladder}
-            else:
-                if self.spec_tokens is None:
-                    self._resident_jit = jax.jit(
-                        self._resident_fn, donate_argnums=(3,))
-                else:
-                    self._resident_spec_jits = {
-                        k: jax.jit(
-                            (lambda *a, _k=k:
-                             self._resident_spec_fn(_k, *a)),
-                            donate_argnums=(3, 7))
-                        for k in self._spec_ladder}
-            if self.spec_tokens is not None:
-                # device-side token history, the n-gram draft source:
-                # hist[s, p] = the token EMBEDDED at position p of slot
-                # s (prompt rows written at prefill, accepted tokens at
-                # their positions in-program). spec_tokens rows of slack
-                # absorb the masked write past the last position.
-                self._hist = jnp.full(
-                    (num_slots, max_len + self.spec_tokens),
-                    gen.pad_token_id, jnp.int32)
+        # device-side token history, the n-gram draft source
+        # (:meth:`_hist_write`; accepted tokens land in-program).
+        # spec_tokens rows of slack absorb the masked write past the
+        # last position. None where no round drafts.
+        self._hist = None if spec is None else jnp.full(
+            (num_slots, max_len + spec), gen.pad_token_id, jnp.int32)
+
+        # THE decode program: one jit per round width — one in all
+        # without speculation, one per ladder rung with it (every rung
+        # traces once, then the steady state is rung selection over
+        # compiled programs). Donated: the store's KV and the history.
+        self._store = _PoolStore(self) if self.paged else _SlabStore(self)
+        if spec is None:
+            rounds = [_Round(self._plain_round, decode_chunk, decode_chunk,
+                             False)]
+        else:
+            B = self._drafter.branches
+            rounds = [_Round(functools.partial(self._spec_round, k), k,
+                             1 + B * (k - 1), True)
+                      for k in self._spec_ladder]
+        self._resident_jits = {
+            rnd.width: jax.jit(self._decode_program(self._store, rnd),
+                               donate_argnums=(3, 8))
+            for rnd in rounds}
 
         self._prefill_programs = {}
 
@@ -369,16 +470,16 @@ class SingleDeviceSlotBackend:
     # -- device programs ---------------------------------------------------
 
     def _run_layers(self, block_stack, h, caches, pos, tree=None):
-        """THE layer loop of every decode program (single-chunk and
-        resident, slab and paged views alike, the speculative verify
-        and the truncated drafters): ``h [S, q, d]`` through all layers
+        """THE layer loop of the decode program (slab and paged views
+        alike, the plain step, the speculative verify and the truncated
+        drafters): ``h [S, q, d]`` through all layers
         at per-slot positions ``pos [S]``. The stacked cache
         ``[L, S, T, C]`` (``attn.make_slab``: a cache row is its heads
         folded into one axis of whole lane tiles) is the loop's CARRY,
         never a scanned input or a stacked output — each layer writes
         its ``S x q`` new rows into it and reads its own layer of it
         (the slab form of ``block.decode``), so the compiler keeps one
-        buffer through the layer loop, the chunk scan and the resident
+        buffer through the layer loop, the chunk scan and the launch's
         ``while`` instead of slicing a layer out, stacking it back and
         copying the whole slab every step. Everything carried here has
         this one shape (slab, paged views, the tree drafter's copies:
@@ -441,49 +542,6 @@ class SingleDeviceSlotBackend:
         tok0 = sample_logits(head_logits(m, post, h_last)[:, 0, :],
                              sub, gen)[0]
         return caches, tok0, key
-
-    def _decode_fn(self, block_stack, pre, post, caches, tok, pos,
-                   key_data):
-        """THE decode step: ``decode_chunk`` tokens for all S slots in
-        one fixed-shape program. Per-slot positions ride the slab form
-        of the layer decode (:meth:`_run_layers`). Traced exactly once —
-        the counter below increments at trace time only, pinning the
-        zero-recompile claim."""
-        m, gen = self.model, self.gen
-        get_registry().counter("serve.engine.decode_traces").inc()
-        eos = gen.eos_token_id
-
-        def embed_one(t, p):
-            return m.embed_at(pre, t[None, None], p)[0]    # [1, d]
-
-        def step(carry, _):
-            if eos is None:
-                caches, tok, pos, key_data = carry
-            else:
-                caches, tok, pos, key_data, done = carry
-            h = jax.vmap(embed_one)(tok, pos)              # [S, 1, d]
-
-            h, caches = self._run_layers(block_stack, h, caches, pos)
-            logits = head_logits(m, post, h)[:, 0, :]      # [S, V]
-            keys = jax.random.wrap_key_data(key_data)
-            ks = jax.vmap(jax.random.split)(keys)          # [S, 2] keys
-            key_data = jax.random.key_data(ks[:, 0])
-            nxt = jax.vmap(
-                lambda lg, k: sample_logits(lg[None], k, gen)[0])(
-                    logits, ks[:, 1])
-            if eos is None:
-                return (caches, nxt, pos + 1, key_data), nxt
-            nxt = jnp.where(done, jnp.int32(gen.pad_token_id), nxt)
-            done = done | (nxt == jnp.int32(eos))
-            return (caches, nxt, pos + 1, key_data, done), nxt
-
-        init = (caches, tok, pos, key_data)
-        if eos is not None:
-            init = init + (tok == jnp.int32(eos),)
-        carry, toks = jax.lax.scan(step, init, None,
-                                   length=self.decode_chunk)
-        caches, tok, pos, key_data = carry[:4]
-        return caches, tok, pos, key_data, jnp.moveaxis(toks, 0, 1)
 
     # -- paged device programs ---------------------------------------------
 
@@ -562,131 +620,16 @@ class SingleDeviceSlotBackend:
                     pool_kv[name], rows[:, None], dst, axis=1)
         return out
 
-    def _gather_views(self, pool_kv, tables, views, regather):
-        """The per-slot block views ``[L, S, R, C]`` (the carried
-        layout of :meth:`_run_layers`, heads folded): gathered afresh
-        from the pool through each slot's first ``max_blocks`` table
-        entries iff ``regather`` (traced), else the carried ones. The
-        gather is a whole copy anyway; the fold rides it."""
-        bs = self.pool.block_size
-        cd = self.model.cfg.compute_dtype
-        view_t = tables[:, :self.pool.max_blocks + 1]
-
-        def gather_layer(pool_l):
-            out = jax.vmap(lambda tr: gather_block_cache(
-                pool_l, tr, block_size=bs, compute_dtype=cd))(view_t)
-            return {name: fold_heads(a[:, 0])
-                    for name, a in out.items()}            # [S, R, C]
-
-        return jax.lax.cond(
-            regather, lambda v: jax.vmap(gather_layer)(pool_kv),
-            lambda v: v, views)
-
-    def _scatter_view_rows(self, pool_kv, views, tables, pos0, n):
-        """The ``n`` rows every slot wrote into its view from ``pos0``
-        on, unfolded, back into the pool through the FULL-width
-        tables."""
-        bs = self.pool.block_size
-        attn = self.model.block.attn
-        ridx = jax.vmap(lambda tr, p0: flat_row_index(
-            tr, p0 + jnp.arange(n, dtype=jnp.int32), bs))(tables, pos0)
-
-        def scat_layer(_, inp):
-            pool_l, view_l = inp
-            rows = {}
-            for name in ("k", "v"):
-                new = jax.vmap(
-                    lambda v, p0: jax.lax.dynamic_slice_in_dim(v, p0, n))(
-                        view_l[name], pos0)                # [S, n, C]
-                rows[name] = unfold_heads(                 # [S*n, H, D]
-                    new.reshape(-1, new.shape[-1]), attn.nhead,
-                    attn.head_dim)
-            return 0, scatter_block_rows(pool_l, ridx.reshape(-1), rows)
-
-        return jax.lax.scan(scat_layer, 0, (pool_kv, views))[1]
-
-    def _decode_paged_fn(self, block_stack, pre, post, pool_kv, tables,
-                         tok, pos, key_data, views, regather):
-        """The paged decode step: each slot's block view — its first
-        ``max_blocks`` table entries, covering every row it can read or
-        write (``rows_needed <= max_len``), exactly the slab's attention
-        footprint — is gathered ONLY when ``regather`` says a prefill
-        moved a table since the last chunk; otherwise the views carried
-        from the previous chunk are the same rows bitwise, because the
-        end-of-chunk scatter keeps the pool current every tick. The
-        chunk then runs ``decode_chunk`` slab-style steps against the
-        view (bitwise-identical attention math, in-chunk rows read back
-        from the view exactly as the slab reads its own updates), and
-        the S*C new rows scatter back once through the FULL-width
-        tables, whose sacrificial clamp routes overshoot/dead-slot
-        writes into block 0 — a dead slot can never corrupt a
-        reallocated block. Traced once; the same counter as the slab
-        path pins zero steady-state recompiles."""
-        m, gen = self.model, self.gen
-        get_registry().counter("serve.engine.decode_traces").inc()
-        eos = gen.eos_token_id
-        C = self.decode_chunk
-        pos0 = pos
-
-        def embed_one(t, p):
-            return m.embed_at(pre, t[None, None], p)[0]    # [1, d]
-
-        views = self._gather_views(pool_kv, tables, views, regather)
-
-        def step(carry, _):
-            if eos is None:
-                views, tok, pos, key_data = carry
-            else:
-                views, tok, pos, key_data, done = carry
-            h = jax.vmap(embed_one)(tok, pos)              # [S, 1, d]
-
-            h, views = self._run_layers(block_stack, h, views, pos)
-            logits = head_logits(m, post, h)[:, 0, :]      # [S, V]
-            keys = jax.random.wrap_key_data(key_data)
-            ks = jax.vmap(jax.random.split)(keys)          # [S, 2] keys
-            key_data = jax.random.key_data(ks[:, 0])
-            nxt = jax.vmap(
-                lambda lg, k: sample_logits(lg[None], k, gen)[0])(
-                    logits, ks[:, 1])
-            if eos is None:
-                return (views, nxt, pos + 1, key_data), nxt
-            nxt = jnp.where(done, jnp.int32(gen.pad_token_id), nxt)
-            done = done | (nxt == jnp.int32(eos))
-            return (views, nxt, pos + 1, key_data, done), nxt
-
-        init = (views, tok, pos, key_data)
-        if eos is not None:
-            init = init + (tok == jnp.int32(eos),)
-        carry, toks = jax.lax.scan(step, init, None, length=C)
-        views, tok, pos, key_data = carry[:4]
-
-        # rows written this chunk, back through the full-width tables
-        pool_kv = self._scatter_view_rows(pool_kv, views, tables, pos0, C)
-        return pool_kv, tok, pos, key_data, views, jnp.moveaxis(toks, 0, 1)
-
-    # -- resident device programs ------------------------------------------
-    #
-    # The resident loop is a `lax.while_loop` over the SAME per-chunk
-    # math as the single-chunk programs above (the step bodies are
-    # duplicated around the one shared layer loop, `_run_layers`). The
-    # carry adds three things the host used to own: a per-slot `done`
-    # mask (eos/length), a per-slot token `budget` (remaining
-    # max_new_tokens), and — paged — the `regather` flag, consumed and
-    # cleared on device. The loop exits
-    # early when any LIVE slot goes done (a slot freed: host admission
-    # can change the slot set) or after `r_max` chunks (the deadline
-    # horizon). One host sync per launch: the chunk count `k`, which
-    # sizes the token readout. Per-step token/key/pos evolution is
-    # bitwise the single-chunk chain; tokens past a slot's eos/budget
-    # are pad and the host's readout break reaches them never.
+    # -- THE decode program ------------------------------------------------
 
     def _resident_step(self, block_stack, pre, post, carry):
-        """One decode step shared by the two non-spec resident bodies:
-        the exact `_decode_fn`/`_decode_paged_fn` step with the done
-        mask extended by the token budget."""
+        """THE decode step: one token for all S slots at per-slot
+        positions (the slab form of the layer decode,
+        :meth:`_run_layers`), each slot on its own batch-1 Generator
+        key chain, the done mask extended by eos and the token budget."""
         m, gen = self.model, self.gen
         eos = gen.eos_token_id
-        caches, tok, pos, key_data, done, budget = carry
+        caches, tok, pos, key_data, hist, done, budget = carry
 
         def embed_one(t, p):
             return m.embed_at(pre, t[None, None], p)[0]
@@ -706,104 +649,93 @@ class SingleDeviceSlotBackend:
         done = done | (budget <= 0)
         if eos is not None:
             done = done | (nxt == jnp.int32(eos))
-        return (caches, nxt, pos + 1, key_data, done, budget), nxt
+        return (caches, nxt, pos + 1, key_data, hist, done, budget), nxt
 
-    def _resident_done0(self, tok, live, budget):
-        """Initial done mask: dead slots, spent budgets, and slots whose
-        first token already hit eos (the engine retires those before
-        decode — this covers direct backend callers)."""
-        done = ~live | (budget <= 0)
-        if self.gen.eos_token_id is not None:
-            done = done | (tok == jnp.int32(self.gen.eos_token_id))
-        return done
+    def _plain_round(self, block_stack, pre, post, carry):
+        """Seam B, the plain round: ``decode_chunk`` steps. Every live
+        slot emits the whole chunk (``n_emit`` None), pad past its
+        eos/budget."""
+        carry, toks = jax.lax.scan(
+            lambda c, _: self._resident_step(block_stack, pre, post, c),
+            carry, None, length=self.decode_chunk)
+        return carry, jnp.moveaxis(toks, 0, 1), None
 
-    def _resident_fn(self, block_stack, pre, post, caches, tok, pos,
-                     key_data, live, budget, r_max):
-        """Slab resident loop: up to ``r_max`` (traced, <= the static
-        ``resident_chunks``) decode chunks back-to-back in one program.
-        Returns the token buffer ``[S, R*C]``, per-chunk valid counts
-        ``[S, R]`` and the chunk count actually run."""
-        get_registry().counter("serve.engine.resident_traces").inc()
-        C = self.decode_chunk
-        R = self.resident_chunks
-        S = tok.shape[0]
+    def _decode_program(self, store, rnd):
+        """Build THE decode program: one ``lax.while_loop`` of rounds
+        over the carry ``(rows, tok, pos, key_data, hist, done, budget,
+        back, buf, counts, k)``, calling at one site each the two things
+        that differ between its uses. Seam A, the cache ``store``
+        (:class:`_SlabStore` | :class:`_PoolStore`): what stands behind
+        the carried ``[L, S, T, C]`` rows. Seam B, the round ``rnd``
+        (:meth:`_plain_round` | :meth:`_spec_round`): what one iteration
+        emits. ``done`` is the per-slot eos/length mask and ``budget``
+        the per-slot remaining max_new_tokens; the loop exits early when
+        any LIVE slot goes done (a slot freed: host admission can change
+        the slot set) or after ``r_max`` rounds (traced, <= the static
+        ``resident_chunks``: the deadline horizon, 1 where ``resident``
+        is off). Per-step token/key/pos evolution is bitwise the batch-1
+        Generator chain however the rounds are chopped into launches;
+        tokens past a slot's eos/budget are pad and the host's readout
+        break reaches them never.
 
-        def body(state):
-            caches, tok, pos, key_data, done, budget, buf, k = state
-            carry, toks = jax.lax.scan(
-                lambda c, _: self._resident_step(
-                    block_stack, pre, post, c),
-                (caches, tok, pos, key_data, done, budget), None, length=C)
-            caches, tok, pos, key_data, done, budget = carry
-            buf = jax.lax.dynamic_update_slice(
-                buf, jnp.moveaxis(toks, 0, 1), (0, k * C))
-            return caches, tok, pos, key_data, done, budget, buf, k + 1
+        The program returns the store's KV, the slots' state, the token
+        buffer ``[S, R*W]``, per-round valid counts ``[S, R]`` and the
+        round count run (the launch's ONE host sync, which sizes the
+        readout). Traced exactly once per round width — the counter
+        below increments at trace time only, pinning the zero-recompile
+        claim (``decode_traces`` for a one-chunk horizon,
+        ``resident_traces`` for a longer one)."""
+        W, R, S = rnd.width, self.resident_chunks, self.num_slots
+        pad, eos = self.gen.pad_token_id, self.gen.eos_token_id
+        traces = ("serve.engine.resident_traces" if R > 1
+                  else "serve.engine.decode_traces")
 
-        def cond(state):
-            return (state[7] < r_max) & ~jnp.any(live & state[4])
+        def _resident_fn(block_stack, pre, post, kv, aux, tok, pos,
+                         key_data, hist, live, budget, r_max):
+            get_registry().counter(traces).inc()
 
-        buf0 = jnp.full((S, R * C), jnp.int32(self.gen.pad_token_id),
-                        jnp.int32)
-        state = (caches, tok, pos, key_data,
-                 self._resident_done0(tok, live, budget), budget, buf0,
-                 jnp.int32(0))
-        caches, tok, pos, key_data, done, budget, buf, k = \
-            jax.lax.while_loop(cond, body, state)
-        counts = jnp.where(
-            (jnp.arange(R, dtype=jnp.int32)[None, :] < k) & live[:, None],
-            jnp.int32(C), jnp.int32(0))
-        return caches, tok, pos, key_data, buf, counts, k
+            # slots: what a round advances, (rows, tok, pos, key_data,
+            # hist, done, budget)
+            def body(state):
+                slots, back, buf, counts, k = state
+                pos0 = slots[2]
+                slots, toks, n_emit = rnd.run(block_stack, pre, post, slots)
+                back = store.commit(back, slots[0], aux, pos0, rnd.rows)
+                buf = jax.lax.dynamic_update_slice(buf, toks, (0, k * W))
+                if rnd.counted:
+                    counts = jax.lax.dynamic_update_slice(
+                        counts, n_emit[:, None], (0, k))
+                return slots, back, buf, counts, k + 1
 
-    def _resident_paged_fn(self, block_stack, pre, post, pool_kv, tables,
-                           tok, pos, key_data, views, regather, live,
-                           budget, r_max):
-        """Paged resident loop. The regather decision rides the carry:
-        the (traced) flag gathers fresh views once at entry iff a
-        prefill moved a table since the last launch, and the program
-        returns it CLEARED — a no-prefill tick launches with the cold
-        flag and performs zero host-driven gather decisions. The
-        2-branch cond is a role conditional (both branches produce the
-        same view shape), not a dispatch."""
-        get_registry().counter("serve.engine.resident_traces").inc()
-        C = self.decode_chunk
-        R = self.resident_chunks
-        S = tok.shape[0]
-        views = self._gather_views(pool_kv, tables, views, regather)
+            def cond(state):
+                slots, _, _, _, k = state
+                return (k < r_max) & ~jnp.any(live & slots[5])
 
-        def body(state):
-            pool_kv, views, tok, pos, key_data, done, budget, buf, k = state
-            pos0 = pos
-            carry, toks = jax.lax.scan(
-                lambda c, _: self._resident_step(
-                    block_stack, pre, post, c),
-                (views, tok, pos, key_data, done, budget), None, length=C)
-            views, tok, pos, key_data, done, budget = carry
-            pool_kv = self._scatter_view_rows(pool_kv, views, tables,
-                                              pos0, C)
-            buf = jax.lax.dynamic_update_slice(
-                buf, jnp.moveaxis(toks, 0, 1), (0, k * C))
-            return (pool_kv, views, tok, pos, key_data, done, budget,
-                    buf, k + 1)
+            # dead slots, spent budgets, and slots whose first token
+            # already hit eos (the engine retires those before decode —
+            # this covers direct backend callers) start done
+            done = ~live | (budget <= 0)
+            if eos is not None:
+                done = done | (tok == jnp.int32(eos))
+            rows, back = store.enter(kv, aux)
+            slots, back, buf, counts, k = jax.lax.while_loop(cond, body, (
+                (rows, tok, pos, key_data, hist, done, budget), back,
+                jnp.full((S, R * W), jnp.int32(pad), jnp.int32),
+                jnp.zeros((S, R), jnp.int32) if rnd.counted else None,
+                jnp.int32(0)))
+            rows, tok, pos, key_data, hist, _, _ = slots
+            if not rnd.counted:     # every live slot emitted every round
+                counts = jnp.where(
+                    (jnp.arange(R, dtype=jnp.int32)[None, :] < k)
+                    & live[:, None], jnp.int32(W), jnp.int32(0))
+            return (store.leave(rows, back), tok, pos, key_data, hist, buf,
+                    counts, k)
 
-        def cond(state):
-            return (state[8] < r_max) & ~jnp.any(live & state[5])
+        return _resident_fn
 
-        buf0 = jnp.full((S, R * C), jnp.int32(self.gen.pad_token_id),
-                        jnp.int32)
-        state = (pool_kv, views, tok, pos, key_data,
-                 self._resident_done0(tok, live, budget), budget, buf0,
-                 jnp.int32(0))
-        pool_kv, views, tok, pos, key_data, done, budget, buf, k = \
-            jax.lax.while_loop(cond, body, state)
-        counts = jnp.where(
-            (jnp.arange(R, dtype=jnp.int32)[None, :] < k) & live[:, None],
-            jnp.int32(C), jnp.int32(0))
-        return (pool_kv, tok, pos, key_data, views,
-                jnp.zeros((), jnp.bool_), buf, counts, k)
-
-    # -- speculative resident programs -------------------------------------
+    # -- the speculative round ---------------------------------------------
     #
-    # One resident iteration becomes a draft/verify ROUND: propose
+    # One loop iteration becomes a draft/verify ROUND: propose
     # K-1 tokens by prompt-lookup (the most recent earlier occurrence
     # of the current token in the slot's device-side history buffer),
     # verify [tok, drafts] teacher-forced in ONE fixed-shape q=K decode
@@ -817,11 +749,13 @@ class SingleDeviceSlotBackend:
     # the sequential Generator chain.
 
     def _spec_round(self, K, block_stack, pre, post, carry):
-        """One draft/verify round (shared by the slab/paged spec
-        bodies) at ladder depth ``K``. Carry: (caches-or-views, tok,
-        pos, key_data, hist, done, budget); returns the updated carry
-        plus the round's ``[S, K]`` token row and ``[S]`` accepted
-        counts.
+        """Seam B, the speculative round: one draft/verify round at
+        ladder depth ``K``. Carry: (caches-or-views, tok, pos,
+        key_data, hist, done, budget); returns the updated carry, the
+        round's ``[S, K]`` token row and ``[S]`` accepted counts. In
+        the paged store the ``Q`` verify rows scatter back through the
+        full-width tables (rejected/dead rows route to the sacrificial
+        block exactly like dead-slot decode).
 
         With a multi-branch drafter the verify chunk is the flattened
         draft tree — ``Q = 1 + B*(K-1)`` rows under the causal tree
@@ -950,88 +884,8 @@ class SingleDeviceSlotBackend:
         if eos is not None:
             done = done | jnp.any(
                 (t_lin == jnp.int32(eos)) & emit_mask, axis=1)
-        return (caches, tok, pos, key_data, hist, done, budget,
+        return ((caches, tok, pos, key_data, hist, done, budget),
                 toks_out, n_emit)
-
-    def _resident_spec_fn(self, K, block_stack, pre, post, caches, tok,
-                          pos, key_data, hist, live, budget, r_max):
-        """Slab resident loop with the speculative lane: each iteration
-        is one draft/verify round emitting 1..K tokens per live slot."""
-        get_registry().counter("serve.engine.resident_traces").inc()
-        R = self.resident_chunks
-        S = tok.shape[0]
-
-        def body(state):
-            caches, tok, pos, key_data, hist, done, budget, \
-                buf, nacc, k = state
-            (caches, tok, pos, key_data, hist, done, budget, toks,
-             n_emit) = self._spec_round(
-                K, block_stack, pre, post,
-                (caches, tok, pos, key_data, hist, done, budget))
-            buf = jax.lax.dynamic_update_slice(buf, toks, (0, k * K))
-            nacc = jax.lax.dynamic_update_slice(
-                nacc, n_emit[:, None], (0, k))
-            return (caches, tok, pos, key_data, hist, done, budget,
-                    buf, nacc, k + 1)
-
-        def cond(state):
-            return (state[9] < r_max) & ~jnp.any(live & state[5])
-
-        buf0 = jnp.full((S, R * K), jnp.int32(self.gen.pad_token_id),
-                        jnp.int32)
-        nacc0 = jnp.zeros((S, R), jnp.int32)
-        state = (caches, tok, pos, key_data, hist,
-                 self._resident_done0(tok, live, budget), budget,
-                 buf0, nacc0, jnp.int32(0))
-        caches, tok, pos, key_data, hist, done, budget, buf, nacc, k = \
-            jax.lax.while_loop(cond, body, state)
-        return caches, tok, pos, key_data, hist, buf, nacc, k
-
-    def _resident_spec_paged_fn(self, K, block_stack, pre, post,
-                                pool_kv, tables, tok, pos, key_data,
-                                views, regather, hist, live, budget,
-                                r_max):
-        """Paged resident loop with the speculative lane: the verify
-        runs against the carried views, each round's Q chunk rows
-        scatter back through the full-width tables (rejected/dead rows
-        route to the sacrificial block exactly like dead-slot
-        decode)."""
-        get_registry().counter("serve.engine.resident_traces").inc()
-        B = self._drafter.branches
-        Q = 1 + B * (K - 1) if B > 1 else K
-        R = self.resident_chunks
-        S = tok.shape[0]
-        views = self._gather_views(pool_kv, tables, views, regather)
-
-        def body(state):
-            pool_kv, views, tok, pos, key_data, hist, done, budget, \
-                buf, nacc, k = state
-            pos0 = pos
-            (views, tok, pos, key_data, hist, done, budget, toks,
-             n_emit) = self._spec_round(
-                K, block_stack, pre, post,
-                (views, tok, pos, key_data, hist, done, budget))
-            pool_kv = self._scatter_view_rows(pool_kv, views, tables,
-                                              pos0, Q)
-            buf = jax.lax.dynamic_update_slice(buf, toks, (0, k * K))
-            nacc = jax.lax.dynamic_update_slice(
-                nacc, n_emit[:, None], (0, k))
-            return (pool_kv, views, tok, pos, key_data, hist, done,
-                    budget, buf, nacc, k + 1)
-
-        def cond(state):
-            return (state[10] < r_max) & ~jnp.any(live & state[6])
-
-        buf0 = jnp.full((S, R * K), jnp.int32(self.gen.pad_token_id),
-                        jnp.int32)
-        nacc0 = jnp.zeros((S, R), jnp.int32)
-        state = (pool_kv, views, tok, pos, key_data, hist,
-                 self._resident_done0(tok, live, budget), budget,
-                 buf0, nacc0, jnp.int32(0))
-        (pool_kv, views, tok, pos, key_data, hist, done, budget, buf,
-         nacc, k) = jax.lax.while_loop(cond, body, state)
-        return (pool_kv, tok, pos, key_data, views,
-                jnp.zeros((), jnp.bool_), hist, buf, nacc, k)
 
     # -- backend API -------------------------------------------------------
 
@@ -1096,10 +950,15 @@ class SingleDeviceSlotBackend:
                                 self._caches, arr, jnp.int32(p),
                                 jnp.int32(slot), key)
         self._caches = caches
+        return self._arm_slot(slot, tok0, p, key)
+
+    def _arm_slot(self, slot: int, tok0, pos: int, key) -> int:
+        """The blocking read of the first token, then the slot's
+        (token, position, key) triple the decode program starts from."""
         with ev.span(ev.SERVE_PREFILL_SYNC, slot=slot):
             tok0 = int(tok0)
         self._tok = self._tok.at[slot].set(tok0)
-        self._pos = self._pos.at[slot].set(p)
+        self._pos = self._pos.at[slot].set(pos)
         self._key_data = self._key_data.at[slot].set(
             jax.random.key_data(key))
         return tok0
@@ -1164,117 +1023,61 @@ class SingleDeviceSlotBackend:
             except Exception:
                 self.pool.release(slot, failed=True)
                 raise
-            with ev.span(ev.SERVE_PREFILL_SYNC, slot=slot):
-                tok0 = int(tok0)
-            self._tok = self._tok.at[slot].set(tok0)
-            self._pos = self._pos.at[slot].set(plen)
-            self._key_data = self._key_data.at[slot].set(
-                jax.random.key_data(key))
-            self._views_dirty = True       # this slot's table moved
-            if self.resident:
-                # arm the device-side regather flag — the ONE host gather
-                # decision per admission (counted here; steady-state
-                # resident ticks make zero)
-                self._regather = jnp.asarray(True)
-                get_registry().counter(
-                    "serve.kv.regather_host_decisions").inc()
+            tok0 = self._arm_slot(slot, tok0, plen, key)
+            # this slot's table moved: arm the device-side regather flag
+            # — the ONE host gather decision per admission (counted here;
+            # steady-state ticks make zero)
+            self._regather = jnp.asarray(True)
+            get_registry().counter("serve.kv.regather_host_decisions").inc()
         self._count_prompt(get_registry(), plen, rows)
         self._hist_write(slot, prompt, tok0)
         return tok0
 
+    def _decode_args(self, live, budget, r_max):
+        """The decode program's arguments at this backend's state."""
+        kv, aux = self._store.take()
+        return (self._block_stack, self._pre, self._post, kv, aux,
+                self._tok, self._pos, self._key_data, self._hist, live,
+                budget, r_max)
+
+    def decode_program(self, k: Optional[int] = None):
+        """For tests and audits: THE decode program (round width ``k``,
+        a ladder rung; default the current one) as ``(jitted function,
+        its arguments at this backend's sizes)``, to ``.lower(*args)``
+        or ``jax.make_jaxpr``."""
+        live = jnp.ones((self.num_slots,), bool)
+        args = self._decode_args(live, live.astype(jnp.int32),
+                                 jnp.int32(self.resident_chunks))
+        return self._resident_jits[k or self.decode_width], args
+
     def decode(self, live: np.ndarray,
                budgets: Optional[np.ndarray] = None,
                r_max: Optional[int] = None):
-        """One decode chunk for all slots. Returns ``(tokens [S, K],
-        valid [S, K])`` — dead slots compute garbage (their rows are
-        rewritten at the next prefill — or, paged, land in the
-        sacrificial block); ``valid`` masks them out.
-
-        With ``budgets`` (per-slot remaining max_new_tokens) on a
-        resident backend, the call runs the RESIDENT loop instead: up
-        to ``r_max`` chunks (default ``resident_chunks``) in one
-        device program, returning ``[S, k*width]`` tokens with the
-        per-chunk validity the device's done-masking produced. Without
-        ``budgets`` the single-chunk path runs even when
-        ``resident=True`` — that is the parity reference."""
-        if self.resident and budgets is not None:
-            return self._decode_resident(live, budgets, r_max)
-        with ev.span(ev.SERVE_DECODE_LAUNCH, chunks=1):
-            if self.paged:
-                get_registry().counter(
-                    "serve.kv.regather_host_decisions").inc()
-                pool_kv, tok, pos, kd, views, toks = self._decode_jit(
-                    self._block_stack, self._pre, self._post, self._pool_kv,
-                    jnp.asarray(self.pool.table), self._tok, self._pos,
-                    self._key_data, self._views,
-                    jnp.asarray(self._views_dirty))
-                self._pool_kv = pool_kv
-                self._views = views
-                self._views_dirty = False
-                if self.resident:
-                    self._regather = jnp.asarray(False)  # views now current
-            else:
-                caches, tok, pos, kd, toks = self._decode_jit(
-                    self._block_stack, self._pre, self._post, self._caches,
-                    self._tok, self._pos, self._key_data)
-                self._caches = caches
-        self._tok, self._pos, self._key_data = tok, pos, kd
-        with ev.span(ev.SERVE_DECODE_SYNC):
-            toks = np.asarray(toks)
-        valid = np.broadcast_to(
-            np.asarray(live, bool)[:, None], toks.shape)
-        return toks, valid
-
-    def _decode_resident(self, live: np.ndarray, budgets: np.ndarray,
-                         r_max: Optional[int]):
-        """One resident launch: up to ``r_max`` chunks/rounds on
-        device, ONE host sync (the chunk count) to size the readout."""
+        """One launch of the decode program for all slots: up to
+        ``r_max`` rounds (default and at most ``resident_chunks``) on
+        device under the per-slot ``budgets`` (remaining
+        max_new_tokens), ONE host sync (the round count) to size the
+        readout. Returns ``(tokens [S, k*width], valid [S, k*width])``
+        — dead slots are masked out by ``valid``; what they write is
+        rewritten at the next prefill — or, paged, lands in the
+        sacrificial block. Without ``budgets`` the same program runs one
+        round with no budget limit."""
         reg = get_registry()
         R = self.resident_chunks
+        if budgets is None:
+            budgets = np.full((self.num_slots,), np.iinfo(np.int32).max)
+            r_max = 1
         rm = R if r_max is None else max(1, min(int(r_max), R))
         live_d = jnp.asarray(np.asarray(live, bool))
         budget = jnp.asarray(np.asarray(budgets, np.int32))
         if self.spec_tokens is not None:
             self.decode_width = self._pick_spec_k(live)
         with ev.span(ev.SERVE_DECODE_LAUNCH, chunks=rm):
-            if self.paged:
-                tables = jnp.asarray(self.pool.table)
-                if self.spec_tokens is not None:
-                    (pool_kv, tok, pos, kd, views, regather, hist, buf,
-                     counts, k) = self._resident_spec_jits[self.decode_width](
-                        self._block_stack, self._pre, self._post,
-                        self._pool_kv, tables, self._tok, self._pos,
-                        self._key_data, self._views, self._regather,
-                        self._hist, live_d, budget, jnp.int32(rm))
-                    self._hist = hist
-                else:
-                    (pool_kv, tok, pos, kd, views, regather, buf, counts,
-                     k) = self._resident_jit(
-                        self._block_stack, self._pre, self._post,
-                        self._pool_kv, tables, self._tok, self._pos,
-                        self._key_data, self._views, self._regather,
-                        live_d, budget, jnp.int32(rm))
-                self._pool_kv = pool_kv
-                self._views = views
-                self._views_dirty = False
-                self._regather = regather          # cleared, never synced
-            else:
-                if self.spec_tokens is not None:
-                    caches, tok, pos, kd, hist, buf, counts, k = \
-                        self._resident_spec_jits[self.decode_width](
-                            self._block_stack, self._pre, self._post,
-                            self._caches, self._tok, self._pos,
-                            self._key_data, self._hist, live_d, budget,
-                            jnp.int32(rm))
-                    self._hist = hist
-                else:
-                    caches, tok, pos, kd, buf, counts, k = \
-                        self._resident_jit(
-                            self._block_stack, self._pre, self._post,
-                            self._caches, self._tok, self._pos,
-                            self._key_data, live_d, budget, jnp.int32(rm))
-                self._caches = caches
-        self._tok, self._pos, self._key_data = tok, pos, kd
+            kv, tok, pos, kd, hist, buf, counts, k = \
+                self._resident_jits[self.decode_width](
+                    *self._decode_args(live_d, budget, jnp.int32(rm)))
+            self._store.put(kv)
+        self._tok, self._pos, self._key_data, self._hist = tok, pos, kd, hist
         with ev.span(ev.SERVE_DECODE_SYNC):
             k = int(k)                         # THE host sync
             buf = np.asarray(buf)              # then the two fetches
@@ -1764,7 +1567,7 @@ class ServeEngine:
 
     def tick(self) -> List[Response]:
         """One scheduler step: sweep deadlines/cancellations, apply the
-        watchdog policies, admit into free slots, run one decode chunk,
+        watchdog policies, admit into free slots, launch the decode,
         retire. Returns the requests that reached a terminal state
         during this tick."""
         with self.events.span(ev.SERVE_TICK, tick=self._tick_index,
@@ -1917,7 +1720,8 @@ class ServeEngine:
                     finished.append(
                         self._retire(slot, "ok", "length", t_first))
 
-        # 3) decode — one fixed-shape chunk for every slot. A failure is
+        # 3) decode — one launch for every slot, under the slots' token
+        # budgets and the deadline horizon. A failure is
         # NOT attributable (all slots share the program): skip the tick
         # with slot state intact, and only a run of consecutive failures
         # retires the live set.
@@ -1929,21 +1733,17 @@ class ServeEngine:
             # prompt and the tokens sampled so far
             rows = sum(len(s.req.prompt) + len(s.tokens)
                        for s in self._slots if s is not None)
-            r_max = 1
             t0 = self.clock()
             try:
                 reg.counter("serve.engine.host_syncs").inc()
                 with self.events.span(ev.SERVE_DECODE, live=n_live):
-                    if getattr(self.backend, "resident", False):
-                        budgets = np.array(
-                            [0 if s is None else
-                             max(s.req.max_new_tokens - len(s.tokens), 0)
-                             for s in self._slots], np.int32)
-                        r_max = self._resident_horizon(now)
-                        toks, valid = self.backend.decode(
-                            live, budgets=budgets, r_max=r_max)
-                    else:
-                        toks, valid = self.backend.decode(live)
+                    budgets = np.array(
+                        [0 if s is None else
+                         max(s.req.max_new_tokens - len(s.tokens), 0)
+                         for s in self._slots], np.int32)
+                    r_max = self._resident_horizon(now)
+                    toks, valid = self.backend.decode(
+                        live, budgets=budgets, r_max=r_max)
             except Exception as e:           # noqa: BLE001 — containment
                 self._on_decode_error(reg, e, tick_idx, finished)
             else:

@@ -129,8 +129,9 @@ class RingSlotBackend:
                 f"{resident_revolutions}")
         self.resident_revolutions = resident_revolutions
         # the engine's deadline horizon speaks in "resident chunks";
-        # for the ring one chunk is one revolution
-        self.resident_chunks = resident_revolutions
+        # for the ring one chunk is one revolution (one where the
+        # single-launch program runs)
+        self.resident_chunks = resident_revolutions if self.resident else 1
         spec = spec_tokens if spec_tokens is not None \
             else gen.spec_tokens
         if spec is not None and spec < 2:

@@ -189,7 +189,7 @@ class Router(FleetController):
                 return "wedge_replica"
             return None
 
-        def chaotic_decode(live):
+        def chaotic_decode(live, **kw):
             kind = _dead()
             if kind is not None:
                 raise ChaosError(
@@ -198,7 +198,7 @@ class Router(FleetController):
             f = plan.replica_fault("slow_replica", router._tick_index, idx)
             if f is not None:
                 time.sleep(f.magnitude)
-            return orig_decode(live)
+            return orig_decode(live, **kw)
 
         # wraps() keeps the wrapped prefill's signature visible so the
         # engine's demand-kwarg probe (_prefill_kwargs) sees the real

@@ -730,9 +730,8 @@ def test_decode_hlo_byte_identical_under_obs_plane(registry):
         be = SingleDeviceSlotBackend(
             model, params, num_slots=2, max_len=24,
             gen=GenerationConfig(max_new_tokens=4, temperature=0.0))
-        return be._decode_jit.lower(
-            be._block_stack, be._pre, be._post, be._caches, be._tok,
-            be._pos, be._key_data).as_text(), be
+        fn, args = be.decode_program()
+        return fn.lower(*args).as_text(), be
 
     base, _ = lowered()
 

@@ -361,10 +361,8 @@ def test_prefix_cache_off_decode_hlo_identical(model_and_params):
         gen_cfg = GenerationConfig(max_new_tokens=4, temperature=0.0,
                                    prefix_cache=prefix_cache)
         be = _paged_backend("single", model, params, gen_cfg)
-        return be._decode_jit.lower(
-            be._block_stack, be._pre, be._post, be._pool_kv,
-            jnp.asarray(be.pool.table), be._tok, be._pos,
-            be._key_data, be._views, jnp.asarray(True)).as_text()
+        fn, args = be.decode_program()
+        return fn.lower(*args).as_text()
 
     assert lowered(True) == lowered(False)
 

@@ -253,11 +253,8 @@ def _lower_step(trainer):
 
 
 def _lower_resident(eng):
-    b = eng.backend
-    return b._resident_jit.lower(
-        b._block_stack, b._pre, b._post, b._caches, b._tok, b._pos,
-        b._key_data, jnp.ones((2,), bool), jnp.ones((2,), jnp.int32),
-        jnp.int32(2))
+    fn, args = eng.backend.decode_program()
+    return fn.lower(*args)
 
 
 def test_the_train_step_names_every_scope_it_uses():

@@ -2,20 +2,24 @@
 
 Gold contract, layered on the serve suite's pins:
 
-* **Parity.** A resident backend — the `lax.while_loop` that runs up to
-  ``resident_chunks`` decode chunks back-to-back on device — emits
-  bitwise the tokens of the single-chunk tick path, on both backends,
-  slab and paged, greedy and sampled. The single-chunk path itself is
-  pinned to the one-shot ``Generator`` by tests/test_serve.py, so the
-  resident loop inherits the gold contract transitively (and we
-  re-assert it directly for greedy).
+* **Parity.** R chunks in one launch equal R launches of one chunk:
+  a resident backend — the decode program's `lax.while_loop` allowed up
+  to ``resident_chunks`` chunks back-to-back on device — emits bitwise
+  the tokens of ``resident=False``, the same program at a horizon of
+  one chunk (the ring: its single-launch program), on both backends,
+  slab and paged, greedy and sampled. The one-chunk horizon is pinned
+  to the one-shot ``Generator`` by tests/test_serve.py, so the longer
+  one inherits the gold contract transitively (and we re-assert it
+  directly for greedy).
 * **Zero steady-state recompiles.** The resident program traces exactly
   once across staggered arrivals and mixed prompt lengths
   (``serve.engine.resident_traces`` / ``serve.ring.resident_traces``).
-* **The regather decision lives on device.** A steady-state resident
-  tick (no prefill) makes ZERO host-driven gather decisions
-  (``serve.kv.regather_host_decisions``); the non-resident paged path
-  makes one per tick.
+* **The regather decision lives on device.** A steady-state tick (no
+  prefill) makes ZERO host-driven gather decisions
+  (``serve.kv.regather_host_decisions``), at every horizon.
+* **``resident`` is a horizon and nothing else.** A backend used with
+  and without ``budgets=`` traces its decode program once, and
+  ``resident=False`` is bitwise ``resident=True, resident_chunks=1``.
 * **Early exit.** The device loop exits before ``r_max`` when any live
   slot finishes (``serve.engine.device_exits``), so a freed slot waits
   at most one chunk, not a full horizon.
@@ -119,9 +123,10 @@ PARITY_IDS = [f"{k}-{l}-{'greedy' if t == 0.0 else 'sampled'}"
 @pytest.mark.parametrize("kind,layout,temp", PARITY_CASES, ids=PARITY_IDS)
 def test_resident_matches_single_chunk_tick(kind, layout, temp,
                                             model_and_params):
-    """resident=True with a small horizon (forcing several launches)
-    emits bitwise the non-resident tick path; greedy additionally
-    re-pins the one-shot Generator directly."""
+    """R chunks in one launch equal R launches of one chunk, both of
+    the same program: resident=True with a small horizon (forcing
+    several launches) emits bitwise what resident=False does; greedy
+    additionally re-pins the one-shot Generator directly."""
     model, params = model_and_params
     gen_cfg = GenerationConfig(max_new_tokens=6, temperature=temp,
                                top_k=12 if temp else None)
@@ -192,36 +197,26 @@ def test_resident_traces_once_and_counts_host_syncs(kind,
     assert reg.gauge("serve.engine.host_overhead_per_token").value >= 0.0
 
 
-def test_regather_decision_stays_on_device(model_and_params):
-    """Paged resident: prefill arms the device regather flag (one host
-    decision per admission); steady-state resident ticks make ZERO.
-    The non-resident path decides once per tick — the host tax the
-    carry fold removes."""
+@pytest.mark.parametrize("resident", [True, False])
+def test_regather_decision_stays_on_device(resident, model_and_params):
+    """Paged: prefill arms the device regather flag (one host decision
+    per admission); steady-state ticks make ZERO, whatever the horizon
+    — the flag rides the one decode program's carry (``resident=False``
+    used to decide once per tick on the host)."""
     model, params = model_and_params
     gen_cfg = GenerationConfig(max_new_tokens=8, temperature=0.0)
     reg = get_registry()
 
-    res = _make_backend("single", model, params, gen_cfg, "paged",
-                        resident=True, resident_chunks=1)
-    eng = ServeEngine(res)
+    backend = _make_backend("single", model, params, gen_cfg, "paged",
+                            resident=resident, resident_chunks=1)
+    eng = ServeEngine(backend)
+    d0 = reg.counter("serve.kv.regather_host_decisions").value
     eng.submit(_mixed_prompts((4,))[0], seed=7)
     eng.submit(_mixed_prompts((5,))[0], seed=7)
     eng.tick()          # prefills (arm the flag) + first launch
-    d0 = reg.counter("serve.kv.regather_host_decisions").value
+    assert reg.counter("serve.kv.regather_host_decisions").value - d0 == 2
     eng.tick()
     eng.tick()          # two steady-state ticks: no prefill
-    assert reg.counter("serve.kv.regather_host_decisions").value - d0 == 0
-    eng.run_until_idle()
-
-    base = _make_backend("single", model, params, gen_cfg, "paged",
-                         resident=False)
-    eng = ServeEngine(base)
-    eng.submit(_mixed_prompts((4,))[0], seed=7)
-    eng.submit(_mixed_prompts((5,))[0], seed=7)
-    eng.tick()
-    d0 = reg.counter("serve.kv.regather_host_decisions").value
-    eng.tick()
-    eng.tick()
     assert reg.counter("serve.kv.regather_host_decisions").value - d0 == 2
     eng.run_until_idle()
 
@@ -243,6 +238,61 @@ def test_resident_early_exit_on_slot_free(model_and_params):
     assert toks.shape == (2, 2)
     assert valid.all()
     assert reg.counter("serve.engine.device_exits").value - exits0 == 1
+
+
+# ---------------------------------------------------------------------------
+# one decode program: ``resident`` is a horizon and nothing else
+
+
+@pytest.mark.parametrize("layout", ["slab", "paged"])
+def test_decode_traces_one_program_with_and_without_budgets(
+        layout, model_and_params):
+    """A backend used both ways — ``decode(live)``, then ``decode(live,
+    budgets=...)`` — traces its decode program ONCE: the call without
+    budgets is a launch of one chunk with no budget limit, not a second
+    program. Both continue the Generator's chain."""
+    model, params = model_and_params
+    gen_cfg = GenerationConfig(max_new_tokens=6, temperature=0.0)
+    prompts = _mixed_prompts((4, 5))
+    refs = _one_shot_refs(model, params, prompts, gen_cfg, seed=7)
+    backend = _make_backend("single", model, params, gen_cfg, layout,
+                            resident=True, resident_chunks=3)
+    first = [backend.prefill(s, p, seed=7) for s, p in enumerate(prompts)]
+    reg = get_registry()
+    names = ("serve.engine.decode_traces", "serve.engine.resident_traces")
+    traces0 = sum(reg.counter(n).value for n in names)
+    live = np.array([True, True])
+
+    one, valid = backend.decode(live)
+    assert one.shape == (2, 1) and valid.all()
+    more, valid = backend.decode(live, budgets=np.array([4, 4], np.int32))
+    assert more.shape == (2, 3) and valid.all()
+
+    assert sum(reg.counter(n).value for n in names) - traces0 == 1
+    for s, ref in enumerate(refs):
+        np.testing.assert_array_equal(
+            np.concatenate([[first[s]], one[s], more[s]]), ref[:5])
+
+
+def test_resident_off_is_a_horizon_of_one_chunk(model_and_params):
+    """``resident=False`` and ``resident=True, resident_chunks=1`` are
+    the same backend: bitwise the same tokens, positions and key data
+    over a staggered sampled run."""
+    model, params = model_and_params
+    gen_cfg = GenerationConfig(max_new_tokens=6, temperature=0.8, top_k=12)
+    prompts = _mixed_prompts((3, 5, 4))
+    runs = []
+    for kw in (dict(resident=False),
+               dict(resident=True, resident_chunks=1)):
+        backend = _make_backend("single", model, params, gen_cfg,
+                                decode_chunk=2, **kw)
+        assert backend.resident_chunks == 1
+        tokens = _drive_staggered(backend, prompts, seed=7)
+        runs.append((tokens, np.asarray(backend._tok),
+                     np.asarray(backend._pos),
+                     np.asarray(backend._key_data)))
+    for a, b in zip(*runs):
+        np.testing.assert_array_equal(a, b)
 
 
 # ---------------------------------------------------------------------------
@@ -469,8 +519,12 @@ def test_resident_knob_validation(model_and_params):
     with pytest.raises(ValueError, match="spec_tokens"):
         _make_backend("single", model, params, gen_cfg,
                       resident=True, spec_tokens=1)
+    # the speculative round is a round of the one loop: any horizon
+    # takes it (only the ring's wavefront is resident-only)
+    assert _make_backend("single", model, params, gen_cfg, resident=False,
+                         spec_tokens=3).resident_chunks == 1
     with pytest.raises(ValueError, match="resident"):
-        _make_backend("single", model, params, gen_cfg,
+        _make_backend("ring", model, params, gen_cfg,
                       resident=False, spec_tokens=3)
     # draft knobs configure the spec lane — meaningless without it
     with pytest.raises(ValueError, match="speculative lane"):
@@ -608,20 +662,10 @@ def _eqns(jaxpr, in_loop=False):
 
 
 def _slab_program(name, backend):
-    """(traced function, its arguments) of one of the slab backend's
-    decode programs."""
-    S = backend.num_slots
-    head = (backend._block_stack, backend._pre, backend._post,
-            backend._caches, backend._tok, backend._pos,
-            backend._key_data)
-    live, budget = jnp.ones((S,), bool), jnp.full((S,), 4, jnp.int32)
-    if name == "decode":
-        return backend._decode_fn, head
-    if name == "resident":
-        return backend._resident_fn, head + (live, budget, jnp.int32(2))
-    K = backend.spec_tokens
-    return (lambda *a: backend._resident_spec_fn(K, *a),
-            head + (backend._hist, live, budget, jnp.int32(2)))
+    """(traced function, its arguments) of the slab backend's decode
+    program. ``decode`` and ``resident`` are the one program (they were
+    two); ``spec`` is the speculative round's rung."""
+    return backend.decode_program()
 
 
 SLAB_PROGRAMS = {

@@ -346,9 +346,8 @@ def test_decode_hlo_unchanged_by_watchdog_and_chaos():
         be = SingleDeviceSlotBackend(
             model, params, num_slots=2, max_len=16,
             gen=GenerationConfig(max_new_tokens=4, temperature=1.0))
-        return be._decode_jit.lower(
-            be._block_stack, be._pre, be._post, be._caches, be._tok,
-            be._pos, be._key_data).as_text(), be
+        fn, args = be.decode_program()
+        return fn.lower(*args).as_text(), be
 
     base, _ = lowered()
     text, be = lowered()
@@ -448,11 +447,11 @@ def test_decode_errors_tolerated_then_retire_all(serve_backend):
     # below the limit: tick skipped, slot state intact, request finishes
     flaky = {"n": 0}
 
-    def flaky_decode(live):
+    def flaky_decode(live, **kw):
         flaky["n"] += 1
         if flaky["n"] <= 2:
             raise RuntimeError("transient")
-        return orig(live)
+        return orig(live, **kw)
 
     eng = ServeEngine(be, decode_error_limit=3)
     be.decode = flaky_decode
@@ -464,7 +463,7 @@ def test_decode_errors_tolerated_then_retire_all(serve_backend):
     assert eng.response(r.id).status == "ok"
 
     # at the limit: live slots retired as errors, engine stays usable
-    def dead_decode(live):
+    def dead_decode(live, **kw):
         raise RuntimeError("dead backend")
 
     eng2 = ServeEngine(be, decode_error_limit=2)
@@ -489,8 +488,8 @@ def test_stuck_slot_retired_as_error(serve_backend):
     be = serve_backend
     orig = be.decode
 
-    def no_progress(live):
-        toks, valid = orig(live)
+    def no_progress(live, **kw):
+        toks, valid = orig(live, **kw)
         return toks, np.zeros_like(valid)          # tokens never valid
 
     eng = ServeEngine(be, watchdog=TickWatchdog(stuck_slack_ticks=2))

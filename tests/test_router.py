@@ -69,7 +69,7 @@ class FakeBackend:
             raise RuntimeError("poisoned prompt")
         return 1
 
-    def decode(self, live):
+    def decode(self, live, budgets=None, r_max=None):
         toks = np.ones((self.num_slots, 1), np.int32)
         valid = np.broadcast_to(np.asarray(live, bool)[:, None],
                                 toks.shape)

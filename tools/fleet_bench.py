@@ -512,9 +512,9 @@ def straggler_trial(model, params, n_requests, seed, sleep_s=0.05,
             backend = router.replicas[2].engine.backend
             orig = backend.decode
 
-            def slow_decode(live, _orig=orig):
+            def slow_decode(live, _orig=orig, **kw):
                 time.sleep(sleep_s)
-                return _orig(live)
+                return _orig(live, **kw)
 
             backend.decode = slow_decode
             pace = 0.01 if mode == "thread" else 0.0

@@ -23,6 +23,7 @@ lists where the KV slab is copied, transposed or padded (:func:`slab`).
 
 from __future__ import annotations
 
+import collections
 import json
 import math
 import os
@@ -430,20 +431,7 @@ def resident(num_slots: int = 2, max_len: int = 16,
             kw["buckets"] = BucketSpec.of(max_len // 2)
         b = SingleDeviceSlotBackend(model, params, num_slots=num_slots,
                                     max_len=max_len, gen=gen, **kw)
-        live = jnp.zeros((num_slots,), bool)
-        budget = jnp.full((num_slots,), gen.max_new_tokens, jnp.int32)
-        if b.paged:
-            args = [b._block_stack, b._pre, b._post, b._pool_kv,
-                    jnp.asarray(b.pool.table), b._tok, b._pos,
-                    b._key_data, b._views, b._regather]
-        else:
-            args = [b._block_stack, b._pre, b._post, b._caches, b._tok,
-                    b._pos, b._key_data]
-        if spec:
-            args.append(b._hist)
-        args += [live, budget, jnp.int32(resident_chunks)]
-        run = (b._resident_spec_jits[spec_tokens] if spec
-               else b._resident_jit)
+        run, args = b.decode_program()
         return run.lower(*args).compile().as_text()
 
     def ring(layout):
@@ -578,13 +566,15 @@ def slab(num_slots: int = 8, max_len: int = 640, decode_chunk: int = 4,
     Builds ``SingleDeviceSlotBackend`` over a GPT-2 of the given sizes
     (defaults: the ``gpt2xl-serve-closed8`` cell's) under
     ``jax.eval_shape``, so no weight is ever made, and compiles its
-    real ``_resident_fn`` and one ``_prefill_fn`` bucket for one chip
-    of ``topology``. Reports, per program: every slab- or layer-sized
-    ``copy``/``transpose`` inside a ``while`` body and outside one, the
-    layouts the slab takes with their tiled bytes beside the data's own,
-    and ``memory_analysis()``. ``ok`` asks what PR 29 asked: no such
-    instruction anywhere in the resident program, and no form of the
-    slab over 1.05x its data. Exits non-zero otherwise."""
+    real decode program (``decode_program()``: ``_resident_fn``) and one
+    ``_prefill_fn`` bucket for one chip of ``topology``. Reports, per
+    program: every slab- or layer-sized ``copy``/``transpose`` inside a
+    ``while`` body and outside one, the layouts the slab takes with
+    their tiled bytes beside the data's own, ``memory_analysis()``, and
+    the count of each kind of HLO instruction. ``ok`` asks what PR 29
+    asked: no such ``copy``/``transpose`` anywhere in the resident
+    program, and no form of the slab over 1.05x its data. Exits non-zero
+    otherwise."""
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
@@ -612,8 +602,7 @@ def slab(num_slots: int = 8, max_len: int = 640, decode_chunk: int = 4,
             decode_chunk=decode_chunk, resident=True,
             resident_chunks=resident_chunks)
         made.append(b)
-        return (b._block_stack, b._pre, b._post, b._caches, b._tok,
-                b._pos, b._key_data)
+        return b._block_stack, b._pre, b._post, b._caches
 
     shapes = jax.eval_shape(build)        # the backend's arrays, unmade
     b = made[0]
@@ -624,14 +613,13 @@ def slab(num_slots: int = 8, max_len: int = 640, decode_chunk: int = 4,
     def on_chip(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
 
-    stack, pre, post, caches, tok, pos, key_data = jax.tree_util.tree_map(
+    stack, pre, post, caches = jax.tree_util.tree_map(
         lambda a: on_chip(a.shape, a.dtype), shapes)
-    S = num_slots
+    resident_fn, resident_args = b.decode_program()
+    resident_args = jax.tree_util.tree_map(
+        lambda a: on_chip(a.shape, a.dtype), resident_args)
     programs = {
-        "resident": lambda: b._resident_jit.lower(
-            stack, pre, post, caches, tok, pos, key_data,
-            on_chip((S,), jnp.bool_), on_chip((S,), jnp.int32),
-            on_chip((), jnp.int32)),
+        "resident": lambda: resident_fn.lower(*resident_args),
         f"prefill{prefill_bucket}": lambda: jax.jit(
             b._prefill_fn, donate_argnums=(3,)).lower(
                 stack, pre, post, caches,
@@ -648,11 +636,16 @@ def slab(num_slots: int = 8, max_len: int = 640, decode_chunk: int = 4,
     violations = []
     for name, lower in programs.items():
         compiled = lower().compile()
-        moves, forms = _slab_census(compiled.as_text(), elems, k.shape[0],
-                                    "bf16")
+        hlo = compiled.as_text()
+        moves, forms = _slab_census(hlo, elems, k.shape[0], "bf16")
         ma = compiled.memory_analysis()
         out["programs"][name] = {
             "slab_moves": moves, "slab_forms_bytes": forms,
+            # what a refactor compares with its parent's: equal counts
+            # are the same program
+            "hlo_ops": dict(sorted(collections.Counter(re.findall(
+                r"^\s*(?:ROOT\s+)?%?[\w.\-]+ = .*?\s([a-z][a-z\-]*)\(",
+                hlo, re.M)).items())),
             "memory": {f: getattr(ma, f) for f in (
                 "argument_size_in_bytes", "output_size_in_bytes",
                 "alias_size_in_bytes", "temp_size_in_bytes")}}

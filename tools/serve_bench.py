@@ -428,10 +428,11 @@ def resident_steady_state(model, params, slots, seed, *, resident,
 
 
 def resident_ab(model, params, slots, seed, *, rounds, reps=2):
-    """The PR 11 A/B: non-resident single-chunk ticks vs the resident
-    ``lax.while_loop`` at EQUAL live slots and equal token volume. The
-    resident loop's job is the host-overhead-per-token column; the
-    tokens/s column is the no-regression bar."""
+    """The PR 11 A/B: launches of one chunk (``resident=False``) vs
+    launches of ``RES_HORIZON`` chunks of the same program, at EQUAL
+    live slots and equal token volume. The longer horizon's job is the
+    host-overhead-per-token column; the tokens/s column is the
+    no-regression bar."""
     non = resident_steady_state(model, params, slots, seed,
                                 resident=False, rounds=rounds, reps=reps)
     res = resident_steady_state(model, params, slots, seed,
