@@ -213,6 +213,11 @@ def serve_phase(phase: Phase, model_cfg: LMConfig, *, n_requests: int = 6,
         eng.run_until_idle()
         return [eng.response(rid) for rid in ids]
 
+    def admissions():
+        return [reg.counter(f"serve.engine.{k}").value
+                for k in ("admitted", "first_tokens_overlapped")]
+
+    admitted0, overlapped0 = admissions()
     t0 = time.perf_counter()
     warm = serve_round()
     t1 = time.perf_counter()
@@ -226,6 +231,12 @@ def serve_phase(phase: Phase, model_cfg: LMConfig, *, n_requests: int = 6,
                 f"no new decode/resident/prefill traces after warm-up "
                 f"({traces_warm} -> {traces()})")
     phase.check(sum(traces_warm.values()) > 0, "the engine traced programs")
+    admitted, overlapped = admissions()
+    phase.check(admitted - admitted0 == overlapped - overlapped0
+                == 2 * n_requests,
+                f"every admission's first token was read after its tick's "
+                f"decode launch was queued ({overlapped - overlapped0} of "
+                f"{admitted - admitted0})")
 
     gen = Generator(model, gen_cfg)
     refs = [np.asarray(gen.generate(

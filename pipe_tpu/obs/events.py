@@ -92,10 +92,11 @@ SERVE_REAP = "serve.reap"
 SERVE_ADMIT = "serve.admit"
 SERVE_DECODE = "serve.decode"
 SERVE_RETIRE = "serve.retire"
-# ... and the slot backend's calls under them: a prefill with the blocking
-# read of its first token; the dispatch of a decode or resident program,
-# the host's wait for it (the chunk count, then the token buffer), and a
-# zero-length record of the counts known only afterwards
+# ... and the slot backend's calls under them: a prefill's dispatch; the
+# engine's wait for its first token, once the tick's decode launch is
+# queued; the dispatch of a decode or resident program, the host's wait
+# for it (the chunk count, then the token buffer), and a zero-length
+# record of the counts known only afterwards
 SERVE_PREFILL = "serve.prefill"
 SERVE_PREFILL_SYNC = "serve.prefill.sync"
 SERVE_DECODE_LAUNCH = "serve.decode.launch"

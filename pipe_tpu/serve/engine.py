@@ -12,12 +12,15 @@ position, PRNG key) triple. A host **tick** is:
    slots whose deadline passed or that were cancelled;
 2. admit waiting requests into free slots — one bucketed prefill program
    per prompt-length bucket (:class:`~.buckets.BucketSpec`) writes the
-   slot's cache rows and samples the first token (TTFT is measured
-   here);
+   slot's cache rows, samples the first token and arms the slot with
+   it on the device; the host dispatches and reads nothing back;
 3. launch the **one** decode program for all S slots — finished/empty
    slots write rows the next prefill overwrites, the same
-   sacrificial-write trick as the pipelined generators — and retire
-   slots on EOS / per-request ``max_new_tokens``.
+   sacrificial-write trick as the pipelined generators — then, with
+   the launch in the device's queue, read the admissions' first tokens
+   (TTFT is measured here: the read waits for the prefill program, the
+   device works on), wait for the launch, and retire slots on EOS /
+   per-request ``max_new_tokens``.
 
 The decode program of :class:`SingleDeviceSlotBackend` is one
 ``lax.while_loop`` with two seams: the cache store (slab or paged pool)
@@ -85,11 +88,14 @@ class _Slot:
 
     __slots__ = ("req", "tokens", "ttft", "admitted_tick")
 
-    def __init__(self, req: Request, first_token: int, ttft: float,
-                 admitted_tick: int = 0):
+    def __init__(self, req: Request, first_token, admitted_tick: int = 0):
         self.req = req
+        # the first token holds its place from admission on, as the
+        # backend's prefill returned it (an int, or what int() waits on
+        # the device for); ServeEngine._land_first_tokens puts the int
+        # there and stamps ttft, within the tick of the admission
         self.tokens: List[int] = [first_token]
-        self.ttft = ttft
+        self.ttft: Optional[float] = None
         self.admitted_tick = admitted_tick
 
 
@@ -372,7 +378,8 @@ class SingleDeviceSlotBackend:
             else:
                 self._kv_store = None
             self._chunk_jit = jax.jit(self._chunk_fn, donate_argnums=(2,))
-            self._sample_jit = jax.jit(self._sample_fn)
+            self._sample_jit = jax.jit(self._sample_fn,
+                                       donate_argnums=(2,))
             self._fork_jit = jax.jit(self._fork_fn, donate_argnums=(0,))
             # per-slot gathered views carried across launches, and the
             # device-side flag that has the decode program gather them
@@ -403,7 +410,7 @@ class SingleDeviceSlotBackend:
         self._key_data = jnp.broadcast_to(kd0, (num_slots,) + kd0.shape)
 
         # device-side token history, the n-gram draft source
-        # (:meth:`_hist_write`; accepted tokens land in-program).
+        # (:meth:`_arm` seeds a row; accepted tokens land in-program).
         # spec_tokens rows of slack absorb the masked write past the
         # last position. None where no round drafts.
         self._hist = None if spec is None else jnp.full(
@@ -505,16 +512,34 @@ class SingleDeviceSlotBackend:
             (block_stack, jnp.arange(n, dtype=jnp.int32)))
         return h, caches
 
-    def _prefill_fn(self, block_stack, pre, post, caches, prompt,
-                    true_len, slot, key):
+    def _arm(self, state, slot, true_len, tok0, key, row):
+        """Traced, the last lines of every admission's program (the
+        slab's prefill, the pool's first-token epilogue): the slot's
+        entries of the backend's ``(tok, pos, key_data, hist)`` written
+        from the program's own first token, the prompt's length and the
+        next key of the chain — what the decode program starts the slot
+        from, with nothing read back to the host in between. ``row`` is
+        the slot's draft history as the host knows it (the prompt, pad
+        beyond); the first token goes in at ``true_len``."""
+        tok, pos, key_data, hist = state
+        put = jax.lax.dynamic_update_index_in_dim
+        if hist is not None:
+            hist = put(hist, put(row, tok0, true_len, 0), slot, 0)
+        return (put(tok, tok0, slot, 0),
+                put(pos, jnp.asarray(true_len, pos.dtype), slot, 0),
+                put(key_data, jax.random.key_data(key), slot, 0), hist)
+
+    def _prefill_fn(self, block_stack, pre, post, caches, state, prompt,
+                    true_len, slot, seed, row):
         """One bucket-length-B prefill: runs the padded prompt through
         every layer against a fresh full-length temp cache (the batch
         form's ``[L, 1, T, H, D]``: a prompt's rows are a matrix, and
         the batch form reads and writes them as one), then writes the
         ENTIRE slot slab (previous occupant's rows are gone, not merely
         masked), folding its heads once on the way into the carried
-        layout ``[L, S, T, C]``, and samples the first token with the
-        exact batch-1 Generator key chain."""
+        layout ``[L, S, T, C]``, samples the first token with the
+        exact batch-1 Generator key chain from ``jax.random.key(seed)``,
+        made here, and arms the slot with it (:meth:`_arm`)."""
         m, gen = self.model, self.gen
         cd = m.cfg.compute_dtype
         get_registry().counter("serve.engine.prefill_traces").inc()
@@ -538,10 +563,18 @@ class SingleDeviceSlotBackend:
                 caches, temp)
         h_last = jax.lax.dynamic_slice(
             h, (0, true_len - 1, 0), (1, 1, h.shape[-1]))
-        key, sub = jax.random.split(key)
-        tok0 = sample_logits(head_logits(m, post, h_last)[:, 0, :],
-                             sub, gen)[0]
-        return caches, tok0, key
+        tok0, key = self._first_token(post, h_last, seed)
+        return caches, self._arm(state, slot, true_len, tok0, key,
+                                 row), tok0
+
+    def _first_token(self, post, h_last, seed):
+        """Traced: the exact batch-1 Generator key chain (key, split,
+        sample) from the request's seed, a host integer until here."""
+        key, sub = jax.random.split(jax.random.key(seed))
+        tok0 = sample_logits(
+            head_logits(self.model, post, h_last)[:, 0, :], sub,
+            self.gen)[0]
+        return tok0, key
 
     # -- paged device programs ---------------------------------------------
 
@@ -582,16 +615,13 @@ class SingleDeviceSlotBackend:
         h_last = jax.lax.dynamic_slice(h, (0, idx, 0), (1, 1, h.shape[-1]))
         return pool_kv, h_last
 
-    def _sample_fn(self, post, h_last, key):
-        """First-token epilogue: the exact batch-1 Generator key chain
-        (split then sample) the slab prefill runs in-program — kept as
+    def _sample_fn(self, post, h_last, state, true_len, slot, seed, row):
+        """First-token epilogue of a paged admission, what the slab
+        prefill ends in (:meth:`_first_token`, :meth:`_arm`) — kept as
         its own fixed-shape program so the chunk loop stays
         length-agnostic."""
-        key, sub = jax.random.split(key)
-        tok0 = sample_logits(
-            head_logits(self.model, post, h_last)[:, 0, :], sub,
-            self.gen)[0]
-        return tok0, key
+        tok0, key = self._first_token(post, h_last, seed)
+        return self._arm(state, slot, true_len, tok0, key, row), tok0
 
     def _fork_fn(self, pool_kv, src, dst):
         """Copy-on-write block copy (src/dst traced — one program for
@@ -711,9 +741,9 @@ class SingleDeviceSlotBackend:
                 slots, _, _, _, k = state
                 return (k < r_max) & ~jnp.any(live & slots[5])
 
-            # dead slots, spent budgets, and slots whose first token
-            # already hit eos (the engine retires those before decode —
-            # this covers direct backend callers) start done
+            # dead slots, spent budgets, and slots whose first token is
+            # eos (known only here: the host reads an admission's first
+            # token after this launch is in the queue) start done
             done = ~live | (budget <= 0)
             if eos is not None:
                 done = done | (tok == jnp.int32(eos))
@@ -890,14 +920,24 @@ class SingleDeviceSlotBackend:
     # -- backend API -------------------------------------------------------
 
     def prefill(self, slot: int, prompt: Sequence[int], seed: int,
-                max_new_tokens: Optional[int] = None) -> int:
-        """Fill slot ``slot``'s cache rows from ``prompt`` and return the
-        first sampled token. Blocking — the returned int IS the TTFT
-        moment. Slab mode: one program per prompt-length bucket. Paged
+                max_new_tokens: Optional[int] = None):
+        """Fill slot ``slot``'s cache rows from ``prompt`` and arm the
+        slot, all on the device: the programs are dispatched and nothing
+        is read back. Returns the first sampled token as the 0-d device
+        array the program gave; ``int()`` of it waits for the program,
+        and that moment is the request's first token on the host (the
+        engine reads it once the tick's decode launch is in the queue).
+        The slot's (token, position, key) and draft history are written
+        by the program itself (:meth:`_arm`), so a ``decode`` may follow
+        at once. Slab mode: one program per prompt-length bucket. Paged
         mode: ONE chunked program regardless of length;
         ``max_new_tokens`` sizes the block reservation (defaults to the
         engine cap — full-demand reservation means no mid-decode OOM)."""
         reg = get_registry()
+        if self.spec_tokens is not None:
+            # adaptive-K starts each request optimistic: full draft depth
+            # until its own acceptance says otherwise
+            self._spec_ewma[slot] = float(self.spec_tokens)
         if self.paged:
             return self._prefill_paged(
                 slot, prompt, seed,
@@ -909,9 +949,8 @@ class SingleDeviceSlotBackend:
             padded, p = list(prompt), len(prompt)
         B = len(padded)
         with ev.span(ev.SERVE_PREFILL, slot=slot, prompt_len=p, bucket=B):
-            tok0 = self._prefill_slab(reg, slot, padded, p, seed)
+            tok0 = self._prefill_slab(reg, slot, padded, prompt, seed)
         self._count_prompt(reg, p, B)
-        self._hist_write(slot, prompt, tok0)
         return tok0
 
     @staticmethod
@@ -921,16 +960,13 @@ class SingleDeviceSlotBackend:
         reg.counter("serve.engine.prompt_tokens").inc(prompt_len)
         reg.counter("serve.engine.padded_prompt_tokens").inc(padded_len)
 
-    def _prefill_slab(self, reg, slot: int, padded, p: int,
-                      seed: int) -> int:
-        """The bucket's program on the padded prompt, then the blocking
-        read of the first token."""
+    def _prefill_slab(self, reg, slot: int, padded, prompt, seed: int):
+        """Dispatch the bucket's program on the padded prompt."""
         B = len(padded)
         run = self._prefill_programs.get(B)
         if run is None:
             reg.counter("serve.engine.prefill_program_misses").inc()
-            run = jax.jit(self._prefill_fn, donate_argnums=(3,))
-            self._prefill_programs[B] = run
+            run = self._prefill_programs[B] = self._prefill_jit()
             reg.gauge("serve.engine.prefill_programs").set(
                 len(self._prefill_programs))
             if self.buckets is None and \
@@ -944,48 +980,64 @@ class SingleDeviceSlotBackend:
                     f"the program cache.", RuntimeWarning, stacklevel=4)
         else:
             reg.counter("serve.engine.prefill_program_hits").inc()
-        arr = jnp.asarray(padded, jnp.int32)[None, :]
-        key = jax.random.key(seed)
-        caches, tok0, key = run(self._block_stack, self._pre, self._post,
-                                self._caches, arr, jnp.int32(p),
-                                jnp.int32(slot), key)
-        self._caches = caches
-        return self._arm_slot(slot, tok0, p, key)
-
-    def _arm_slot(self, slot: int, tok0, pos: int, key) -> int:
-        """The blocking read of the first token, then the slot's
-        (token, position, key) triple the decode program starts from."""
-        with ev.span(ev.SERVE_PREFILL_SYNC, slot=slot):
-            tok0 = int(tok0)
-        self._tok = self._tok.at[slot].set(tok0)
-        self._pos = self._pos.at[slot].set(pos)
-        self._key_data = self._key_data.at[slot].set(
-            jax.random.key_data(key))
+        self._caches, state, tok0 = run(
+            *self._prefill_args(slot, padded, prompt, seed))
+        self._put_slot_state(state)
         return tok0
 
-    def _hist_write(self, slot: int, prompt: Sequence[int],
-                    tok0: int) -> None:
-        """Seed the speculative draft history for a freshly prefilled
-        slot: hist[s, p] = the token embedded at position p (prompt
-        rows + the first sampled token); pad beyond."""
-        if self.spec_tokens is None:
-            return
-        row = np.full((self._hist.shape[1],), self.gen.pad_token_id,
-                      np.int32)
-        row[:len(prompt)] = np.asarray(list(prompt), np.int32)
-        row[len(prompt)] = tok0
-        self._hist = self._hist.at[slot].set(jnp.asarray(row))
-        # adaptive-K starts each request optimistic: full draft depth
-        # until its own acceptance says otherwise
-        self._spec_ewma[slot] = float(self.spec_tokens)
+    def _prefill_jit(self):
+        """A slab prefill program. Donated: the slab and the slots'
+        state, both written in place."""
+        return jax.jit(self._prefill_fn, donate_argnums=(3, 4))
+
+    def _prefill_args(self, slot: int, padded, prompt, seed: int):
+        """The prefill program's arguments at this backend's state.
+        Prompt, length, slot and seed go in as host values, inside the
+        one call: no device program runs for any of them on its own."""
+        return (self._block_stack, self._pre, self._post, self._caches,
+                self._slot_state(), np.asarray(padded, np.int32)[None, :],
+                *self._arm_args(slot, prompt, seed))
+
+    def prefill_program(self, bucket: int):
+        """For tests and audits: a slab prefill program as ``(jitted
+        function, its arguments at this backend's sizes)`` for a prompt
+        that fills ``bucket``, to ``.lower(*args)``."""
+        prompt = [self.gen.pad_token_id] * bucket
+        return self._prefill_jit(), self._prefill_args(0, prompt, prompt, 0)
+
+    def _slot_state(self):
+        """What :meth:`_arm` writes a slot's entries of, donated to the
+        admission's program and taken back from it."""
+        return self._tok, self._pos, self._key_data, self._hist
+
+    def _put_slot_state(self, state) -> None:
+        self._tok, self._pos, self._key_data, self._hist = state
+
+    def _arm_args(self, slot: int, prompt: Sequence[int], seed: int):
+        """The host's part of arming ``slot``, as the trailing arguments
+        of the admission's program: ``(true_len, slot, seed, row)``,
+        plain host values. The seed is the scalar ``jax.random.key``
+        itself makes of a Python int (an int64, which wraps to 32 bits
+        without x64), so the key made in the program is its key bit for
+        bit. ``row`` is the slot's draft history before its first token
+        (hist[s, p] = the token embedded at position p: the prompt, pad
+        beyond), None where nothing drafts."""
+        row = None
+        if self.spec_tokens is not None:
+            row = np.full((self._hist.shape[1],), self.gen.pad_token_id,
+                          np.int32)
+            row[:len(prompt)] = prompt
+        return (np.int32(len(prompt)), np.int32(slot),
+                np.int64(seed).astype(jax.dtypes.canonicalize_dtype(
+                    np.int64)), row)
 
     def _prefill_paged(self, slot: int, prompt: Sequence[int], seed: int,
-                       max_new_tokens: int) -> int:
+                       max_new_tokens: int):
         """Admit into the pool (reserving full demand), run the COW
         forks, stream the prompt's recompute tail through the one chunk
-        program, sample the first token with the Generator key chain. A
-        failure mid-stream releases the reservation and unpublishes any
-        half-written cache entries."""
+        program, sample the first token with the Generator key chain and
+        arm the slot. A failure mid-stream releases the reservation and
+        unpublishes any half-written cache entries."""
         plen = len(prompt)
         with ev.span(ev.SERVE_PREFILL, slot=slot, prompt_len=plen) as sp:
             adm = self.pool.admit(slot, prompt, max_new_tokens,
@@ -997,12 +1049,11 @@ class SingleDeviceSlotBackend:
                     # the regather armed below refreshes the decode views —
                     # no extra host decision per tick
                     self._pool_kv = self._restore_jit(
-                        self._pool_kv, jnp.int32(dst),
-                        {k: jnp.asarray(v) for k, v in payload.items()})
+                        self._pool_kv, np.int32(dst), payload)
                 for src, dst in adm.cow_forks:
                     self._pool_kv = self._fork_jit(
-                        self._pool_kv, jnp.int32(src), jnp.int32(dst))
-                trow = jnp.asarray(adm.table)
+                        self._pool_kv, np.int32(src), np.int32(dst))
+                trow = np.asarray(adm.table)
                 C = self.prefill_chunk
                 pad = self.gen.pad_token_id
                 t = adm.resume_from
@@ -1013,24 +1064,24 @@ class SingleDeviceSlotBackend:
                 while t < plen:
                     toks = list(prompt[t:t + C])
                     toks += [pad] * (C - len(toks))
-                    arr = jnp.asarray(toks, jnp.int32)[None, :]
                     self._pool_kv, h_last = self._chunk_jit(
                         self._block_stack, self._pre, self._pool_kv, trow,
-                        arr, jnp.int32(t), jnp.int32(plen))
+                        np.asarray(toks, np.int32)[None, :], np.int32(t),
+                        np.int32(plen))
                     t += C
-                tok0, key = self._sample_jit(
-                    self._post, h_last, jax.random.key(seed))
+                state, tok0 = self._sample_jit(
+                    self._post, h_last, self._slot_state(),
+                    *self._arm_args(slot, prompt, seed))
             except Exception:
                 self.pool.release(slot, failed=True)
                 raise
-            tok0 = self._arm_slot(slot, tok0, plen, key)
+            self._put_slot_state(state)
             # this slot's table moved: arm the device-side regather flag
             # — the ONE host gather decision per admission (counted here;
             # steady-state ticks make zero)
             self._regather = jnp.asarray(True)
             get_registry().counter("serve.kv.regather_host_decisions").inc()
         self._count_prompt(get_registry(), plen, rows)
-        self._hist_write(slot, prompt, tok0)
         return tok0
 
     def _decode_args(self, live, budget, r_max):
@@ -1052,7 +1103,8 @@ class SingleDeviceSlotBackend:
 
     def decode(self, live: np.ndarray,
                budgets: Optional[np.ndarray] = None,
-               r_max: Optional[int] = None):
+               r_max: Optional[int] = None,
+               launched: Optional[Callable[[], None]] = None):
         """One launch of the decode program for all slots: up to
         ``r_max`` rounds (default and at most ``resident_chunks``) on
         device under the per-slot ``budgets`` (remaining
@@ -1060,24 +1112,31 @@ class SingleDeviceSlotBackend:
         readout. Returns ``(tokens [S, k*width], valid [S, k*width])``
         — dead slots are masked out by ``valid``; what they write is
         rewritten at the next prefill — or, paged, lands in the
-        sacrificial block. Without ``budgets`` the same program runs one
-        round with no budget limit."""
+        sacrificial block. A launch in which a live slot starts done
+        (its first token eos, its budget spent) runs no round and
+        returns zero columns. Without ``budgets`` the same program runs
+        one round with no budget limit. ``launched`` is called once the
+        launch is in the device's queue and before the host waits for
+        it: the caller's moment for what may wait on earlier programs
+        (the engine reads its admissions' first tokens there)."""
         reg = get_registry()
         R = self.resident_chunks
         if budgets is None:
             budgets = np.full((self.num_slots,), np.iinfo(np.int32).max)
             r_max = 1
         rm = R if r_max is None else max(1, min(int(r_max), R))
-        live_d = jnp.asarray(np.asarray(live, bool))
-        budget = jnp.asarray(np.asarray(budgets, np.int32))
+        live_d = np.asarray(live, bool)
+        budget = np.asarray(budgets, np.int32)
         if self.spec_tokens is not None:
             self.decode_width = self._pick_spec_k(live)
         with ev.span(ev.SERVE_DECODE_LAUNCH, chunks=rm):
             kv, tok, pos, kd, hist, buf, counts, k = \
                 self._resident_jits[self.decode_width](
-                    *self._decode_args(live_d, budget, jnp.int32(rm)))
+                    *self._decode_args(live_d, budget, np.int32(rm)))
             self._store.put(kv)
-        self._tok, self._pos, self._key_data, self._hist = tok, pos, kd, hist
+        self._put_slot_state((tok, pos, kd, hist))
+        if launched is not None:
+            launched()
         with ev.span(ev.SERVE_DECODE_SYNC):
             k = int(k)                         # THE host sync
             buf = np.asarray(buf)              # then the two fetches
@@ -1649,6 +1708,7 @@ class ServeEngine:
         # pop order — a small request behind a parked giant no longer
         # starves (serve.engine.admission_skipped counts the bypasses).
         device_sec = 0.0                    # prefill + decode launches
+        pending: List[int] = []             # slots whose first token is due
         head_blocked_counted = False
         while self._free and not self._draining:
             can = getattr(self.backend, "can_admit", None)
@@ -1702,48 +1762,48 @@ class ServeEngine:
                         self._fail_queued(req, e, self.clock()))
                     continue
                 device_sec += self.clock() - t_pre
-                t_first = self.clock()
-                st = _Slot(req, tok0, ttft=t_first - req.submitted_at,
-                           admitted_tick=tick_idx)
-                self._slots[slot] = st
-                reg.counter("serve.engine.admitted").inc()
-                reg.histogram("serve.engine.ttft_sec").observe(st.ttft)
-                self.events.event(REQUEST, request=req.id,
-                                  stage="prefill", trace=req.trace_id,
-                                  slot=slot, ttft=st.ttft,
-                                  attempts=req.attempts,
-                                  prompt_len=len(req.prompt))
-                if eos is not None and tok0 == eos:
-                    finished.append(
-                        self._retire(slot, "ok", "eos", t_first))
-                elif req.max_new_tokens == 1:
-                    finished.append(
-                        self._retire(slot, "ok", "length", t_first))
+                self._slots[slot] = _Slot(req, tok0, admitted_tick=tick_idx)
+                if isinstance(tok0, (int, np.integer)):
+                    # a token that has already arrived
+                    self._land_first_tokens([slot], finished)
+                else:
+                    pending.append(slot)
 
         # 3) decode — one launch for every slot, under the slots' token
         # budgets and the deadline horizon. A failure is
         # NOT attributable (all slots share the program): skip the tick
         # with slot state intact, and only a run of consecutive failures
         # retires the live set.
-        live = np.array([s is not None for s in self._slots])
+        budgets = np.array(
+            [0 if s is None else
+             max(s.req.max_new_tokens - len(s.tokens), 0)
+             for s in self._slots], np.int32)
+        # a slot admitted for one token has it already, on its way
+        live = budgets > 0
         decode_sec = 0.0
         if live.any():
             n_live = int(live.sum())
             # rows the launch's first step attends over: each live slot's
             # prompt and the tokens sampled so far
             rows = sum(len(s.req.prompt) + len(s.tokens)
-                       for s in self._slots if s is not None)
+                       for s, on in zip(self._slots, live) if on)
+            # the admissions' first tokens are read once the launch is in
+            # the device's queue: they wait for the prefill programs, which
+            # end before it does, with the device busy meanwhile
+            kw = {"launched": functools.partial(
+                self._land_first_tokens, pending, finished,
+                overlapped=True)} if pending else {}
             t0 = self.clock()
             try:
                 reg.counter("serve.engine.host_syncs").inc()
                 with self.events.span(ev.SERVE_DECODE, live=n_live):
-                    budgets = np.array(
-                        [0 if s is None else
-                         max(s.req.max_new_tokens - len(s.tokens), 0)
-                         for s in self._slots], np.int32)
                     r_max = self._resident_horizon(now)
-                    toks, valid = self.backend.decode(
-                        live, budgets=budgets, r_max=r_max)
+                    try:
+                        toks, valid = self.backend.decode(
+                            live, budgets=budgets, r_max=r_max, **kw)
+                    finally:
+                        # a backend that told nobody, or raised before it
+                        self._land_first_tokens(pending, finished)
             except Exception as e:           # noqa: BLE001 — containment
                 self._on_decode_error(reg, e, tick_idx, finished)
             else:
@@ -1794,6 +1854,8 @@ class ServeEngine:
                     reg.counter("serve.engine.tokens").inc(emitted)
                     reg.histogram("serve.engine.token_sec").observe(
                         (t1 - t0) / emitted)
+        else:
+            self._land_first_tokens(pending, finished)   # no launch
 
         reg.gauge("serve.engine.queue_depth").set(self.queue.depth)
         reg.gauge("serve.engine.slot_occupancy").set(
@@ -1815,6 +1877,51 @@ class ServeEngine:
                               tick=tick_idx, duration_s=dur,
                               budget_s=wd.tick_budget_s)
         return finished
+
+    def _land_first_tokens(self, pending: List[int],
+                           finished: List[Response],
+                           overlapped: bool = False) -> None:
+        """The first token of every slot in ``pending`` (emptied) arrives
+        on the host: ``int()`` of what the backend's prefill returned,
+        which for a device value waits until that admission's program is
+        done. This is the TTFT moment: ``ttft`` is stamped, the request's
+        prefill record written, and a slot whose first token is eos or
+        its whole budget retires. A read that raises fails that one
+        request, as a raising prefill does. ``overlapped``: the tick's
+        decode launch is already in the device's queue (the backend's
+        ``launched`` call), so the device works through the wait."""
+        reg = get_registry()
+        eos = self.backend.gen.eos_token_id
+        while pending:
+            slot = pending.pop(0)
+            st = self._slots[slot]
+            req = st.req
+            try:
+                with self.events.span(ev.SERVE_PREFILL_SYNC, slot=slot):
+                    tok0 = st.tokens[0] = int(st.tokens[0])
+            except Exception as e:           # noqa: BLE001 — containment
+                self._slots[slot] = None
+                self._free.append(slot)
+                rel = getattr(self.backend, "release", None)
+                if rel is not None:
+                    rel(slot)
+                finished.append(self._fail_queued(req, e, self.clock()))
+                continue
+            t_first = self.clock()
+            st.ttft = t_first - req.submitted_at
+            reg.counter("serve.engine.admitted").inc()
+            if overlapped:
+                reg.counter("serve.engine.first_tokens_overlapped").inc()
+            reg.histogram("serve.engine.ttft_sec").observe(st.ttft)
+            self.events.event(REQUEST, request=req.id,
+                              stage="prefill", trace=req.trace_id,
+                              slot=slot, ttft=st.ttft,
+                              attempts=req.attempts,
+                              prompt_len=len(req.prompt))
+            if eos is not None and tok0 == eos:
+                finished.append(self._retire(slot, "ok", "eos", t_first))
+            elif req.max_new_tokens == 1:
+                finished.append(self._retire(slot, "ok", "length", t_first))
 
     def _resident_horizon(self, now: float) -> int:
         """How many chunks the device may run before host attention

@@ -112,7 +112,8 @@ def test_serve_spans_nest_as_the_tick_runs(served):
     assert parents[ev.SERVE_REAP] == {ev.SERVE_TICK}
     assert parents[ev.SERVE_ADMIT] == {ev.SERVE_TICK}
     assert parents[ev.SERVE_PREFILL] == {ev.SERVE_ADMIT}
-    assert parents[ev.SERVE_PREFILL_SYNC] == {ev.SERVE_PREFILL}
+    # the first token is waited for once the launch is in the queue
+    assert parents[ev.SERVE_PREFILL_SYNC] == {ev.SERVE_DECODE}
     assert parents[ev.SERVE_DECODE] == {ev.SERVE_TICK}
     assert parents[ev.SERVE_DECODE_LAUNCH] == {ev.SERVE_DECODE}
     assert parents[ev.SERVE_DECODE_SYNC] == {ev.SERVE_DECODE}
@@ -216,13 +217,13 @@ def test_event_log_spans_carry_the_same_names_and_parents(tmp_path):
     logged = sorted((r["kind"], kinds.get(r["parent"])) for r in records)
     spans = [s for s in _program_spans(tmp_path / "capture")
              # the backend holds no log: its spans reach the profiler alone
-             if s[0] not in (ev.SERVE_PREFILL, ev.SERVE_PREFILL_SYNC,
-                             ev.SERVE_DECODE_LAUNCH, ev.SERVE_DECODE_SYNC)]
-    holds = {ev.SERVE_PREFILL: ev.SERVE_ADMIT}
-    captured = sorted(
-        (n, holds.get(_parent(spans, i), _parent(spans, i)))
-        for i, (n, *_) in enumerate(spans))
+             if s[0] not in (ev.SERVE_PREFILL, ev.SERVE_DECODE_LAUNCH,
+                             ev.SERVE_DECODE_SYNC)]
+    captured = sorted((n, _parent(spans, i))
+                      for i, (n, *_) in enumerate(spans))
     assert logged == captured and len(logged) >= 8
+    # the wait for a first token is the engine's span, under the launch
+    assert (ev.SERVE_PREFILL_SYNC, ev.SERVE_DECODE) in logged
     done = [r for r in records if r["kind"] == ev.SERVE_DECODE_DONE]
     assert all(r["steps"] == 2 for r in done)       # not resident: a chunk
     # what is known only at a span's end reaches the log's record too

@@ -828,4 +828,16 @@ def test_the_cells_resident_program_moves_no_slab_on_a_described_v5e(
     assert res["slab_moves"] == {"in_loops": [], "outside_loops": []}
     assert set(res["slab_forms_bytes"].values()) == {out["slab_data_bytes"]}
     assert res["memory"]["temp_size_in_bytes"] < 2 * 2**30
+    # the prefill program arms its slot itself (PR 31): the slots' tok,
+    # pos and key_data go in and out in place beside the slab, and no
+    # slab-sized copy stands in for the donation
+    pre = out["programs"]["prefill512"]
+    assert [a["shape"] for a in pre["io"]["aliases"]] == [
+        "bf16[48,8,640,1664]", "bf16[48,8,640,1664]", "s32[8]", "s32[8]",
+        "u32[8,2]"]
+    assert pre["io"]["outputs"] == [
+        "bf16[48,8,640,1664]", "bf16[48,8,640,1664]", "s32[8]", "s32[8]",
+        "u32[8,2]", "s32[]"]
+    assert not [m for m in pre["slab_moves"]["outside_loops"]
+                + pre["slab_moves"]["in_loops"] if m["what"] == "slab"]
     assert out["ok"], out["violations"]

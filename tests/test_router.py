@@ -123,6 +123,26 @@ def test_least_loaded_placement_spreads_work():
         assert resp.status == "ok" and len(resp.tokens) == 4
 
 
+def test_stub_first_tokens_have_arrived_with_prefills_return():
+    """The stub's ``prefill`` returns an int: the engines serve it as a
+    first token that has already arrived (ttft stamped at admission,
+    nothing counted as read behind a launch), and pass its ``decode``
+    the two keywords it takes and no other."""
+    from pipe_tpu.obs.telemetry import get_registry
+    overlapped = get_registry().counter(
+        "serve.engine.first_tokens_overlapped")
+    before = overlapped.value
+    router, t = make_fleet(2, slots=2)
+    ids = [router.submit([1, 2, 3], max_new_tokens=n).id
+           for n in (4, 1, 3, 2)]
+    run(router, t)
+    for rid, n in zip(ids, (4, 1, 3, 2)):
+        resp = router.response(rid)
+        assert resp.status == "ok" and resp.tokens == [1] * n
+        assert resp.ttft is not None and resp.ttft <= resp.latency
+    assert overlapped.value == before
+
+
 def test_session_affinity_pins_then_remaps_off_unhealthy_home():
     router, t = make_fleet(3, placement="session")
     r1 = router.submit([1, 2], max_new_tokens=6, session="a")

@@ -555,6 +555,27 @@ def _slab_census(hlo: str, slab_elems: int, layers: int, dtype: str):
     return moves, forms
 
 
+def _io_census(hlo: str) -> dict:
+    """A compiled program's entry arguments and outputs by shape, and
+    which output is written over which argument (the module's
+    ``input_output_alias``: a donated argument the compiler took): what
+    a program that carries state in place must show, and a copy of the
+    state in its stead must not."""
+    entry = re.search(r"^ENTRY [^\n]*\{\n(.*?)^\}", hlo, re.M | re.S).group(1)
+    params = {int(n): shape for shape, n in re.findall(
+        r"= (\w+\[[\d,]*\])(?:\{[^}]*\})? parameter\((\d+)\)", entry)}
+    root = re.search(r"ROOT [^\n]* = \((.*?)\) tuple\(", entry)
+    outs = (re.findall(r"(\w+\[[\d,]*\])(?:\{[^}]*\})?", root.group(1))
+            if root else [])
+    head = hlo.split("\n", 1)[0]
+    aliases = [{"output": int(o), "argument": int(a), "shape": params[int(a)]}
+               for o, a in re.findall(
+                   r"\{(\d+)\}: \((\d+), \{\}", head)]
+    return {"arguments": dict(sorted(collections.Counter(
+                params.values()).items())),
+            "outputs": outs, "aliases": aliases}
+
+
 def slab(num_slots: int = 8, max_len: int = 640, decode_chunk: int = 4,
          resident_chunks: int = 8, n_layers: int = 48, d_model: int = 1600,
          nhead: int = 25, d_ff: int = 6400, vocab: int = 50257,
@@ -570,10 +591,14 @@ def slab(num_slots: int = 8, max_len: int = 640, decode_chunk: int = 4,
     ``_prefill_fn`` bucket for one chip of ``topology``. Reports, per
     program: every slab- or layer-sized ``copy``/``transpose`` inside a
     ``while`` body and outside one, the layouts the slab takes with
-    their tiled bytes beside the data's own, ``memory_analysis()``, and
-    the count of each kind of HLO instruction. ``ok`` asks what PR 29
-    asked: no such ``copy``/``transpose`` anywhere in the resident
-    program, and no form of the slab over 1.05x its data. Exits non-zero
+    their tiled bytes beside the data's own, ``memory_analysis()``, the
+    count of each kind of HLO instruction, and under ``io`` its entry
+    arguments and outputs by shape with the outputs written over an
+    argument. ``ok`` asks what PR 29 asked: no such
+    ``copy``/``transpose`` anywhere in the resident program, and no form
+    of the slab over 1.05x its data; and what PR 31 asked: the prefill
+    program, which arms its slot itself, writes the slab and the slots'
+    ``tok``, ``pos`` and ``key_data`` in place. Exits non-zero
     otherwise."""
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     os.environ.setdefault("JAX_PLATFORMS", "cpu")
@@ -602,9 +627,9 @@ def slab(num_slots: int = 8, max_len: int = 640, decode_chunk: int = 4,
             decode_chunk=decode_chunk, resident=True,
             resident_chunks=resident_chunks)
         made.append(b)
-        return b._block_stack, b._pre, b._post, b._caches
+        return b._caches
 
-    shapes = jax.eval_shape(build)        # the backend's arrays, unmade
+    caches = jax.eval_shape(build)        # the backend's arrays, unmade
     b = made[0]
     chip = SingleDeviceSharding(
         topologies.get_topology_desc(platform="tpu",
@@ -613,19 +638,13 @@ def slab(num_slots: int = 8, max_len: int = 640, decode_chunk: int = 4,
     def on_chip(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
 
-    stack, pre, post, caches = jax.tree_util.tree_map(
-        lambda a: on_chip(a.shape, a.dtype), shapes)
     resident_fn, resident_args = b.decode_program()
-    resident_args = jax.tree_util.tree_map(
-        lambda a: on_chip(a.shape, a.dtype), resident_args)
+    prefill_fn, prefill_args = b.prefill_program(prefill_bucket)
+    resident_args, prefill_args = jax.tree_util.tree_map(
+        lambda a: on_chip(a.shape, a.dtype), (resident_args, prefill_args))
     programs = {
         "resident": lambda: resident_fn.lower(*resident_args),
-        f"prefill{prefill_bucket}": lambda: jax.jit(
-            b._prefill_fn, donate_argnums=(3,)).lower(
-                stack, pre, post, caches,
-                on_chip((1, prefill_bucket), jnp.int32),
-                on_chip((), jnp.int32), on_chip((), jnp.int32),
-                jax.eval_shape(lambda: jax.random.key(0))),
+        f"prefill{prefill_bucket}": lambda: prefill_fn.lower(*prefill_args),
     }
     k = caches["k"]
     elems = math.prod(k.shape)
@@ -648,7 +667,17 @@ def slab(num_slots: int = 8, max_len: int = 640, decode_chunk: int = 4,
                 hlo, re.M)).items())),
             "memory": {f: getattr(ma, f) for f in (
                 "argument_size_in_bytes", "output_size_in_bytes",
-                "alias_size_in_bytes", "temp_size_in_bytes")}}
+                "alias_size_in_bytes", "temp_size_in_bytes")},
+            "io": _io_census(hlo)}
+        aliased = collections.Counter(
+            a["shape"] for a in out["programs"][name]["io"]["aliases"])
+        if name != "resident" and not (
+                aliased[f"s32[{num_slots}]"] >= 2
+                and aliased[f"u32[{num_slots},2]"] >= 1
+                and sum(aliased.values()) >= 5):
+            violations.append(
+                f"{name}: the slab and the slots' tok, pos and key_data "
+                f"are not all written in place: aliased {dict(aliased)}")
         fat = {f: n for f, n in forms.items() if n > 1.05 * data}
         if name == "resident" and (moves["in_loops"]
                                    or moves["outside_loops"] or fat):
