@@ -252,7 +252,12 @@ def build_argparser() -> argparse.ArgumentParser:
                         "replicas resume from shipped blocks")
     p.add_argument("--int8", action="store_true",
                    help="int8 weight-only quantized block weights")
-    p.add_argument("--family", choices=["lm", "gpt2"], default="lm")
+    p.add_argument("--family", choices=["lm", "gpt2", "laguna"],
+                   default="lm",
+                   help="laguna: Laguna-S-2.1's share for one chip (5 of "
+                        "48 layers, 128 of 256 experts, half the "
+                        "vocabulary; models/laguna.py): --stages 1, --kv "
+                        "slab, no --spec-tokens")
     p.add_argument("--tiny", action="store_true")
     p.add_argument("--cpu", type=int, default=0,
                    help="force N virtual CPU devices (testing without TPU)")
@@ -275,6 +280,9 @@ def main(argv=None) -> int:
     if args.family == "gpt2":
         from ..models.gpt2 import GPT2Config as _Cfg
         from ..models.gpt2 import PipelinedGPT2 as _Model
+    elif args.family == "laguna":
+        from ..models.laguna import LagunaConfig as _Cfg
+        from ..models.laguna import PipelinedLaguna as _Model
     else:
         from ..models.transformer_lm import LMConfig as _Cfg
         from ..models.transformer_lm import PipelinedLM as _Model

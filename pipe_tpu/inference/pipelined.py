@@ -43,6 +43,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
+from ..models.common import refuse_grouped
 from ..obs.telemetry import get_registry
 from ..parallel.mesh import STAGE_AXIS
 from .generate import (GenerationConfig, check_positions, head_logits,
@@ -70,6 +71,7 @@ class PipelinedGenerator:
             raise TypeError(
                 f"{type(model).__name__} has no embed_at; KV-cache "
                 "generation needs position-offset embedding")
+        refuse_grouped(model, "PipelinedGenerator (inference/pipelined.py)")
         self.mesh = mesh
         self.model = model
         self.gen_cfg = gen_cfg

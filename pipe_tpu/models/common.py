@@ -19,7 +19,7 @@ import jax.numpy as jnp
 from ..core.partition import StageCtx
 from ..obs.events import LOSS, scoped
 
-__all__ = ["per_row_ce", "PipelinedTransformer"]
+__all__ = ["per_row_ce", "PipelinedTransformer", "refuse_grouped"]
 
 
 @scoped(LOSS)
@@ -44,6 +44,17 @@ def per_row_ce(logits, targets, weights=None):
     if reduce_axes:
         return jnp.mean(ce, axis=reduce_axes)
     return ce
+
+
+def refuse_grouped(model, who: str) -> None:
+    """One sentence from a path that decodes ONE stacked block over one
+    kind of cache: a model whose layers come in groups of unlike blocks
+    (it has ``layer_groups``) does not run there."""
+    if getattr(model, "layer_groups", None) is not None:
+        raise NotImplementedError(
+            f"{type(model).__name__} has layers of unlike kinds over two "
+            f"kinds of cache, and {who} decodes one stacked block over "
+            f"one: serve it with SingleDeviceSlotBackend's slab cache")
 
 
 class PipelinedTransformer:

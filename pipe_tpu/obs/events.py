@@ -64,7 +64,8 @@ import jax
 
 __all__ = ["EventLog", "NullEventLog", "NULL_EVENT_LOG", "SPAN_KINDS",
            "STEP", "STAGE", "MICROBATCH", "COMM", "RECOMPUTE", "REQUEST",
-           "RECOVERY", "DEVICE_SCOPES", "REMAT_SCOPE", "device_scope",
+           "RECOVERY", "DEVICE_SCOPES", "MODEL_SCOPES", "REMAT_SCOPE",
+           "device_scope",
            "scoped", "stage_scope", "span", "SpanHandle"]
 
 STEP = "step"
@@ -119,6 +120,20 @@ LOSS = "loss"
 OPTIMIZER = "optimizer"
 KV_CACHE = "kv_cache"
 DEVICE_SCOPES = (EMBED, ATTENTION, FFN, HEAD, LOSS, OPTIMIZER, KV_CACHE)
+# Scopes a model opens INSIDE one of the above, for a mechanism only it has
+# (an expert layer's router, grouped product and shared expert inside
+# ``ffn``; the kind of attention layer inside ``attention``). A reader of
+# :data:`DEVICE_SCOPES` skips them, so ``ffn``, ``attention`` and
+# ``kv_cache`` go on adding up; a reader of these names looks for them
+# itself. The compiler's own grouped-product kernel keeps no path at all
+# (its ``op_name`` is ``ragged-dot-*``): a reader of ``moe_experts``
+# counts it in.
+MOE_ROUTER = "moe_router"
+MOE_EXPERTS = "moe_experts"
+MOE_SHARED = "moe_shared"
+ATTN_WINDOW = "attn_window"
+ATTN_FULL = "attn_full"
+MODEL_SCOPES = (MOE_ROUTER, MOE_EXPERTS, MOE_SHARED, ATTN_WINDOW, ATTN_FULL)
 # Not a layer but a mark that cuts across them: a forward that runs again
 # for its backward. ``jax.checkpoint`` writes this name itself; the
 # scheduled executor's manual re-forward opens a scope of the same name.
@@ -126,11 +141,12 @@ REMAT_SCOPE = "rematted_computation"
 
 
 def device_scope(name: str):
-    """``jax.named_scope`` for a name of :data:`DEVICE_SCOPES` (or
-    :data:`REMAT_SCOPE`): metadata on the ops traced inside, nothing at
-    run time."""
-    if name not in DEVICE_SCOPES and name != REMAT_SCOPE:
-        raise ValueError(f"{name!r} is not one of {DEVICE_SCOPES}")
+    """``jax.named_scope`` for a name of :data:`DEVICE_SCOPES` or
+    :data:`MODEL_SCOPES` (or :data:`REMAT_SCOPE`): metadata on the ops
+    traced inside, nothing at run time."""
+    if name not in DEVICE_SCOPES + MODEL_SCOPES and name != REMAT_SCOPE:
+        raise ValueError(
+            f"{name!r} is not one of {DEVICE_SCOPES + MODEL_SCOPES}")
     return jax.named_scope(name)
 
 

@@ -27,9 +27,10 @@ from ..obs.events import (ATTENTION, EMBED, FFN, HEAD, KV_CACHE,
 
 __all__ = [
     "Module", "Sequential", "Lambda", "Linear", "Embedding", "LayerNorm",
-    "Dropout", "MultiHeadAttention", "TransformerEncoderLayer",
-    "PreLNBlock", "PositionalEncoding", "Decoder", "spec",
-    "slab_width", "fold_heads", "unfold_heads",
+    "RMSNorm", "GatedMLP", "Dropout", "MultiHeadAttention",
+    "TransformerEncoderLayer", "PreLNBlock", "PositionalEncoding",
+    "Decoder", "spec", "slab_width", "fold_heads", "unfold_heads",
+    "rope_frequencies", "apply_rope", "blocked_causal_attention",
 ]
 
 
@@ -192,6 +193,58 @@ class LayerNorm(Module):
         return y * params["g"] + params["b"]
 
 
+class RMSNorm(Module):
+    """``x / rms(x) * g``, computed in float32 whatever ``x``'s type and
+    given back in it (no mean, no bias)."""
+
+    def __init__(self, eps: float = 1e-6, dtype=jnp.float32,
+                 name: str = "rmsnorm"):
+        self.eps = eps
+        self.dtype = dtype
+        self.name = name
+
+    def init(self, key, x):
+        return {"g": jnp.ones((jnp.shape(x)[-1],), self.dtype)}
+
+    def apply(self, params, x, ctx: StageCtx = StageCtx()):
+        xf = x.astype(jnp.float32)
+        y = xf * jax.lax.rsqrt(
+            jnp.mean(jnp.square(xf), axis=-1, keepdims=True) + self.eps)
+        return (y * params["g"].astype(jnp.float32)).astype(x.dtype)
+
+
+class GatedMLP(Module):
+    """Gated SiLU feed-forward without biases: ``(silu(x W_gate) * (x
+    W_up)) W_down``. The two first products come out in float32 and the
+    gate is applied there; the hidden row goes on in ``x``'s type."""
+
+    def __init__(self, d_ff: int, dtype=jnp.float32, name: str = "gated_mlp"):
+        self.d_ff = d_ff
+        self.dtype = dtype
+        self.name = name
+
+    def init(self, key, x):
+        d = jnp.shape(x)[-1]
+        ks = jax.random.split(key, 3)
+
+        def mat(k, shape):
+            bound = 1.0 / math.sqrt(shape[0])
+            return jax.random.uniform(k, shape, self.dtype, -bound, bound)
+
+        return {"w_gate": mat(ks[0], (d, self.d_ff)),
+                "w_up": mat(ks[1], (d, self.d_ff)),
+                "w_down": mat(ks[2], (self.d_ff, d))}
+
+    def apply(self, params, x, ctx: StageCtx = StageCtx()):
+        f32 = jnp.float32
+        a = jnp.einsum("...d,df->...f", x, params["w_gate"],
+                       preferred_element_type=f32)
+        b = jnp.einsum("...d,df->...f", x, params["w_up"],
+                       preferred_element_type=f32)
+        h = (jax.nn.silu(a) * b).astype(x.dtype)
+        return jnp.einsum("...f,fd->...d", h, params["w_down"])
+
+
 class Dropout(Module):
     """Inverted dropout driven by the explicit ctx key.
 
@@ -235,20 +288,141 @@ def dot_product_attention(q, k, v, *, causal: bool = False,
     return jnp.einsum("...hqk,...khd->...qhd", weights, v)
 
 
+def rope_frequencies(head_dim: int, *, theta: float, fraction: float = 1.0,
+                     yarn: Optional[dict] = None):
+    """``(inv_freq [rot / 2], scale)`` of rotary positions over the first
+    ``rot = head_dim * fraction`` dims of a head. ``yarn`` (``factor``,
+    ``original``, ``beta_fast``, ``beta_slow``, optional
+    ``attention_factor``) blends interpolated and extrapolated frequencies
+    as Hugging Face's ``_compute_yarn_parameters`` does, and ``scale``
+    (its ``attention_factor``, ``0.1 ln(factor) + 1`` unless given)
+    multiplies cos and sin."""
+    rot = int(head_dim * fraction)
+    pos_freqs = theta ** (np.arange(0, rot, 2, dtype=np.float64) / rot)
+    if yarn is None:
+        return (1.0 / pos_freqs).astype(np.float32), 1.0
+    factor, orig = yarn["factor"], yarn["original"]
+
+    def correction_dim(rotations):
+        return (rot * math.log(orig / (rotations * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    low = max(math.floor(correction_dim(yarn["beta_fast"])), 0)
+    high = min(math.ceil(correction_dim(yarn["beta_slow"])), rot - 1)
+    if low == high:
+        high += 0.001
+    ramp = np.clip((np.arange(rot // 2, dtype=np.float64) - low)
+                   / (high - low), 0.0, 1.0)
+    extra = 1.0 - ramp
+    inv = (1.0 / (factor * pos_freqs)) * (1.0 - extra) + extra / pos_freqs
+    scale = yarn.get("attention_factor")
+    if scale is None:
+        scale = 0.1 * math.log(factor) + 1.0
+    return inv.astype(np.float32), float(scale)
+
+
+def apply_rope(x, positions, inv_freq, scale: float = 1.0):
+    """Rotary positions on ``x [..., q, H, D]`` at ``positions [..., q]``
+    (leading axes broadcast), over the first ``2 * len(inv_freq)`` dims of
+    each head in rotate-half pairing (dim ``i`` with ``i + rot / 2``); the
+    rest of the head passes. Angles, cos and sin in float32."""
+    half = inv_freq.shape[0]
+    ang = (positions[..., None].astype(jnp.float32)
+           * jnp.asarray(inv_freq))[..., None, :]          # [..., q, 1, half]
+    cos, sin = jnp.cos(ang) * scale, jnp.sin(ang) * scale
+    xf = x.astype(jnp.float32)
+    x1, x2, rest = xf[..., :half], xf[..., half:2 * half], xf[..., 2 * half:]
+    return jnp.concatenate(
+        [x1 * cos - x2 * sin, x2 * cos + x1 * sin, rest],
+        axis=-1).astype(x.dtype)
+
+
+def blocked_causal_attention(q, k, v, *, window: Optional[int] = None,
+                             q_block: int = 256):
+    """Causal softmax attention over a whole sequence from position 0, a
+    block of ``q_block`` queries at a time, so that no score matrix of the
+    whole sequence exists: ``q [b, s, H, D]``, ``k``/``v`` ``[b, s, Hkv,
+    D]`` (``H`` a multiple of ``Hkv``: query head ``h`` reads KV head ``h
+    // (H / Hkv)``) -> ``[b, s, H, D]``. Position ``j`` is visible from
+    ``i`` iff ``j <= i`` and, with ``window``, ``j > i - window``; a
+    windowed block reads its own rows and the ``window`` before them, a
+    full one every row. Scores and softmax in float32. Plain
+    ``jax.numpy``: the flash kernel has neither groups nor a window."""
+    b, s, h, d = q.shape
+    hkv = k.shape[2]
+    qb = min(q_block, s)
+    if s % qb or h % hkv:
+        raise ValueError(f"{s} rows in blocks of {qb}, {h} heads over "
+                         f"{hkv}: neither may leave a remainder")
+    qg = q.reshape(b, s // qb, qb, hkv, h // hkv, d)
+    if window is None or window >= s:
+        span, front = s, 0
+    else:
+        span, front = window + qb, window
+        pad = ((0, 0), (front, 0), (0, 0), (0, 0))
+        k, v = jnp.pad(k, pad), jnp.pad(v, pad)
+
+    def one(i):
+        start = i * qb if front else 0       # row of k/v the span starts at
+        ks = jax.lax.dynamic_slice_in_dim(k, start, span, axis=1)
+        vs = jax.lax.dynamic_slice_in_dim(v, start, span, axis=1)
+        kpos = start - front + jnp.arange(span)
+        qpos = i * qb + jnp.arange(qb)
+        logits = jnp.einsum("bqkgd,btkd->bkgqt", qg[:, i], ks).astype(
+            jnp.float32) / math.sqrt(d)
+        seen = (kpos[None, :] <= qpos[:, None]) & (kpos[None, :] >= 0)
+        if window is not None:
+            seen = seen & (kpos[None, :] > qpos[:, None] - window)
+        w = jax.nn.softmax(jnp.where(seen, logits, jnp.float32(-1e30)),
+                           axis=-1).astype(q.dtype)
+        return jnp.einsum("bkgqt,btkd->bqkgd", w, vs)
+
+    o = jax.lax.map(one, jnp.arange(s // qb))     # [blocks, b, qb, ...]
+    return jnp.moveaxis(o, 0, 1).reshape(b, s, h, d)
+
+
 class MultiHeadAttention(Module):
     """Self-attention block (the math inside ``nn.TransformerEncoderLayer``,
-    reference ``main.py:148``), batch-first: x is [batch, seq, d_model]."""
+    reference ``main.py:148``), batch-first: x is [batch, seq, d_model].
+
+    The defaults are that block. The keyword arguments after ``impl`` are
+    what later architectures vary, each off by default: ``kv_heads``
+    (grouped queries: ``nhead / kv_heads`` query heads read one cached
+    head, and a cache row holds the ``kv_heads`` only), ``head_dim`` (a
+    head size other than ``d_model / nhead``), ``bias``, ``rope``
+    (:func:`rope_frequencies`' keywords: rotary positions on q and k,
+    applied at each row's own position, so ``decode`` needs no position
+    table), ``window`` (position ``j`` visible from ``i`` only if ``j > i
+    - window``; the slab form's cache is then a ring of ``window`` rows),
+    ``gate`` (a sigmoid of a bias-free linear map of the layer's input,
+    one scalar a query head, times that head's output before ``wo``)."""
 
     def __init__(self, d_model: int, nhead: int, dropout: float = 0.0,
                  causal: bool = True, dtype=jnp.float32, name: str = "mha",
-                 impl: str = "auto"):
-        if d_model % nhead:
+                 impl: str = "auto", *, kv_heads: Optional[int] = None,
+                 head_dim: Optional[int] = None, bias: bool = True,
+                 rope: Optional[dict] = None, window: Optional[int] = None,
+                 gate: bool = False):
+        if head_dim is None and d_model % nhead:
             raise ValueError("nhead must divide d_model")
         if impl not in ("auto", "xla", "flash"):
             raise ValueError(f"impl must be auto|xla|flash, got {impl!r}")
         self.d_model = d_model
         self.nhead = nhead
-        self.head_dim = d_model // nhead
+        self.head_dim = head_dim if head_dim is not None else d_model // nhead
+        self.kv_heads = kv_heads if kv_heads is not None else nhead
+        if nhead % self.kv_heads:
+            raise ValueError(f"kv_heads {self.kv_heads} must divide nhead "
+                             f"{nhead}")
+        self.group = nhead // self.kv_heads
+        self.bias = bias
+        self.rope = (None if rope is None
+                     else rope_frequencies(self.head_dim, **rope))
+        self.window = window
+        self.gate = gate
+        # what the flash kernel and the dropout path do not know
+        self.plain = (self.group == 1 and window is None and not gate
+                      and rope is None)
         self.dropout = dropout
         self.causal = causal
         self.dtype = dtype
@@ -256,32 +430,80 @@ class MultiHeadAttention(Module):
         self.impl = impl
 
     def init(self, key, x):
-        keys = jax.random.split(key, 4)
-        bound = 1.0 / math.sqrt(self.d_model)
+        keys = jax.random.split(key, 5)
+        d, hd = self.d_model, self.head_dim
+        wide, narrow = self.nhead * hd, self.kv_heads * hd
 
-        def mat(k):
-            return jax.random.uniform(k, (self.d_model, self.d_model),
-                                      self.dtype, -bound, bound)
+        def mat(k, shape):
+            bound = 1.0 / math.sqrt(shape[0])
+            return jax.random.uniform(k, shape, self.dtype, -bound, bound)
 
-        return {
-            "wq": mat(keys[0]), "wk": mat(keys[1]), "wv": mat(keys[2]),
-            "wo": mat(keys[3]),
-            "bq": jnp.zeros((self.d_model,), self.dtype),
-            "bk": jnp.zeros((self.d_model,), self.dtype),
-            "bv": jnp.zeros((self.d_model,), self.dtype),
-            "bo": jnp.zeros((self.d_model,), self.dtype),
+        params = {
+            "wq": mat(keys[0], (d, wide)), "wk": mat(keys[1], (d, narrow)),
+            "wv": mat(keys[2], (d, narrow)), "wo": mat(keys[3], (wide, d)),
         }
+        if self.bias:
+            params.update(
+                bq=jnp.zeros((wide,), self.dtype),
+                bk=jnp.zeros((narrow,), self.dtype),
+                bv=jnp.zeros((narrow,), self.dtype),
+                bo=jnp.zeros((d,), self.dtype))
+        if self.gate:
+            params["wg"] = mat(keys[4], (d, self.nhead))
+        return params
+
+    def _qkv(self, params, x, positions):
+        """The three projections of ``x [b, q, d]`` as heads ``[b, q, H |
+        Hkv, D]``, q and k turned to ``positions [1 | b, q]`` where the
+        attention has rotary positions."""
+        b, q, _ = x.shape
+
+        def proj(w, bias, heads):
+            y = jnp.einsum("bsd,de->bse", x, params[w])
+            if self.bias:
+                y = y + params[bias]
+            return y.reshape(b, q, heads, self.head_dim)
+
+        qh = proj("wq", "bq", self.nhead)
+        kh = proj("wk", "bk", self.kv_heads)
+        vh = proj("wv", "bv", self.kv_heads)
+        if self.rope is not None:
+            qh = apply_rope(qh, positions, *self.rope)
+            kh = apply_rope(kh, positions, *self.rope)
+        return qh, kh, vh
+
+    def _out(self, params, x, o):
+        """The heads' outputs ``o [b, q, H, D]``, gated by the layer's
+        input ``x`` where the attention has a gate, through ``wo``."""
+        b, q = o.shape[:2]
+        if self.gate:
+            g = jax.nn.sigmoid(jnp.einsum(
+                "bsd,dh->bsh", x, params["wg"],
+                preferred_element_type=jnp.float32))
+            o = (o * g[..., None]).astype(o.dtype)
+        out = jnp.einsum("bsd,de->bse",
+                         o.reshape(b, q, self.nhead * self.head_dim),
+                         params["wo"])
+        return out + params["bo"] if self.bias else out
+
+    def prefill(self, params, x):
+        """:meth:`apply` over a whole prompt from position 0 without a
+        cache, its attention a block of queries at a time
+        (:func:`blocked_causal_attention`): ``(out [b, s, d], {"k", "v"} [b,
+        s, Hkv, D])``, the rows as a cache keeps them."""
+        qh, kh, vh = self._qkv(params, x, jnp.arange(x.shape[1])[None])
+        o = blocked_causal_attention(qh, kh, vh, window=self.window)
+        return self._out(params, x, o), {"k": kh, "v": vh}
 
     def apply(self, params, x, ctx: StageCtx = StageCtx()):
         b, s, _ = x.shape
-        h, hd = self.nhead, self.head_dim
-
-        def proj(w, bias):
-            return (jnp.einsum("bsd,de->bse", x, w) + bias).reshape(b, s, h, hd)
-
-        q = proj(params["wq"], params["bq"])
-        k = proj(params["wk"], params["bk"])
-        v = proj(params["wv"], params["bv"])
+        if not self.plain:
+            if not self.causal or (self.dropout > 0.0 and ctx.train):
+                raise ValueError(
+                    "grouped, windowed, gated or rotary attention runs "
+                    "causal and without dropout")
+            return self.prefill(params, x)[0]
+        q, k, v = self._qkv(params, x, None)
         dk = ctx.fold(1).key if ctx.key is not None else None
         # The choice is static at trace time. Flash handles attention-weight
         # dropout only when compiled on TPU (the kernel's hardware PRNG
@@ -319,24 +541,44 @@ class MultiHeadAttention(Module):
             o = dot_product_attention(q, k, v, causal=self.causal,
                                       dropout_rate=self.dropout,
                                       dropout_key=dk, train=ctx.train)
-        o = o.reshape(b, s, self.d_model)
-        return jnp.einsum("bsd,de->bse", o, params["wo"]) + params["bo"]
+        return self._out(params, x, o)
 
     def make_cache(self, batch: int, max_len: int, dtype=None):
         """Zeroed KV cache for incremental decoding: ``{"k","v"}`` of
-        ``[batch, max_len, nhead, head_dim]``."""
-        shape = (batch, max_len, self.nhead, self.head_dim)
+        ``[batch, max_len, kv_heads, head_dim]``."""
+        shape = (batch, max_len, self.kv_heads, self.head_dim)
         dt = dtype if dtype is not None else self.dtype
         return {"k": jnp.zeros(shape, dt), "v": jnp.zeros(shape, dt)}
 
+    def slab_rows(self, max_len: int) -> int:
+        """Rows a slot holds in the slab form: ``max_len``, or a ring of
+        ``window`` where the attention sees no further back."""
+        return max_len if self.window is None else self.window
+
     def make_slab(self, layers: int, slots: int, max_len: int, dtype=None):
         """Zeroed stacked KV cache for the SLAB form of :meth:`decode`
-        (``layer=``): ``{"k","v"}`` of ``[layers, slots, max_len, C]``,
-        a cache row the ``nhead * head_dim`` values of :func:`fold_heads`."""
-        shape = (layers, slots, max_len,
-                 slab_width(self.nhead, self.head_dim))
+        (``layer=``): ``{"k","v"}`` of ``[layers, slots, rows, C]``,
+        ``rows`` :meth:`slab_rows`, a cache row the ``kv_heads * head_dim``
+        values of :func:`fold_heads`."""
+        shape = (layers, slots, self.slab_rows(max_len),
+                 slab_width(self.kv_heads, self.head_dim))
         dt = dtype if dtype is not None else self.dtype
         return {"k": jnp.zeros(shape, dt), "v": jnp.zeros(shape, dt)}
+
+    def seat(self, rows, true_len):
+        """A prompt's cache rows ``[..., B, Hkv, D]`` (positions 0 .. B -
+        1, the first ``true_len`` real) as a slot of the slab form holds
+        them: folded (:func:`fold_heads`), ``[..., B, C]``; for a window
+        the ring ``[..., window, C]``, row ``r`` the newest position
+        before ``true_len`` that is ``r`` mod ``window`` (a row no
+        position has reached holds what :meth:`decode` masks)."""
+        rows = fold_heads(rows)
+        if self.window is None:
+            return rows
+        last = true_len - 1
+        src = last - (last - jnp.arange(self.window)) % self.window
+        return jnp.take(rows, jnp.clip(src, 0, rows.shape[-2] - 1),
+                        axis=-2)
 
     def decode(self, params, x, cache, pos, tree=None, layer=None):
         """Incremental self-attention with a KV cache (inference only).
@@ -384,19 +626,33 @@ class MultiHeadAttention(Module):
         decode step is bound by the memory. Same math as the batch form
         vmapped over slots (a sum gains exact zeros), whose cache stays
         ``[b, T, H, D]``. Returns ``(out [S, q, d], slab)``.
+
+        With the constructor's later arguments (class docstring): a cache
+        row holds the ``kv_heads`` only (``[b, T, Hkv, D]``; folded, ``C =
+        Hkv * D``) and a group's query heads read one cached head's block;
+        q and k are turned to their own positions ``pos + j`` before the
+        row is written; a ``window`` masks rows ``window`` or more behind
+        a query, and in the slab form the cache is then a RING of
+        ``window`` rows (:meth:`make_slab`): the new row goes to ``pos %
+        window``, row ``r`` holds the newest position at or before ``pos``
+        that is ``r`` mod ``window``, rows no position has reached yet
+        are masked, and only ``q = 1`` is taken (:meth:`seat` puts a
+        prompt's rows there).
         """
         if not self.causal:
             raise ValueError("KV-cache decode requires causal attention")
         b, q, _ = x.shape
-        h, hd = self.nhead, self.head_dim
-
-        def proj(w, bias):
-            return (jnp.einsum("bsd,de->bse", x, w) + bias).reshape(
-                b, q, h, hd)
-
-        qh = proj(params["wq"], params["bq"])
-        kh = proj(params["wk"], params["bk"])
-        vh = proj(params["wv"], params["bv"])
+        hd, grp, win = self.head_dim, self.group, self.window
+        # a scalar pos is every row's; the slab form's is one per row
+        at = jnp.reshape(pos, (-1, 1))                     # [1|b, 1]
+        qh, kh, vh = self._qkv(params, x, at + jnp.arange(q)[None, :])
+        # the slab form of a windowed attention is a ring: position p
+        # lives in row p % window, and row r holds the newest position at
+        # or before pos that is r mod window
+        ring = layer is not None and win is not None
+        if ring and (q != 1 or tree is not None):
+            raise ValueError("a ring of window rows takes one new row a "
+                             "step (no speculative or chunked rows)")
         # the cache's update, and below its two reads (every cached row
         # of k for the scores, of v for the mix): what a decode step pays
         # for the cache, apart from the projections and the softmax
@@ -408,20 +664,28 @@ class MultiHeadAttention(Module):
                     cache[n], rows[n], (0, pos, 0, 0)) for n in rows}
                 ck, cv = cache["k"], cache["v"]
             else:
-                cache = {n: _write_slab_rows(cache[n], rows[n], layer, pos)
+                cache = {n: _write_slab_rows(
+                    cache[n], rows[n], layer, pos % win if ring else pos)
                          for n in rows}
                 ck, cv = (jax.lax.dynamic_index_in_dim(          # [S, T, C]
                     cache[n], layer, 0, keepdims=False) for n in ("k", "v"))
-            if layer is None:
+            if layer is not None:
+                logits = jnp.einsum("bqhc,bkc->bhqk",
+                                    _own_blocks(qh, ck.shape[-1], grp), ck)
+            elif grp == 1:
                 logits = jnp.einsum("bqhd,bkhd->bhqk", qh, ck)
             else:
-                logits = jnp.einsum("bqhc,bkc->bhqk",
-                                    _own_blocks(qh, ck.shape[-1]), ck)
+                logits = jnp.einsum(
+                    "bqkgd,btkd->bkgqt",
+                    qh.reshape(b, q, self.kv_heads, grp, hd), ck).reshape(
+                        b, self.nhead, q, ck.shape[1])
             logits = logits.astype(jnp.float32)
         logits = logits / math.sqrt(hd)
-        # a scalar pos is every row's; the slab form's is one per row
-        rel = (jnp.arange(logits.shape[-1])[None, :]
-               - jnp.reshape(pos, (-1, 1)))                # [1|b, K_cache]
+        # rel: a cached row's position less the first new row's
+        if ring:
+            rel = -((at - jnp.arange(logits.shape[-1])[None, :]) % win)
+        else:
+            rel = jnp.arange(logits.shape[-1])[None, :] - at   # [1|b, K]
         if tree is None:
             allowed = (rel[:, None, :]
                        <= jnp.arange(q)[None, :, None])    # [1|b, q, K]
@@ -431,18 +695,26 @@ class MultiHeadAttention(Module):
                 :, jnp.clip(rel, 0, q - 1)], 0, 1)         # [1|b, q, K]
             allowed = (rel < 0)[:, None, :] | (in_chunk[:, None, :]
                                                & within)
+        if ring:        # a row no position of this sequence has reached
+            allowed = allowed & (at + rel >= 0)[:, None, :]
+        elif win is not None:
+            allowed = allowed & (rel[:, None, :]
+                                 > jnp.arange(q)[None, :, None] - win)
         logits = jnp.where(allowed[:, None], logits,
                            jnp.asarray(-1e30, logits.dtype))
         weights = jax.nn.softmax(logits, axis=-1).astype(x.dtype)
         with device_scope(KV_CACHE):
-            if layer is None:
+            if layer is not None:
+                o = _own_blocks_of(
+                    jnp.einsum("bhqk,bkc->bqhc", weights, cv), hd, grp)
+            elif grp == 1:
                 o = jnp.einsum("bhqk,bkhd->bqhd", weights, cv)
             else:
-                o = _own_blocks_of(
-                    jnp.einsum("bhqk,bkc->bqhc", weights, cv), hd)
-        o = o.reshape(b, q, self.d_model)
-        out = jnp.einsum("bsd,de->bse", o, params["wo"]) + params["bo"]
-        return out, cache
+                o = jnp.einsum(
+                    "bkgqt,btkd->bqkgd",
+                    weights.reshape(b, self.kv_heads, grp, q, cv.shape[1]),
+                    cv).reshape(b, q, self.nhead, hd)
+        return self._out(params, x, o), cache
 
 
 def slab_width(nhead: int, head_dim: int) -> int:
@@ -473,24 +745,35 @@ def unfold_heads(rows, nhead: int, head_dim: int):
         rows.shape[:-1] + (nhead, head_dim))
 
 
-def _own_blocks(qh, width: int):
+def _block_of_head(nhead: int, group: int, dtype):
+    """``[H, H / group]``: one where cached head ``g`` is the one query
+    head ``h`` reads (``g == h // group``); the identity without groups."""
+    eye = jnp.eye(nhead // group, dtype=dtype)
+    return eye if group == 1 else jnp.repeat(eye, group, axis=0)
+
+
+def _own_blocks(qh, width: int, group: int = 1):
     """``qh [b, q, H, D]`` -> ``[b, q, H, C]``: head ``h``'s query in
-    block ``h`` of the folded axis and zeros in every other, so that one
-    product with folded cache rows ``[T, C]`` gives each head the scores
-    of its own keys."""
+    block ``h // group`` of the folded axis (its cached head's) and zeros
+    in every other, so that one product with folded cache rows ``[T, C]``
+    gives each head the scores of its own keys."""
     b, q, h, hd = qh.shape
-    eye = jnp.eye(h, dtype=qh.dtype)
-    blocks = qh[:, :, :, None, :] * eye[None, None, :, :, None]
-    return jnp.pad(blocks.reshape(b, q, h, h * hd),
-                   ((0, 0),) * 3 + ((0, width - h * hd),))
+    own = _block_of_head(h, group, qh.dtype)
+    blocks = qh[:, :, :, None, :] * own[None, None, :, :, None]
+    folded = (h // group) * hd
+    return jnp.pad(blocks.reshape(b, q, h, folded),
+                   ((0, 0),) * 3 + ((0, width - folded),))
 
 
-def _own_blocks_of(o, head_dim: int):
-    """``o [b, q, H, C]`` (each head's weights mixed over every head's
-    folded values) -> ``[b, q, H, D]``: head ``h`` keeps block ``h``."""
+def _own_blocks_of(o, head_dim: int, group: int = 1):
+    """``o [b, q, H, C]`` (each head's weights mixed over every cached
+    head's folded values) -> ``[b, q, H, D]``: head ``h`` keeps block ``h
+    // group``."""
     b, q, h, _ = o.shape
-    blocks = o[..., :h * head_dim].reshape(b, q, h, h, head_dim)
-    return jnp.einsum("bqhgd,hg->bqhd", blocks, jnp.eye(h, dtype=o.dtype))
+    hkv = h // group
+    blocks = o[..., :hkv * head_dim].reshape(b, q, h, hkv, head_dim)
+    return jnp.einsum("bqhgd,hg->bqhd", blocks,
+                      _block_of_head(h, group, o.dtype))
 
 
 def _write_slab_rows(slab, rows, layer, pos):

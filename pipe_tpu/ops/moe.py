@@ -24,6 +24,11 @@ TPU-shaped design:
 
 ``ep_axis=None`` runs the identical math unsharded — the transparency
 yardstick (``tests/test_moe.py``).
+
+Beside it, for serving a chip's share of a large expert layer:
+:func:`dropless_moe` (no capacity, no dropped token: the rows are sorted
+by expert and the held experts' rows go through one grouped product).
+The capacity layer above stays the training path's.
 """
 
 from __future__ import annotations
@@ -35,6 +40,7 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from ..core.partition import StageCtx
+from ..obs.events import MOE_EXPERTS, MOE_ROUTER, device_scope
 from ..parallel.mesh import MODEL_AXIS
 from .tp_layers import (tp_allreduce, tp_attention_init,
                         tp_attention_sublayer, tp_enter, _dropout,
@@ -42,7 +48,8 @@ from .tp_layers import (tp_allreduce, tp_attention_init,
 
 __all__ = ["moe_ffn_init", "moe_ffn_apply", "moe_ffn_specs", "moe_capacity",
            "moe_block_init", "moe_block_apply", "moe_block_decode",
-           "moe_block_specs"]
+           "moe_block_specs", "dropless_moe_init", "dropless_moe",
+           "DROPLESS_COUNTS"]
 
 
 def moe_ffn_init(key: jax.Array, d_model: int, d_ff: int, n_experts: int,
@@ -245,3 +252,109 @@ def moe_block_apply(p: Dict[str, Any], h: jax.Array, ctx: StageCtx, *,
                             capacity_factor=capacity_factor,
                             ep_axis=ep_axis)
     return h + _dropout(ff, dropout, key2), aux
+
+
+# ---------------------------------------------------------------------------
+# Dropless top-k routing over a chip's share of the experts (serving)
+# ---------------------------------------------------------------------------
+
+# what :func:`dropless_moe` counts, in the order of its second result
+DROPLESS_COUNTS = ("expert_rows", "absent_rows", "experts_touched")
+
+
+def dropless_moe_init(key: jax.Array, d_model: int, d_ff: int,
+                      n_experts: int, held: int,
+                      dtype=jnp.float32) -> Dict[str, Any]:
+    """A router over all ``n_experts`` and the weights of the ``held``
+    experts that live here, each a gated SiLU MLP of width ``d_ff``."""
+    ks = jax.random.split(key, 4)
+
+    def mat(k, shape, fan_in):
+        b = 1.0 / float(fan_in) ** 0.5
+        return jax.random.uniform(k, shape, dtype, -b, b)
+
+    return {"router": mat(ks[0], (d_model, n_experts), d_model),
+            "w_gate": mat(ks[1], (held, d_model, d_ff), d_model),
+            "w_up": mat(ks[2], (held, d_model, d_ff), d_model),
+            "w_down": mat(ks[3], (held, d_ff, d_model), d_ff)}
+
+
+def dropless_moe(p: Dict[str, Any], x: jax.Array, *, top_k: int,
+                 first: int = 0, scale: float = 1.0, live=None, layer=None):
+    """The routed part of an expert layer as ONE member of an
+    expert-parallel group computes it: ``sum_{i in I, i held} w_i E_i(x)``.
+
+    ``x [rows, d]``. ``p["router"] [d, E]`` has the PUBLISHED width: every
+    row is scored against all ``E`` experts (logits, softmax and top-k in
+    float32), its ``top_k`` picks ``I`` renormalised over all of them and
+    scaled (``w_i = scale r_i / sum_{j in I} r_j``), whether or not pick
+    ``i`` lives here. ``p["w_gate"]``, ``p["w_up"] [held, d, f]`` and
+    ``p["w_down"] [held, f, d]`` are experts ``first .. first + held - 1``.
+    Pairs whose expert is absent are dropped from the product, not stood
+    in for: what those experts would add is another chip's part of the
+    sum. ``live [rows]`` (optional) drops a row's pairs likewise (a dead
+    slot, a bucket's padding). With ``layer`` (a traced index) the three
+    expert tensors lead with a layers axis, ``[layers, held, ...]``, and
+    the product takes layer ``layer`` of them where they lie, as groups
+    ``layer * held ..`` of ``layers * held``: a scan over like layers
+    never slices a layer's experts out (a copy of all of them a step).
+    No capacity and no overflow: the pairs are
+    sorted by expert and the held ones go through one grouped product
+    (``jax.lax.ragged_dot``, on the TPU the compiler's own grouped-matmul
+    kernel, which reads the weights of the experts that have rows and of
+    no other). One function for a prefill's rows and a decode step's.
+
+    Returns ``(y [rows, d] in x's type, counts int32[3])``, the counts in
+    :data:`DROPLESS_COUNTS`' order: pairs computed here, pairs of live
+    rows left to absent experts, held experts with at least one row."""
+    rows, d = x.shape
+    held = p["w_gate"].shape[0 if layer is None else 1]
+    f32 = jnp.float32
+    with device_scope(MOE_ROUTER):
+        logits = jnp.einsum("td,de->te", x.astype(f32),
+                            p["router"].astype(f32),
+                            precision=jax.lax.Precision.HIGHEST)
+        top_r, top_e = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), top_k)
+        w = scale * top_r / jnp.sum(top_r, axis=-1, keepdims=True)
+        local = top_e - first
+        here = (local >= 0) & (local < held)
+        if live is not None:
+            here = here & live[:, None]
+        # an absent pair sorts behind every held expert's
+        flat_e = jnp.where(here, local, held).reshape(-1)   # [rows * k]
+        order = jnp.argsort(flat_e, stable=True)
+        sorted_e = flat_e[order]
+        bounds = jnp.searchsorted(
+            sorted_e, jnp.arange(held + 1, dtype=sorted_e.dtype))
+        sizes = (bounds[1:] - bounds[:-1]).astype(jnp.int32)   # [held]
+        computed = bounds[-1].astype(jnp.int32)
+    with device_scope(MOE_EXPERTS):
+        experts = {n: p[n] for n in ("w_gate", "w_up", "w_down")}
+        groups = sizes
+        if layer is not None:
+            layers = p["w_gate"].shape[0]
+            experts = {n: a.reshape((layers * held,) + a.shape[2:])
+                       for n, a in experts.items()}
+            groups = jax.lax.dynamic_update_slice(
+                jnp.zeros((layers * held,), jnp.int32), sizes,
+                (layer * held,))
+        xs = jnp.take(x, order // top_k, axis=0)            # [rows * k, d]
+        a = jax.lax.ragged_dot(xs, experts["w_gate"], groups,
+                               preferred_element_type=f32)
+        b = jax.lax.ragged_dot(xs, experts["w_up"], groups,
+                               preferred_element_type=f32)
+        h = (jax.nn.silu(a) * b).astype(x.dtype)
+        ys = jax.lax.ragged_dot(h, experts["w_down"], groups,
+                                preferred_element_type=f32)
+        # rows behind the held pairs belong to no group: whatever the
+        # product left there is not read
+        ys = jnp.where((jnp.arange(rows * top_k) < computed)[:, None],
+                       ys * w.reshape(-1)[order][:, None], 0.0)
+        # back to the pairs' own order, then each row's picks summed
+        y = jnp.sum(jnp.take(ys, jnp.argsort(order), axis=0).reshape(
+            rows, top_k, d), axis=1).astype(x.dtype)
+    n_live = (jnp.int32(rows) if live is None
+              else jnp.sum(live.astype(jnp.int32)))
+    counts = jnp.stack([computed, n_live * top_k - computed,
+                        jnp.sum((sizes > 0).astype(jnp.int32))])
+    return y, counts

@@ -28,6 +28,14 @@ and the round (``decode_chunk`` plain steps, or one speculative
 draft/verify round). ``resident`` sets only its horizon: how many
 rounds one launch may run before the host looks again.
 
+A model of one block is scanned as one stack of layers. A model whose
+layers differ hands them over in groups of like layers
+(``model.layer_groups()``; ``models/laguna.py``): a group is scanned, the
+groups run in order, each kind of cache has a slab of its own in the
+loop's carry (a window layer's a ring), and a prefill attends a block of
+queries at a time and seats its rows in both. Only the slab store and
+the plain round take such a model.
+
 Zero steady-state recompiles is a pinned invariant, not an aspiration:
 the decode program body increments ``serve.engine.decode_traces`` (a
 one-chunk horizon) or ``serve.engine.resident_traces`` (a longer one)
@@ -64,6 +72,7 @@ from ..inference.draft import DraftSource, resolve_draft, tree_layout
 from ..inference.generate import (GenerationConfig, head_logits,
                                   sample_logits)
 from ..inference.quant import QuantLeaf, dequant_tree
+from ..models.common import refuse_grouped
 from ..obs import events as ev
 from ..obs.events import NULL_EVENT_LOG, REQUEST
 from ..obs.telemetry import get_registry, host_overhead_per_token
@@ -75,6 +84,10 @@ from .kvpool import (HostKvStore, KvPool, PoolExhausted, block_demand,
 from .queue import QueueFull, Request, RequestQueue, Response
 
 __all__ = ["SingleDeviceSlotBackend", "ServeEngine", "EngineDraining"]
+
+
+# rows of a grouped model's carried ``counts``: who counted
+COUNT_PREFILL, COUNT_DECODE = 0, 1
 
 
 class EngineDraining(RuntimeError):
@@ -115,7 +128,10 @@ class _Round(NamedTuple):
 class _SlabStore:
     """Seam A of the decode program, the slab: the program's KV
     argument is the carried ``[L, S, T, C]`` rows themselves, and
-    nothing stands behind them."""
+    nothing stands behind them. For a model whose layers come in groups
+    it is one slab a kind of cache (``{"full": [2, S, max_len, C],
+    "window": [3, S, window, C]}``, the second a ring) and the counts
+    the layers keep (``"counts"``), all of it carried alike."""
 
     def __init__(self, backend):
         self.b = backend
@@ -292,11 +308,27 @@ class SingleDeviceSlotBackend:
                       else p.astype(cd),
                       bp, is_leaf=lambda x: isinstance(x, QuantLeaf))
                   for bp in flat]
-        self._n_layers = len(blocks)
         self._n_stages = len(stage_params)
         self._layers_per_stage = len(stage_params[0])
-        self._block_stack = jax.tree_util.tree_map(
-            lambda *xs: jnp.stack(xs), *blocks)
+        # The layers come in GROUPS of like layers: a group is scanned,
+        # the groups run in order. A model of one block is one group,
+        # its blocks stacked here once. A model that has
+        # ``layer_groups`` hands each group's parameters in already
+        # stacked, the layout they are served in: nothing is stacked or
+        # copied a second time (its weights may not fit twice).
+        grouped = getattr(model, "layer_groups", None)
+        self._groups = None if grouped is None else grouped()
+        if self._groups is None:
+            self._n_layers = len(blocks)
+            self._block_stack = jax.tree_util.tree_map(
+                lambda *xs: jnp.stack(xs), *blocks)
+        else:
+            self._n_layers = sum(g.n for g in self._groups)
+            self._block_stack = tuple(blocks)
+            if kv_block_size is not None or gen.kv_block_size is not None:
+                refuse_grouped(model, "_PoolStore (the paged KV pool)")
+            if spec is not None:
+                refuse_grouped(model, "_spec_round (speculative decoding)")
         self._pre = pre_params
         self._post = post_params
 
@@ -341,8 +373,8 @@ class SingleDeviceSlotBackend:
             else gen.kv_block_size
         self.paged = kbs is not None
         self.kv_dtype = kv_dtype
-        proto = model.block.attn.make_cache(1, max_len, dtype=cd)
         if self.paged:
+            proto = model.block.attn.make_cache(1, max_len, dtype=cd)
             # paged KV: a block pool + per-slot tables replace the slab.
             # Default pool = the slab's row budget (same memory, ~2x the
             # servable live slots on mixed-length traffic) + block 0.
@@ -402,8 +434,11 @@ class SingleDeviceSlotBackend:
             self.kv_offload = False
             self._kv_store = None
             self.pool = None
-            self._caches = model.block.attn.make_slab(
-                self._n_layers, num_slots, max_len, dtype=cd)
+            if self._groups is None:
+                self._caches = model.block.attn.make_slab(
+                    self._n_layers, num_slots, max_len, dtype=cd)
+            else:
+                self._caches = self._make_group_slabs(cd)
         self._tok = jnp.zeros((num_slots,), jnp.int32)
         self._pos = jnp.zeros((num_slots,), jnp.int32)
         kd0 = jax.random.key_data(jax.random.key(0))
@@ -476,7 +511,8 @@ class SingleDeviceSlotBackend:
 
     # -- device programs ---------------------------------------------------
 
-    def _run_layers(self, block_stack, h, caches, pos, tree=None):
+    def _run_layers(self, block_stack, h, caches, pos, tree=None,
+                    live=None):
         """THE layer loop of the decode program (slab and paged views
         alike, the plain step, the speculative verify and the truncated
         drafters): ``h [S, q, d]`` through all layers
@@ -496,9 +532,23 @@ class SingleDeviceSlotBackend:
         rows of ``[H, D]`` had 2.6x, no launch relays it, and a row
         write touches 13 tiles (``MultiHeadAttention.decode``; PERF.md,
         PR 29). ``block_stack`` may hold fewer layers than the cache:
-        the loop then runs the first ones (a truncated drafter)."""
+        the loop then runs the first ones (a truncated drafter).
+
+        A model whose layers come in groups (:meth:`_run_groups`) has
+        one such carried slab a kind of cache, in ``caches`` by name,
+        and ``live [S]`` tells its expert layers which slots' rows to
+        compute."""
         m = self.model
         cd = m.cfg.compute_dtype
+        if self._groups is not None:
+            def step(g, bp, i, h, slab):
+                h, slab, counts = g.block.decode(
+                    bp, h, slab, pos, tree=tree, layer=g.first + i,
+                    live=None if live is None else live[:, None], at=i)
+                return h, slab, None, counts
+
+            return self._run_groups(block_stack, h, caches, COUNT_DECODE,
+                                    step)[:2]
 
         def layer(carry, inp):
             h, caches = carry
@@ -510,6 +560,99 @@ class SingleDeviceSlotBackend:
         (h, caches), _ = jax.lax.scan(
             layer, (h, caches),
             (block_stack, jnp.arange(n, dtype=jnp.int32)))
+        return h, caches
+
+    # -- layers in groups ----------------------------------------------------
+
+    def _make_group_slabs(self, cd):
+        """The carried state of a model whose layers come in groups:
+        one slab a kind of cache, each as its own attention lays it out
+        (``make_slab``: ``max_len`` rows a slot, or a ring of the
+        window's), and ``counts [2, n]``: what the layers and the decode
+        step count (``self._count_names``), row :data:`COUNT_PREFILL`
+        by the prefill programs and row :data:`COUNT_DECODE` by the
+        decode program, summed on the device for as long as the backend
+        lives (int32, wrapping: the host takes differences)."""
+        kinds = {}
+        for g in self._groups:
+            attn, n = kinds.get(g.cache, (g.block.attn, 0))
+            kinds[g.cache] = (attn, max(n, g.first + g.n))
+        slabs = {kind: attn.make_slab(n, self.num_slots, self.max_len,
+                                      dtype=cd)
+                 for kind, (attn, n) in kinds.items()}
+        self._layer_counts = tuple(self.model.layer_counts)
+        self._cache_kinds = tuple(slabs)
+        self._count_names = self._layer_counts + tuple(
+            f"cache.{kind}_rows_read" for kind in slabs)
+        self._counts_seen = np.zeros((2, len(self._count_names)), np.uint32)
+        self.launch_counts = {}
+        return dict(slabs, counts=jnp.zeros(self._counts_seen.shape,
+                                            jnp.int32))
+
+    def _run_groups(self, stacks, h, caches, row, step):
+        """``h`` through every group in order, a group a ``lax.scan``
+        over its layers' indices with its stacked parameters standing
+        outside the scan (a layer picks its own; the routed experts are
+        never sliced out). ``step(group, stack, i, h, slab) -> (h, slab,
+        rows, counts)``: the slab as the layer leaves it, the layer's new
+        cache rows where the caller seats them itself (else None), and
+        the layer's counts, which are summed into row ``row`` of the
+        carried ``counts``. Returns ``(h, caches, [a group's stacked
+        rows])``."""
+        caches = dict(caches)
+        n_counts = len(self._layer_counts)
+        total = jnp.zeros((n_counts,), jnp.int32)
+        rows = []
+        for g, bp in zip(self._groups, stacks):
+            def layer(carry, i, g=g, bp=bp):
+                h, slab, acc = carry
+                h, slab, new, c = step(g, bp, i, h, slab)
+                return (h, slab, acc + c), new
+
+            (h, caches[g.cache], total), new = jax.lax.scan(
+                layer, (h, caches[g.cache], total),
+                jnp.arange(g.n, dtype=jnp.int32))
+            rows.append(new)
+        caches["counts"] = caches["counts"].at[row, :n_counts].add(total)
+        return h, caches, rows
+
+    def _count_rows_read(self, caches, pos, live):
+        """The cache rows a decode step's live slots read, by kind of
+        cache (a ring's stop growing at its length), into the carried
+        counts."""
+        counts = caches["counts"]
+        for j, kind in enumerate(self._cache_kinds,
+                                 start=len(self._layer_counts)):
+            layers, _, length, _ = caches[kind]["k"].shape
+            rows = jnp.sum(jnp.where(live, jnp.minimum(pos + 1, length), 0))
+            counts = counts.at[COUNT_DECODE, j].add(rows * layers)
+        return dict(caches, counts=counts)
+
+    def _prefill_groups(self, block_stack, pre, caches, prompt, true_len,
+                        slot):
+        """A padded prompt through the groups without a temporary cache:
+        each layer's attention runs over the prompt itself a block of
+        queries at a time (``block.prefill``), and its rows are seated in
+        the slot of its kind of slab as that attention keeps them
+        (``attn.seat``: the bucket's rows, or the ring of the last
+        ``window``). The bucket's padding takes no part in the experts'
+        product. Returns ``(h [1, B, d], caches)``."""
+        live = (jnp.arange(prompt.shape[1]) < true_len)[None, :]
+
+        def step(g, bp, i, h, slab):
+            h, new, counts = g.block.prefill(bp, h, live=live, at=i)
+            return h, slab, new, counts
+
+        h, caches, rows = self._run_groups(
+            block_stack, self.model.embed_at(pre, prompt, 0), caches,
+            COUNT_PREFILL, step)
+        with ev.device_scope(ev.KV_CACHE):       # the slot's rows, seated
+            for g, new in zip(self._groups, rows):
+                caches[g.cache] = {
+                    n: jax.lax.dynamic_update_slice(
+                        slab, g.block.attn.seat(new[n], true_len).astype(
+                            slab.dtype), (g.first, slot, 0, 0))
+                    for n, slab in caches[g.cache].items()}
         return h, caches
 
     def _arm(self, state, slot, true_len, tok0, key, row):
@@ -543,6 +686,11 @@ class SingleDeviceSlotBackend:
         m, gen = self.model, self.gen
         cd = m.cfg.compute_dtype
         get_registry().counter("serve.engine.prefill_traces").inc()
+        if self._groups is not None:
+            h, caches = self._prefill_groups(block_stack, pre, caches,
+                                             prompt, true_len, slot)
+            return self._prefill_end(post, caches, state, h, true_len,
+                                     slot, seed, row)
         with ev.device_scope(ev.KV_CACHE):       # the temporary cache
             proto = m.block.attn.make_cache(1, self.max_len, dtype=cd)
             temp0 = jax.tree_util.tree_map(
@@ -561,6 +709,13 @@ class SingleDeviceSlotBackend:
                 lambda big, rows: jax.lax.dynamic_update_slice(
                     big, fold_heads(rows), (0, slot, 0, 0)),
                 caches, temp)
+        return self._prefill_end(post, caches, state, h, true_len, slot,
+                                 seed, row)
+
+    def _prefill_end(self, post, caches, state, h, true_len, slot, seed,
+                     row):
+        """Traced, what every slab prefill ends in: the first token from
+        the prompt's last real row, and the slot armed with it."""
         h_last = jax.lax.dynamic_slice(
             h, (0, true_len - 1, 0), (1, 1, h.shape[-1]))
         tok0, key = self._first_token(post, h_last, seed)
@@ -666,7 +821,12 @@ class SingleDeviceSlotBackend:
 
         h = jax.vmap(embed_one)(tok, pos)                  # [S, 1, d]
 
-        h, caches = self._run_layers(block_stack, h, caches, pos)
+        if self._groups is None:
+            h, caches = self._run_layers(block_stack, h, caches, pos)
+        else:
+            h, caches = self._run_layers(block_stack, h, caches, pos,
+                                         live=~done)
+            caches = self._count_rows_read(caches, pos, ~done)
         logits = head_logits(m, post, h)[:, 0, :]          # [S, V]
         keys = jax.random.wrap_key_data(key_data)
         ks = jax.vmap(jax.random.split)(keys)              # [S, 2] keys
@@ -1141,6 +1301,8 @@ class SingleDeviceSlotBackend:
             k = int(k)                         # THE host sync
             buf = np.asarray(buf)              # then the two fetches
             counts = np.asarray(counts)
+            if self._groups is not None:       # and the layers' counts
+                self._land_counts(reg, np.asarray(self._caches["counts"]))
         if k < rm:
             reg.counter("serve.engine.device_exits").inc()
         W = self.decode_width
@@ -1180,6 +1342,27 @@ class SingleDeviceSlotBackend:
                 self._spec_ewma[upd] = (0.7 * self._spec_ewma[upd]
                                         + 0.3 * mean_acc[upd])
         return toks, valid
+
+    def _land_counts(self, reg, counts: np.ndarray) -> None:
+        """The device's running counts as this launch left them: what
+        grew since the last launch goes to the ``serve.<name>`` counters
+        (prefill programs and decode steps together) and, split, to
+        ``launch_counts``, which the engine writes into the launch's
+        ``serve.decode.done`` span: the decode program's under each
+        count's own name, the prefill programs' since the last launch
+        under ``prefill_<name>``."""
+        now = counts.astype(np.uint32)
+        grew = (now - self._counts_seen).astype(np.int64)   # wraps right
+        self._counts_seen = now
+        self.launch_counts = {}
+        for j, name in enumerate(self._count_names):
+            short = name.split(".", 1)[1]
+            self.launch_counts[short] = int(grew[COUNT_DECODE, j])
+            if name in self._layer_counts:
+                self.launch_counts["prefill_" + short] = int(
+                    grew[COUNT_PREFILL, j])
+            if grew[:, j].any():
+                reg.counter(f"serve.{name}").inc(int(grew[:, j].sum()))
 
     def _pick_spec_k(self, live: np.ndarray) -> int:
         """Smallest pre-traced ladder rung covering the live slots'
@@ -1846,7 +2029,8 @@ class ServeEngine:
                 with self.events.span(
                         ev.SERVE_DECODE_DONE, steps=steps, chunks=chunks,
                         live=n_live, rows=rows, emitted=emitted,
-                        early_exit=int(chunks < r_max)):
+                        early_exit=int(chunks < r_max),
+                        **getattr(self.backend, "launch_counts", {})):
                     pass
                 reg.counter("serve.engine.decode_launches").inc()
                 reg.counter("serve.engine.decode_steps").inc(steps)

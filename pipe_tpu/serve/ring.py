@@ -57,6 +57,7 @@ from ..inference.draft import DraftSource, resolve_draft
 from ..inference.generate import (GenerationConfig, head_logits,
                                   sample_logits)
 from ..inference.quant import QuantLeaf, dequant_tree
+from ..models.common import refuse_grouped
 from ..obs.telemetry import get_registry
 from ..parallel.mesh import STAGE_AXIS
 from .buckets import BucketSpec
@@ -105,6 +106,7 @@ class RingSlotBackend:
                 f"revolutions must be >= 1, got {revolutions}")
         if max_len < 2:
             raise ValueError(f"max_len must be >= 2, got {max_len}")
+        refuse_grouped(model, "RingSlotBackend (serve/ring.py)")
         self.mesh = mesh
         self.model = model
         self.gen = gen

@@ -519,6 +519,13 @@ def _tiled_bytes(shape: str) -> int:
     return _HLO_ITEMSIZE[m.group(1)] * math.prod(dims)
 
 
+def _loop_computations(comps: dict):
+    """The computations a ``while`` holds, however deeply."""
+    return _reachable(comps, [
+        mt.group(1) for body in comps.values()
+        for mt in re.finditer(r"(?:body|condition)=%?([\w.\-]+)", body)])
+
+
 def _slab_census(hlo: str, slab_elems: int, layers: int, dtype: str):
     """Where a compiled decode program moves the KV slab about: every
     ``copy`` or ``transpose`` whose result is a whole slab tensor or one
@@ -529,9 +536,7 @@ def _slab_census(hlo: str, slab_elems: int, layers: int, dtype: str):
     outside any loop); and the distinct shapes-with-layout the slab
     takes anywhere, with their tiled bytes."""
     comps = _hlo_computations(hlo)
-    in_loop = _reachable(comps, [
-        mt.group(1) for body in comps.values()
-        for mt in re.finditer(r"(?:body|condition)=%?([\w.\-]+)", body)])
+    in_loop = _loop_computations(comps)
     sizes = {slab_elems: "slab", slab_elems // layers: "layer"}
     moves = {"in_loops": [], "outside_loops": []}
     forms = {}
@@ -576,10 +581,39 @@ def _io_census(hlo: str) -> dict:
             "outputs": outs, "aliases": aliases}
 
 
-def slab(num_slots: int = 8, max_len: int = 640, decode_chunk: int = 4,
+def _big_copies(hlo: str, at_least: int):
+    """Every ``copy`` of a compiled program whose result holds at least
+    ``at_least`` bytes, with whether a ``while`` body holds it: where a
+    program relays a weight matrix or a slab before or inside its loop."""
+    comps = _hlo_computations(hlo)
+    in_loop = _loop_computations(comps)
+    found = []
+    for nm, body in comps.items():
+        for mt in re.finditer(
+                r"^\s*(?:ROOT )?%?([\w.\-]+) = "
+                r"((\w+)\[([\d,]*)\](?:\{[^}]*\})?) copy\(", body, re.M):
+            if mt.group(3) not in _HLO_ITEMSIZE:
+                continue
+            n = _tiled_bytes(mt.group(2))
+            if n >= at_least:
+                found.append({"name": mt.group(1), "shape": mt.group(2),
+                              "bytes": n, "in_loop": nm in in_loop})
+    return found
+
+
+SLAB_MODELS = {
+    # the sizes of each model's serve cell (benchmark/traffic/)
+    "gpt2": dict(num_slots=8, max_len=640, prefill_bucket=512),
+    "laguna": dict(num_slots=16, max_len=2432, prefill_bucket=2048),
+}
+HBM_BYTES = int(15.75 * 2**30)
+
+
+def slab(num_slots: int = None, max_len: int = None, decode_chunk: int = 4,
          resident_chunks: int = 8, n_layers: int = 48, d_model: int = 1600,
          nhead: int = 25, d_ff: int = 6400, vocab: int = 50257,
-         prefill_bucket: int = 512, topology: str = "v5e:2x2") -> dict:
+         prefill_bucket: int = None, topology: str = "v5e:2x2",
+         model: str = "gpt2") -> dict:
     """Where the KV slab lives in the serve engine's compiled programs,
     for a DESCRIBED TPU (no device; nothing runs): the check a cache
     layout PR makes before it spends chip time (PERF.md, PRs 26, 29).
@@ -599,7 +633,17 @@ def slab(num_slots: int = 8, max_len: int = 640, decode_chunk: int = 4,
     of the slab over 1.05x its data; and what PR 31 asked: the prefill
     program, which arms its slot itself, writes the slab and the slots'
     ``tok``, ``pos`` and ``key_data`` in place. Exits non-zero
-    otherwise."""
+    otherwise.
+
+    ``model="laguna"`` builds Laguna-S-2.1's share for one chip
+    (``LagunaConfig()``: 10.4 GiB of weights, unmade) at its cell's sizes
+    (16 slots of 2,432 rows, the 2,048 bucket). It has one slab a kind of
+    cache (``slabs``: full layers at ``max_len`` rows, window layers a
+    ring), each checked as above. ``big_copies`` lists every ``copy`` of
+    64 MiB or more (a weight matrix relaid before the loop), and ``ok``
+    also asks that they come to under a twentieth of the arguments (at
+    these sizes a copy of the weights does not fit) and that arguments
+    and temporaries stay under the chip's 15.75 GiB."""
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
@@ -609,20 +653,29 @@ def slab(num_slots: int = 8, max_len: int = 640, decode_chunk: int = 4,
     from jax.sharding import SingleDeviceSharding
 
     from pipe_tpu.inference import GenerationConfig
-    from pipe_tpu.models.gpt2 import GPT2Config, PipelinedGPT2
     from pipe_tpu.serve import BucketSpec, SingleDeviceSlotBackend
 
-    cfg = GPT2Config(vocab=vocab, d_model=d_model, nhead=nhead, d_ff=d_ff,
-                     n_layers=n_layers, dropout=0.0,
-                     seq_len=max(max_len, 1024), compute_dtype=jnp.bfloat16)
-    model = PipelinedGPT2(cfg, 1)
+    sizes = SLAB_MODELS[model]
+    num_slots = sizes["num_slots"] if num_slots is None else num_slots
+    max_len = sizes["max_len"] if max_len is None else max_len
+    prefill_bucket = (sizes["prefill_bucket"] if prefill_bucket is None
+                      else prefill_bucket)
+    if model == "laguna":
+        from pipe_tpu.models.laguna import LagunaConfig, PipelinedLaguna
+        net = PipelinedLaguna(LagunaConfig(), 1)
+    else:
+        from pipe_tpu.models.gpt2 import GPT2Config, PipelinedGPT2
+        net = PipelinedGPT2(GPT2Config(
+            vocab=vocab, d_model=d_model, nhead=nhead, d_ff=d_ff,
+            n_layers=n_layers, dropout=0.0, seq_len=max(max_len, 1024),
+            compute_dtype=jnp.bfloat16), 1)
     gen = GenerationConfig(max_new_tokens=max_len - prefill_bucket,
                            temperature=0.0)
     made = []
 
     def build():
         b = SingleDeviceSlotBackend(
-            model, model.init(jax.random.key(0)), num_slots=num_slots,
+            net, net.init(jax.random.key(0)), num_slots=num_slots,
             max_len=max_len, gen=gen, buckets=BucketSpec.of(prefill_bucket),
             decode_chunk=decode_chunk, resident=True,
             resident_chunks=resident_chunks)
@@ -646,20 +699,34 @@ def slab(num_slots: int = 8, max_len: int = 640, decode_chunk: int = 4,
         "resident": lambda: resident_fn.lower(*resident_args),
         f"prefill{prefill_bucket}": lambda: prefill_fn.lower(*prefill_args),
     }
-    k = caches["k"]
-    elems = math.prod(k.shape)
-    data = elems * k.dtype.itemsize
-    out = {"topology": topology, "num_slots": num_slots, "max_len": max_len,
-           "slab_shape": list(k.shape), "slab_data_bytes": data,
+    # one slab, or one a kind of cache (a model whose layers come in groups)
+    slabs = ({"kv": caches["k"]} if "k" in caches else
+             {kind: c["k"] for kind, c in caches.items() if kind != "counts"})
+    k = next(iter(slabs.values()))
+    data = math.prod(k.shape) * k.dtype.itemsize
+    out = {"topology": topology, "model": model, "num_slots": num_slots,
+           "max_len": max_len, "slab_shape": list(k.shape),
+           "slab_data_bytes": data,
+           "slabs": {kind: list(a.shape) for kind, a in slabs.items()},
            "programs": {}}
     violations = []
     for name, lower in programs.items():
         compiled = lower().compile()
         hlo = compiled.as_text()
-        moves, forms = _slab_census(hlo, elems, k.shape[0], "bf16")
+        moves, forms = {"in_loops": [], "outside_loops": []}, {}
+        fat = {}
+        for a in slabs.values():
+            elems = math.prod(a.shape)
+            mv, fm = _slab_census(hlo, elems, a.shape[0], "bf16")
+            for where in moves:
+                moves[where] += mv[where]
+            forms.update(fm)
+            fat.update({f: n for f, n in fm.items()
+                        if n > 1.05 * elems * a.dtype.itemsize})
         ma = compiled.memory_analysis()
         out["programs"][name] = {
             "slab_moves": moves, "slab_forms_bytes": forms,
+            "big_copies": _big_copies(hlo, 64 * 2**20),
             # what a refactor compares with its parent's: equal counts
             # are the same program
             "hlo_ops": dict(sorted(collections.Counter(re.findall(
@@ -674,11 +741,21 @@ def slab(num_slots: int = 8, max_len: int = 640, decode_chunk: int = 4,
         if name != "resident" and not (
                 aliased[f"s32[{num_slots}]"] >= 2
                 and aliased[f"u32[{num_slots},2]"] >= 1
-                and sum(aliased.values()) >= 5):
+                and sum(aliased.values()) >= 3 + 2 * len(slabs)):
             violations.append(
                 f"{name}: the slab and the slots' tok, pos and key_data "
                 f"are not all written in place: aliased {dict(aliased)}")
-        fat = {f: n for f, n in forms.items() if n > 1.05 * data}
+        if model != "gpt2":
+            need = (ma.argument_size_in_bytes + ma.temp_size_in_bytes
+                    + ma.output_size_in_bytes - ma.alias_size_in_bytes)
+            copied = sum(c["bytes"]
+                         for c in out["programs"][name]["big_copies"])
+            if (copied > ma.argument_size_in_bytes / 20
+                    or need > HBM_BYTES):
+                violations.append(
+                    f"{name}: {copied} B in copies of 64 MiB or more; "
+                    f"arguments + temporaries + unaliased outputs {need} B "
+                    f"of {HBM_BYTES}")
         if name == "resident" and (moves["in_loops"]
                                    or moves["outside_loops"] or fat):
             violations.append(
@@ -709,7 +786,7 @@ if __name__ == "__main__":
         k, v = a.lstrip("-").split("=", 1)
         k = k.replace("-", "_")
         kw[k] = tuple(v.split(",")) if k == "schedules" else (
-            v if k in ("checkpoint", "topology") else int(v))
+            v if k in ("checkpoint", "topology", "model") else int(v))
     res = mode(**kw)
     print(json.dumps(res))
     if mode in (phases, resident, slab) and not res["ok"]:
