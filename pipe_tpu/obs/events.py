@@ -127,7 +127,8 @@ DEVICE_SCOPES = (EMBED, ATTENTION, FFN, HEAD, LOSS, OPTIMIZER, KV_CACHE)
 # ``kv_cache`` go on adding up; a reader of these names looks for them
 # itself. The compiler's own grouped-product kernel keeps no path at all
 # (its ``op_name`` is ``ragged-dot-*``): a reader of ``moe_experts``
-# counts it in.
+# counts it in. The tiled one (``ops/grouped_product.py``) keeps its path:
+# ``.../ffn/moe_experts/grouped_product/pallas_call``.
 MOE_ROUTER = "moe_router"
 MOE_EXPERTS = "moe_experts"
 MOE_SHARED = "moe_shared"

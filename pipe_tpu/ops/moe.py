@@ -28,6 +28,12 @@ yardstick (``tests/test_moe.py``).
 Beside it, for serving a chip's share of a large expert layer:
 :func:`dropless_moe` (no capacity, no dropped token: the rows are sorted
 by expert and the held experts' rows go through one grouped product).
+Two implementations of that product, picked from the call's static shapes
+(:func:`grouped_impl`): the compiler's own kernel (``jax.lax.ragged_dot``)
+where the pairs are few (a decode step), and
+:func:`~pipe_tpu.ops.grouped_product.grouped_gated_mlp`, one Pallas kernel
+for the three products and the gate, in row tiles the size of an expert's
+share, where they are many (a prefill).
 The capacity layer above stays the training path's.
 """
 
@@ -41,7 +47,9 @@ from jax.sharding import PartitionSpec as P
 
 from ..core.partition import StageCtx
 from ..obs.events import MOE_EXPERTS, MOE_ROUTER, device_scope
+from ..obs.telemetry import get_registry
 from ..parallel.mesh import MODEL_AXIS
+from .grouped_product import grouped_gated_mlp, tile_groups
 from .tp_layers import (tp_allreduce, tp_attention_init,
                         tp_attention_sublayer, tp_enter, _dropout,
                         _layernorm)
@@ -49,7 +57,7 @@ from .tp_layers import (tp_allreduce, tp_attention_init,
 __all__ = ["moe_ffn_init", "moe_ffn_apply", "moe_ffn_specs", "moe_capacity",
            "moe_block_init", "moe_block_apply", "moe_block_decode",
            "moe_block_specs", "dropless_moe_init", "dropless_moe",
-           "DROPLESS_COUNTS"]
+           "DROPLESS_COUNTS", "grouped_impl"]
 
 
 def moe_ffn_init(key: jax.Array, d_model: int, d_ff: int, n_experts: int,
@@ -279,6 +287,26 @@ def dropless_moe_init(key: jax.Array, d_model: int, d_ff: int,
             "w_down": mat(ks[3], (held, d_ff, d_model), d_ff)}
 
 
+# Pairs a held expert (the mean the static shapes give) from which the tiled
+# kernel takes the grouped products. One layer's three products on a v5e at
+# hidden 3072, width 1024, 128 of 256 experts held, top-10
+# (``tools/grouped_product_bench.py``, PR 33), compiler's | tiled, in ms:
+# 160 pairs (a decode step of 16 slots, 1.25 an expert) 1.415 | 1.418, the
+# whole layer 1.449 | 1.463; 640 pairs (5 an expert) 3.66 | 2.98;
+# 1,280 4.85 | 3.23; 2,560 7.74 | 3.31; 5,120 8.00 | 3.48; 10,240 8.41 |
+# 3.80; 20,480 9.55 | 4.44. The two cross between 1.25 and 5.
+_TILED_FROM = 4
+
+
+def grouped_impl(pairs: int, held: int) -> str:
+    """Which implementation :func:`dropless_moe` gives the grouped product
+    of ``pairs`` (rows x picks) over ``held`` experts: ``"compiler"``
+    (``jax.lax.ragged_dot``) or ``"tiled"``
+    (:func:`~pipe_tpu.ops.grouped_product.grouped_gated_mlp`). A rule over
+    the call's static shapes and nothing else."""
+    return "tiled" if pairs >= _TILED_FROM * held else "compiler"
+
+
 def dropless_moe(p: Dict[str, Any], x: jax.Array, *, top_k: int,
                  first: int = 0, scale: float = 1.0, live=None, layer=None):
     """The routed part of an expert layer as ONE member of an
@@ -299,16 +327,32 @@ def dropless_moe(p: Dict[str, Any], x: jax.Array, *, top_k: int,
     ``layer * held ..`` of ``layers * held``: a scan over like layers
     never slices a layer's experts out (a copy of all of them a step).
     No capacity and no overflow: the pairs are
-    sorted by expert and the held ones go through one grouped product
-    (``jax.lax.ragged_dot``, on the TPU the compiler's own grouped-matmul
-    kernel, which reads the weights of the experts that have rows and of
-    no other). One function for a prefill's rows and a decode step's.
+    sorted by expert and the held ones go through one grouped product,
+    which reads the weights of the experts that have rows and of no other,
+    bfloat16 operands accumulated and returned in float32. One function
+    for a prefill's rows and a decode step's, and two implementations of
+    the product, by :func:`grouped_impl` over the static shapes: few pairs
+    (a decode step) stream through the compiler's kernel
+    (``jax.lax.ragged_dot``), many (a prefill) go in row tiles the size of
+    an expert's share (:mod:`~pipe_tpu.ops.grouped_product`). Trace-time
+    counters ``ops.moe.grouped.compiler`` and ``ops.moe.grouped.tiled``
+    say which a program took, once a call.
 
     Returns ``(y [rows, d] in x's type, counts int32[3])``, the counts in
     :data:`DROPLESS_COUNTS`' order: pairs computed here, pairs of live
     rows left to absent experts, held experts with at least one row."""
+    held = p["w_gate"].shape[0 if layer is None else 1]
+    return _dropless_moe(p, x, top_k=top_k, first=first, scale=scale,
+                         live=live, layer=layer,
+                         impl=grouped_impl(x.shape[0] * top_k, held))
+
+
+def _dropless_moe(p, x, *, top_k, first, scale, live, layer, impl):
+    """:func:`dropless_moe` with the grouped product's implementation
+    named (``"compiler"`` | ``"tiled"``)."""
     rows, d = x.shape
     held = p["w_gate"].shape[0 if layer is None else 1]
+    get_registry().counter(f"ops.moe.grouped.{impl}").inc()
     f32 = jnp.float32
     with device_scope(MOE_ROUTER):
         logits = jnp.einsum("td,de->te", x.astype(f32),
@@ -330,24 +374,34 @@ def dropless_moe(p: Dict[str, Any], x: jax.Array, *, top_k: int,
         computed = bounds[-1].astype(jnp.int32)
     with device_scope(MOE_EXPERTS):
         experts = {n: p[n] for n in ("w_gate", "w_up", "w_down")}
-        groups = sizes
+        first_group = 0
         if layer is not None:
+            # the layer's experts where they lie: groups ``layer * held ..``
             layers = p["w_gate"].shape[0]
             experts = {n: a.reshape((layers * held,) + a.shape[2:])
                        for n, a in experts.items()}
-            groups = jax.lax.dynamic_update_slice(
-                jnp.zeros((layers * held,), jnp.int32), sizes,
-                (layer * held,))
+            first_group = layer * held
         xs = jnp.take(x, order // top_k, axis=0)            # [rows * k, d]
-        a = jax.lax.ragged_dot(xs, experts["w_gate"], groups,
-                               preferred_element_type=f32)
-        b = jax.lax.ragged_dot(xs, experts["w_up"], groups,
-                               preferred_element_type=f32)
-        h = (jax.nn.silu(a) * b).astype(x.dtype)
-        ys = jax.lax.ragged_dot(h, experts["w_down"], groups,
-                                preferred_element_type=f32)
-        # rows behind the held pairs belong to no group: whatever the
-        # product left there is not read
+        if impl == "tiled":
+            ys = grouped_gated_mlp(
+                xs, experts["w_gate"], experts["w_up"], experts["w_down"],
+                tile_groups(sizes, rows * top_k), first_group=first_group)
+        else:
+            groups = sizes
+            if layer is not None:
+                groups = jax.lax.dynamic_update_slice(
+                    jnp.zeros((layers * held,), jnp.int32), sizes,
+                    (first_group,))
+            a = jax.lax.ragged_dot(xs, experts["w_gate"], groups,
+                                   preferred_element_type=f32)
+            b = jax.lax.ragged_dot(xs, experts["w_up"], groups,
+                                   preferred_element_type=f32)
+            h = (jax.nn.silu(a) * b).astype(x.dtype)
+            ys = jax.lax.ragged_dot(h, experts["w_down"], groups,
+                                    preferred_element_type=f32)
+        # rows behind the held pairs belong to no group: the compiler's
+        # kernel leaves zeros there and the tiled one whatever the memory
+        # held (NaN, interpreted); neither is read
         ys = jnp.where((jnp.arange(rows * top_k) < computed)[:, None],
                        ys * w.reshape(-1)[order][:, None], 0.0)
         # back to the pairs' own order, then each row's picks summed

@@ -398,3 +398,33 @@ def test_the_serve_app_builds_the_model_as_it_builds_gpt2(capsys):
     rc = app.main(["--family", "laguna", "--tiny", "--requests", "5",
                    "--rate", "0", "--slots", "2", "--max-new", "6"])
     assert rc == 0, capsys.readouterr()
+
+
+def test_the_prefill_programs_trace_the_tiled_product_and_decode_does_not(
+        tiny):
+    """The grouped product's implementation follows the static pair count
+    (``ops.moe.grouped_impl``; here top-3 over 4 held experts: tiled from 16
+    pairs): the one decode program (2 slots: 6 pairs) traces the compiler's
+    kernel in each of its expert-layer groups and no Pallas call; every
+    prefill bucket of 8 rows or more traces the tiled kernel alone."""
+    cfg, weights, model = tiny
+    be = SingleDeviceSlotBackend(
+        model, FAMILY.serve_params(weights), num_slots=2, max_len=32 + 24,
+        gen=GenerationConfig(max_new_tokens=24, temperature=0.0),
+        buckets=BucketSpec.pow2(min_len=8, max_len=32), decode_chunk=2,
+        resident=True, resident_chunks=3)
+    reg = get_registry()
+    names = ("ops.moe.grouped.compiler", "ops.moe.grouped.tiled",
+             "ops.grouped_product.interpreted")
+
+    def traced(program):
+        fn, args = program
+        before = [reg.counter(n).value for n in names]
+        fn.lower(*args)
+        return [reg.counter(n).value - b for n, b in zip(names, before)]
+
+    compiler, tiled, kernels = traced(be.decode_program())
+    assert compiler >= 2 and tiled == 0 and kernels == 0
+    for bucket in (8, 16, 32):
+        compiler, tiled, kernels = traced(be.prefill_program(bucket))
+        assert compiler == 0 and tiled >= 2 and kernels == tiled

@@ -36,6 +36,8 @@ Gold contract, layered on the serve suite's pins:
   history) and EOS landing mid-accepted-run all preserve the pin.
 """
 
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -841,3 +843,45 @@ def test_the_cells_resident_program_moves_no_slab_on_a_described_v5e(
     assert not [m for m in pre["slab_moves"]["outside_loops"]
                 + pre["slab_moves"]["in_loops"] if m["what"] == "slab"]
     assert out["ok"], out["violations"]
+
+
+@pytest.mark.parametrize("pairs", [5120, 20480])
+def test_the_tiled_grouped_product_compiles_for_a_described_v5e(
+        described_v5e, pairs):
+    """The expert layer's Pallas kernel at ``laguna-serve-closed16``'s
+    widths (a 512 and a 2048 bucket's pairs), compiled for a described v5e
+    (a few seconds each): two buffers of an expert's three whole matrices
+    fit the vector memory the call asks for; the stacked ``[3, 128, ...]``
+    weights reach the kernel as a bitcast (no ``copy`` of them: 2.4 GB a
+    layer each); and the custom call's ``op_name`` keeps the
+    ``moe_experts`` path the benchmark's readers look for."""
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    from pipe_tpu.obs.events import FFN, MOE_EXPERTS, device_scope
+    from pipe_tpu.ops.grouped_product import grouped_gated_mlp, tile_groups
+
+    chip = SingleDeviceSharding(topologies.get_topology_desc(
+        platform="tpu", topology_name=described_v5e).devices[0])
+    d, f = 3072, 1024
+
+    def experts(x, w_gate, w_up, w_down, sizes, layer):
+        with device_scope(FFN), device_scope(MOE_EXPERTS):
+            return grouped_gated_mlp(
+                x, *(w.reshape((3 * 128,) + w.shape[2:])
+                     for w in (w_gate, w_up, w_down)),
+                tile_groups(sizes, pairs), first_group=layer * 128,
+                interpret=False)
+
+    hlo = jax.jit(experts).lower(*(
+        jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+        for shape, dtype in (((pairs, d), jnp.bfloat16),
+                             ((3, 128, d, f), jnp.bfloat16),
+                             ((3, 128, d, f), jnp.bfloat16),
+                             ((3, 128, f, d), jnp.bfloat16),
+                             ((128,), jnp.int32), ((), jnp.int32)))
+    ).compile().as_text()
+    calls = [ln for ln in hlo.splitlines() if "tpu_custom_call" in ln]
+    assert len(calls) == 1 and f"f32[{pairs},{d}]" in calls[0]
+    assert "/ffn/moe_experts/grouped_product/" in calls[0]
+    assert not re.search(r"bf16\[(384|3,128),\d+,\d+\][^ ]* copy\(", hlo)

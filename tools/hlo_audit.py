@@ -24,11 +24,13 @@ lists where the KV slab is copied, transposed or padded (:func:`slab`).
 from __future__ import annotations
 
 import collections
+import functools
 import json
 import math
 import os
 import re
 import sys
+from unittest import mock
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
@@ -601,6 +603,26 @@ def _big_copies(hlo: str, at_least: int):
     return found
 
 
+def _grouped_products(hlo: str) -> dict:
+    """The Mosaic kernels of a compiled program (``tpu_custom_call``s): how
+    many are the compiler's own grouped product (named ``ragged-dot*``),
+    and every other one, a Pallas kernel of the program's, with its result
+    and the ``op_name`` a profile will show for it."""
+    compilers, ours = 0, []
+    for line in hlo.splitlines():
+        mt = re.match(r"\s*(?:ROOT )?%?([\w.\-]+) = (\w+\[[\d,]*\])"
+                      r"(?:\{[^}]*\})? custom-call\(", line)
+        if not mt or 'custom_call_target="tpu_custom_call"' not in line:
+            continue
+        if mt.group(1).startswith("ragged-dot"):
+            compilers += 1
+            continue
+        op = re.search(r'op_name="([^"]*)"', line)
+        ours.append({"name": mt.group(1), "result": mt.group(2),
+                     "op_name": op.group(1) if op else None})
+    return {"ragged_dot": compilers, "pallas": ours}
+
+
 SLAB_MODELS = {
     # the sizes of each model's serve cell (benchmark/traffic/)
     "gpt2": dict(num_slots=8, max_len=640, prefill_bucket=512),
@@ -640,7 +662,9 @@ def slab(num_slots: int = None, max_len: int = None, decode_chunk: int = 4,
     (16 slots of 2,432 rows, the 2,048 bucket). It has one slab a kind of
     cache (``slabs``: full layers at ``max_len`` rows, window layers a
     ring), each checked as above. ``big_copies`` lists every ``copy`` of
-    64 MiB or more (a weight matrix relaid before the loop), and ``ok``
+    64 MiB or more (a weight matrix relaid before the loop),
+    ``grouped_products`` the expert layer's products by what implements
+    them (the compiler's ``ragged-dot``s, Pallas custom calls), and ``ok``
     also asks that they come to under a twentieth of the arguments (at
     these sizes a copy of the weights does not fit) and that arguments
     and temporaries stay under the chip's 15.75 GiB."""
@@ -710,8 +734,13 @@ def slab(num_slots: int = None, max_len: int = None, decode_chunk: int = 4,
            "slabs": {kind: list(a.shape) for kind, a in slabs.items()},
            "programs": {}}
     violations = []
+    # this process sees the CPU, where the expert layer's tiled product
+    # would be traced as its interpreter: the chip's program has the kernel
+    from pipe_tpu.ops import moe
     for name, lower in programs.items():
-        compiled = lower().compile()
+        with mock.patch.object(moe, "grouped_gated_mlp", functools.partial(
+                moe.grouped_gated_mlp, interpret=False)):
+            compiled = lower().compile()
         hlo = compiled.as_text()
         moves, forms = {"in_loops": [], "outside_loops": []}, {}
         fat = {}
@@ -727,6 +756,7 @@ def slab(num_slots: int = None, max_len: int = None, decode_chunk: int = 4,
         out["programs"][name] = {
             "slab_moves": moves, "slab_forms_bytes": forms,
             "big_copies": _big_copies(hlo, 64 * 2**20),
+            "grouped_products": _grouped_products(hlo),
             # what a refactor compares with its parent's: equal counts
             # are the same program
             "hlo_ops": dict(sorted(collections.Counter(re.findall(
