@@ -149,9 +149,10 @@ def build_argparser() -> argparse.ArgumentParser:
                    default="fifo")
     p.add_argument("--timeout-s", type=float, default=None,
                    help="per-request deadline")
-    p.add_argument("--decode-chunk", type=int, default=4,
+    p.add_argument("--decode-chunk", type=int, default=None,
                    help="decode steps per host tick (ring: ring "
-                        "revolutions per tick)")
+                        "revolutions per tick); default 4, and 1 for a "
+                        "model that generates a block a step")
     p.add_argument("--resident", choices=["auto", "on", "off"],
                    default="auto",
                    help="the decode launch's horizon: on runs up to "
@@ -252,12 +253,15 @@ def build_argparser() -> argparse.ArgumentParser:
                         "replicas resume from shipped blocks")
     p.add_argument("--int8", action="store_true",
                    help="int8 weight-only quantized block weights")
-    p.add_argument("--family", choices=["lm", "gpt2", "laguna"],
+    p.add_argument("--family", choices=["lm", "gpt2", "laguna", "sdar"],
                    default="lm",
                    help="laguna: Laguna-S-2.1's share for one chip (5 of "
                         "48 layers, 128 of 256 experts, half the "
-                        "vocabulary; models/laguna.py): --stages 1, --kv "
-                        "slab, no --spec-tokens")
+                        "vocabulary; models/laguna.py); sdar: "
+                        "SDAR-30B-A3B-Chat's first pipeline stage (6 of 48 "
+                        "layers, each whole; models/sdar.py), generated by "
+                        "diffusion over blocks, --decode-chunk 1. Both: "
+                        "--stages 1, --kv slab, no --spec-tokens")
     p.add_argument("--tiny", action="store_true")
     p.add_argument("--cpu", type=int, default=0,
                    help="force N virtual CPU devices (testing without TPU)")
@@ -283,6 +287,9 @@ def main(argv=None) -> int:
     elif args.family == "laguna":
         from ..models.laguna import LagunaConfig as _Cfg
         from ..models.laguna import PipelinedLaguna as _Model
+    elif args.family == "sdar":
+        from ..models.sdar import PipelinedSdar as _Model
+        from ..models.sdar import SdarConfig as _Cfg
     else:
         from ..models.transformer_lm import LMConfig as _Cfg
         from ..models.transformer_lm import PipelinedLM as _Model
@@ -290,6 +297,10 @@ def main(argv=None) -> int:
     model_cfg = _Cfg()
     if args.tiny:
         model_cfg = model_cfg.tiny()
+    if args.decode_chunk is None:
+        # a round of a model that generates by blocks is one block
+        args.decode_chunk = 1 if getattr(model_cfg, "generation", None) \
+            else 4
     n_stages = max(args.stages, 1)
     # Pipeline-prefix drafts run "the first stage(s)", so the model must
     # be partitioned with a strict prefix to carve. The ring already is;

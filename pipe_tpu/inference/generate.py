@@ -37,7 +37,8 @@ from ..obs.telemetry import get_registry
 from .quant import QuantLeaf, dequant_tree
 
 __all__ = ["GenerationConfig", "Generator", "check_positions",
-           "head_logits", "sample_logits", "sequence_lengths"]
+           "head_logits", "most_confident", "sample_logits",
+           "sample_with_confidence", "sequence_lengths"]
 
 
 @dataclasses.dataclass(frozen=True)
@@ -197,18 +198,57 @@ def head_logits(model, post_params, h: jax.Array) -> jax.Array:
                             h.astype(jnp.float32))
 
 
+def _shaped_logits(logits: jax.Array, cfg: GenerationConfig) -> jax.Array:
+    """The logits a token is drawn from: over the temperature, the top-k
+    kept."""
+    logits = logits / cfg.temperature
+    if cfg.top_k is not None:
+        kth = jax.lax.top_k(logits, cfg.top_k)[0][..., -1:]
+        logits = jnp.where(logits >= kth, logits,
+                           jnp.asarray(-1e30, logits.dtype))
+    return logits
+
+
 def sample_logits(logits: jax.Array, key: jax.Array,
                   cfg: GenerationConfig) -> jax.Array:
     """Next-token ids ``[b]`` from ``logits [b, vocab]`` (float32 math)."""
     logits = logits.astype(jnp.float32)
     if cfg.temperature == 0.0:
         return jnp.argmax(logits, axis=-1).astype(jnp.int32)
-    logits = logits / cfg.temperature
-    if cfg.top_k is not None:
-        kth = jax.lax.top_k(logits, cfg.top_k)[0][..., -1:]
-        logits = jnp.where(logits >= kth, logits,
-                           jnp.asarray(-1e30, logits.dtype))
-    return jax.random.categorical(key, logits, axis=-1).astype(jnp.int32)
+    return jax.random.categorical(key, _shaped_logits(logits, cfg),
+                                  axis=-1).astype(jnp.int32)
+
+
+def sample_with_confidence(logits: jax.Array, keys: Optional[jax.Array],
+                           cfg: GenerationConfig):
+    """``(ids [...], confidence [...])`` from ``logits [..., vocab]``, for
+    a generation that picks positions by how sure the model is: greedy,
+    the token put first and its probability (``max softmax(logits)``);
+    with a temperature, a token drawn with ``keys [...]`` (one a row) as
+    :func:`sample_logits` draws it, and its probability under the
+    distribution it was drawn from. Float32."""
+    logits = logits.astype(jnp.float32)
+    if cfg.temperature == 0.0:
+        ids = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+    else:
+        logits = _shaped_logits(logits, cfg)
+        flat = logits.reshape((-1, logits.shape[-1]))
+        ids = jax.vmap(lambda k, row: jax.random.categorical(k, row))(
+            keys.reshape((-1,)), flat).reshape(logits.shape[:-1]).astype(
+                jnp.int32)
+    got = jnp.take_along_axis(logits, ids[..., None], axis=-1)[..., 0]
+    return ids, jnp.exp(got - jax.nn.logsumexp(logits, axis=-1))
+
+
+def most_confident(confidence: jax.Array, candidates: jax.Array,
+                   n: jax.Array) -> jax.Array:
+    """Of each row's ``candidates [rows, L]`` (bool) the ``n [rows]`` of
+    largest ``confidence [rows, L]``, as a mask; a tie goes to the earlier
+    position. Fewer candidates than ``n``: all of them."""
+    score = jnp.where(candidates, confidence, -1.0)
+    order = jnp.argsort(-score, axis=-1, stable=True)
+    rank = jnp.argsort(order, axis=-1)
+    return candidates & (rank < n[:, None])
 
 
 def sequence_lengths(tokens: jax.Array,

@@ -48,13 +48,16 @@ def per_row_ce(logits, targets, weights=None):
 
 def refuse_grouped(model, who: str) -> None:
     """One sentence from a path that decodes ONE stacked block over one
-    kind of cache: a model whose layers come in groups of unlike blocks
-    (it has ``layer_groups``) does not run there."""
+    kind of cache, a token a step: a model that hands its layers over in
+    groups (it has ``layer_groups``) does not run there. The model says
+    why it is grouped (``grouped_because``)."""
     if getattr(model, "layer_groups", None) is not None:
+        why = getattr(model, "grouped_because",
+                      "has layers of unlike kinds over two kinds of cache")
         raise NotImplementedError(
-            f"{type(model).__name__} has layers of unlike kinds over two "
-            f"kinds of cache, and {who} decodes one stacked block over "
-            f"one: serve it with SingleDeviceSlotBackend's slab cache")
+            f"{type(model).__name__} {why}, and {who} decodes one stacked "
+            f"block over one cache, a token a step: serve it with "
+            f"SingleDeviceSlotBackend's slab cache")
 
 
 class PipelinedTransformer:

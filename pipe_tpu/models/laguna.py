@@ -48,7 +48,7 @@ from ..ops.moe import DROPLESS_COUNTS, dropless_moe, dropless_moe_init
 from .common import PipelinedTransformer
 
 __all__ = ["LagunaConfig", "LagunaBlock", "LayerGroup", "PipelinedLaguna",
-           "LAYER_COUNTS"]
+           "LAYER_COUNTS", "stacked_layer"]
 
 # what a layer's decode or prefill counts, in the order of its third
 # result: the expert layer's three and whether the layer has experts
@@ -113,6 +113,27 @@ class LayerGroup(NamedTuple):
     first: int
 
 
+def stacked_layer(params, at):
+    """Layer ``at`` of a group's stacked parameters: every leaf sliced,
+    but the routed experts' tensors. Those stay as they lie, ``[layers,
+    held, ...]``, for the grouped product to index: a slice of one (0.8
+    GB) would be copied out for the kernel at every step. ``at`` None:
+    ``params`` is one layer's already."""
+    if at is None:
+        return params
+
+    def pick(tree):
+        return jax.tree_util.tree_map(
+            lambda a: jax.lax.dynamic_index_in_dim(a, at, 0,
+                                                   keepdims=False), tree)
+
+    out = {k: pick(v) for k, v in params.items() if k != "moe"}
+    if "moe" in params:
+        out["moe"] = dict(params["moe"],
+                          router=pick(params["moe"]["router"]))
+    return out
+
+
 class LagunaBlock(Module):
     """One layer: RMSNorm, grouped-query attention with rotary positions
     and a per-head gate (full, or a window), residual; RMSNorm,
@@ -152,26 +173,6 @@ class LagunaBlock(Module):
                 cfg.experts_held[1], dtype=cfg.compute_dtype)
         return params
 
-    def _at(self, params, at):
-        """Layer ``at`` of a group's stacked parameters: every leaf
-        sliced, but the routed experts' tensors. Those stay as they lie,
-        ``[layers, held, ...]``, for the grouped product to index: a
-        slice of one (0.8 GB) would be copied out for the kernel at every
-        step."""
-        if at is None:
-            return params
-
-        def pick(tree):
-            return jax.tree_util.tree_map(
-                lambda a: jax.lax.dynamic_index_in_dim(a, at, 0,
-                                                       keepdims=False), tree)
-
-        out = {k: pick(v) for k, v in params.items() if k != "moe"}
-        if "moe" in params:
-            out["moe"] = dict(params["moe"],
-                              router=pick(params["moe"]["router"]))
-        return out
-
     def _layer(self, params, x, attend, live=None, at=None):
         """``attend(attention's parameters, normed x) -> (its output, what
         it gives back)``; ``live [b | 1, q | 1]``: the rows whose experts'
@@ -179,7 +180,7 @@ class LagunaBlock(Module):
         this the layer's index in it. Returns ``(x, what attend gave back,
         counts)``, the counts in :data:`LAYER_COUNTS`' order."""
         cfg = self.cfg
-        params = self._at(params, at)
+        params = stacked_layer(params, at)
         with device_scope(ATTENTION), device_scope(
                 ATTN_FULL if self.attention == "full" else ATTN_WINDOW):
             a, back = attend(params["attn"],

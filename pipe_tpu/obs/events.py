@@ -134,7 +134,15 @@ MOE_EXPERTS = "moe_experts"
 MOE_SHARED = "moe_shared"
 ATTN_WINDOW = "attn_window"
 ATTN_FULL = "attn_full"
-MODEL_SCOPES = (MOE_ROUTER, MOE_EXPERTS, MOE_SHARED, ATTN_WINDOW, ATTN_FULL)
+# Generation by diffusion over blocks (the serve engine's block round):
+# around the layer loop of a denoise pass and of the commit pass, outside
+# every layer's own scopes, and inside ``head`` around what picks the
+# positions a pass reveals (softmax, confidence, the reveal).
+DIFFUSION_DENOISE = "diffusion_denoise"
+DIFFUSION_COMMIT = "diffusion_commit"
+DIFFUSION_SELECT = "diffusion_select"
+MODEL_SCOPES = (MOE_ROUTER, MOE_EXPERTS, MOE_SHARED, ATTN_WINDOW, ATTN_FULL,
+                DIFFUSION_DENOISE, DIFFUSION_COMMIT, DIFFUSION_SELECT)
 # Not a layer but a mark that cuts across them: a forward that runs again
 # for its backward. ``jax.checkpoint`` writes this name itself; the
 # scheduled executor's manual re-forward opens a scope of the same name.

@@ -338,7 +338,8 @@ def apply_rope(x, positions, inv_freq, scale: float = 1.0):
 
 
 def blocked_causal_attention(q, k, v, *, window: Optional[int] = None,
-                             q_block: int = 256):
+                             q_block: int = 256,
+                             block: Optional[int] = None):
     """Causal softmax attention over a whole sequence from position 0, a
     block of ``q_block`` queries at a time, so that no score matrix of the
     whole sequence exists: ``q [b, s, H, D]``, ``k``/``v`` ``[b, s, Hkv,
@@ -346,7 +347,10 @@ def blocked_causal_attention(q, k, v, *, window: Optional[int] = None,
     // (H / Hkv)``) -> ``[b, s, H, D]``. Position ``j`` is visible from
     ``i`` iff ``j <= i`` and, with ``window``, ``j > i - window``; a
     windowed block reads its own rows and the ``window`` before them, a
-    full one every row. Scores and softmax in float32. Plain
+    full one every row. With ``block`` (and no window) visibility is
+    BLOCK-causal: ``j // block <= i // block``, every
+    position of a block of ``block`` sees the whole of it. Scores and
+    softmax in float32. Plain
     ``jax.numpy``: the flash kernel has neither groups nor a window."""
     b, s, h, d = q.shape
     hkv = k.shape[2]
@@ -354,6 +358,9 @@ def blocked_causal_attention(q, k, v, *, window: Optional[int] = None,
     if s % qb or h % hkv:
         raise ValueError(f"{s} rows in blocks of {qb}, {h} heads over "
                          f"{hkv}: neither may leave a remainder")
+    if block is not None and window is not None:
+        raise ValueError(f"block-causal attention in blocks of {block} "
+                         f"takes no window")
     qg = q.reshape(b, s // qb, qb, hkv, h // hkv, d)
     if window is None or window >= s:
         span, front = s, 0
@@ -370,7 +377,10 @@ def blocked_causal_attention(q, k, v, *, window: Optional[int] = None,
         qpos = i * qb + jnp.arange(qb)
         logits = jnp.einsum("bqkgd,btkd->bkgqt", qg[:, i], ks).astype(
             jnp.float32) / math.sqrt(d)
-        seen = (kpos[None, :] <= qpos[:, None]) & (kpos[None, :] >= 0)
+        if block is None:
+            seen = (kpos[None, :] <= qpos[:, None]) & (kpos[None, :] >= 0)
+        else:
+            seen = kpos[None, :] // block <= qpos[:, None] // block
         if window is not None:
             seen = seen & (kpos[None, :] > qpos[:, None] - window)
         w = jax.nn.softmax(jnp.where(seen, logits, jnp.float32(-1e30)),
@@ -395,14 +405,21 @@ class MultiHeadAttention(Module):
     table), ``window`` (position ``j`` visible from ``i`` only if ``j > i
     - window``; the slab form's cache is then a ring of ``window`` rows),
     ``gate`` (a sigmoid of a bias-free linear map of the layer's input,
-    one scalar a query head, times that head's output before ``wo``)."""
+    one scalar a query head, times that head's output before ``wo``),
+    ``qk_norm`` (its eps: an RMSNorm with a gain of ``head_dim``, ``gq``
+    and ``gk``, on each head's query and key before the rotary positions),
+    ``block`` (BLOCK-causal visibility in the whole-sequence forward:
+    ``j`` visible from ``i`` iff ``j // block <= i // block``; ``decode``
+    takes a block's rows at a block-aligned ``pos`` with ``tree=`` all
+    ones, which is that mask over a cache of whole earlier blocks)."""
 
     def __init__(self, d_model: int, nhead: int, dropout: float = 0.0,
                  causal: bool = True, dtype=jnp.float32, name: str = "mha",
                  impl: str = "auto", *, kv_heads: Optional[int] = None,
                  head_dim: Optional[int] = None, bias: bool = True,
                  rope: Optional[dict] = None, window: Optional[int] = None,
-                 gate: bool = False):
+                 gate: bool = False, qk_norm: Optional[float] = None,
+                 block: Optional[int] = None):
         if head_dim is None and d_model % nhead:
             raise ValueError("nhead must divide d_model")
         if impl not in ("auto", "xla", "flash"):
@@ -420,9 +437,12 @@ class MultiHeadAttention(Module):
                      else rope_frequencies(self.head_dim, **rope))
         self.window = window
         self.gate = gate
+        self.qk_norm = qk_norm
+        self.block = block
         # what the flash kernel and the dropout path do not know
         self.plain = (self.group == 1 and window is None and not gate
-                      and rope is None)
+                      and rope is None and qk_norm is None
+                      and block is None)
         self.dropout = dropout
         self.causal = causal
         self.dtype = dtype
@@ -450,6 +470,9 @@ class MultiHeadAttention(Module):
                 bo=jnp.zeros((d,), self.dtype))
         if self.gate:
             params["wg"] = mat(keys[4], (d, self.nhead))
+        if self.qk_norm is not None:
+            params.update(gq=jnp.ones((hd,), jnp.float32),
+                          gk=jnp.ones((hd,), jnp.float32))
         return params
 
     def _qkv(self, params, x, positions):
@@ -467,6 +490,10 @@ class MultiHeadAttention(Module):
         qh = proj("wq", "bq", self.nhead)
         kh = proj("wk", "bk", self.kv_heads)
         vh = proj("wv", "bv", self.kv_heads)
+        if self.qk_norm is not None:
+            norm = RMSNorm(self.qk_norm)
+            qh = norm.apply({"g": params["gq"]}, qh)
+            kh = norm.apply({"g": params["gk"]}, kh)
         if self.rope is not None:
             qh = apply_rope(qh, positions, *self.rope)
             kh = apply_rope(kh, positions, *self.rope)
@@ -492,7 +519,8 @@ class MultiHeadAttention(Module):
         (:func:`blocked_causal_attention`): ``(out [b, s, d], {"k", "v"} [b,
         s, Hkv, D])``, the rows as a cache keeps them."""
         qh, kh, vh = self._qkv(params, x, jnp.arange(x.shape[1])[None])
-        o = blocked_causal_attention(qh, kh, vh, window=self.window)
+        o = blocked_causal_attention(qh, kh, vh, window=self.window,
+                                     block=self.block)
         return self._out(params, x, o), {"k": kh, "v": vh}
 
     def apply(self, params, x, ctx: StageCtx = StageCtx()):
@@ -500,8 +528,8 @@ class MultiHeadAttention(Module):
         if not self.plain:
             if not self.causal or (self.dropout > 0.0 and ctx.train):
                 raise ValueError(
-                    "grouped, windowed, gated or rotary attention runs "
-                    "causal and without dropout")
+                    "grouped, windowed, gated, normed, block-causal or "
+                    "rotary attention runs causal and without dropout")
             return self.prefill(params, x)[0]
         q, k, v = self._qkv(params, x, None)
         dk = ctx.fold(1).key if ctx.key is not None else None
@@ -641,6 +669,9 @@ class MultiHeadAttention(Module):
         """
         if not self.causal:
             raise ValueError("KV-cache decode requires causal attention")
+        if self.block is not None and tree is None:
+            raise ValueError("block-causal attention decodes a block's "
+                             "rows under tree= (all ones), not a causal run")
         b, q, _ = x.shape
         hd, grp, win = self.head_dim, self.group, self.window
         # a scalar pos is every row's; the slab form's is one per row

@@ -294,7 +294,13 @@ def dropless_moe_init(key: jax.Array, d_model: int, d_ff: int,
 # 160 pairs (a decode step of 16 slots, 1.25 an expert) 1.415 | 1.418, the
 # whole layer 1.449 | 1.463; 640 pairs (5 an expert) 3.66 | 2.98;
 # 1,280 4.85 | 3.23; 2,560 7.74 | 3.31; 5,120 8.00 | 3.48; 10,240 8.41 |
-# 3.80; 20,480 9.55 | 4.44. The two cross between 1.25 and 5.
+# 3.80; 20,480 9.55 | 4.44. The two cross between 1.25 and 5. At hidden
+# 2048, width 768, all 128 experts held, top-8 (PR 34: SDAR's block round
+# takes the tiled product AT DECODE, 8 real rows in a 128-row tile): 256
+# pairs (2 an expert) 2.634 | 1.448; 512 4.447 | 1.686; 1,024 (a pass of 32
+# slots, 8 an expert) 4.471 | 1.719; 2,048 4.645 | 1.844, the two bit for
+# bit alike. There the tiled one wins from 2 an expert down; nothing runs
+# between 1.25 and 4, so the rule stands and both models stay on their sides.
 _TILED_FROM = 4
 
 

@@ -70,7 +70,8 @@ import numpy as np
 
 from ..inference.draft import DraftSource, resolve_draft, tree_layout
 from ..inference.generate import (GenerationConfig, head_logits,
-                                  sample_logits)
+                                  most_confident, sample_logits,
+                                  sample_with_confidence)
 from ..inference.quant import QuantLeaf, dequant_tree
 from ..models.common import refuse_grouped
 from ..obs import events as ev
@@ -88,6 +89,13 @@ __all__ = ["SingleDeviceSlotBackend", "ServeEngine", "EngineDraining"]
 
 # rows of a grouped model's carried ``counts``: who counted
 COUNT_PREFILL, COUNT_DECODE = 0, 1
+# what the block round counts, behind the layers' and the caches' counts:
+# blocks and passes a LIVE slot went through (a pass counts once a slot),
+# positions revealed (the prompt's tail not counted), and those of them
+# behind the reply's asked length, which the host drops
+BLOCK_COUNTS = ("diffusion.blocks", "diffusion.denoise_passes",
+                "diffusion.commit_passes", "diffusion.tokens",
+                "diffusion.cut_tokens")
 
 
 class EngineDraining(RuntimeError):
@@ -99,15 +107,20 @@ class EngineDraining(RuntimeError):
 class _Slot:
     """Host-side state of one running request."""
 
-    __slots__ = ("req", "tokens", "ttft", "admitted_tick")
+    __slots__ = ("req", "tokens", "notes", "ttft", "admitted_tick")
 
     def __init__(self, req: Request, first_token, admitted_tick: int = 0):
         self.req = req
         # the first token holds its place from admission on, as the
         # backend's prefill returned it (an int, or what int() waits on
         # the device for); ServeEngine._land_first_tokens puts the int
-        # there and stamps ttft, within the tick of the admission
-        self.tokens: List[int] = [first_token]
+        # there and stamps ttft, within the tick of the admission. None:
+        # the prefill yields no token (a block round); the first tokens
+        # come with the first launch, and ttft is stamped there
+        self.tokens: List[int] = [] if first_token is None else [first_token]
+        # what a noted round says of each token (the block round: the
+        # denoise pass that revealed it)
+        self.notes: List[int] = []
         self.ttft: Optional[float] = None
         self.admitted_tick = admitted_tick
 
@@ -115,14 +128,25 @@ class _Slot:
 class _Round(NamedTuple):
     """Seam B of the decode program: what one loop iteration emits.
     ``run(block_stack, pre, post, slots) -> slots, toks [S, width],
-    n_emit [S]`` over ``slots = (rows, tok, pos, key_data, hist, done,
-    budget)``, writing ``rows`` cache rows a slot. ``counted``: ``n_emit``
-    varies (the accepted length) and the loop records it; otherwise it
-    is None, and every live slot emits ``width``."""
+    n_emit [S], notes [S, width]`` over ``slots = (rows, tok, pos,
+    key_data, hist, done, budget)``, writing ``rows`` cache rows a slot.
+    ``tok`` and ``hist`` are the round's own: one token a slot and the
+    draft history (None where nothing drafts), or, for the block round,
+    a block of tokens ``[S, L]`` and which of them are still masked.
+    ``counted``: ``n_emit`` varies (the accepted length; a reply's last
+    block cut) and the loop records it; otherwise it is None, and every
+    live slot emits ``width``. ``noted``: the round says one number of
+    each token it emits (the denoise pass that revealed it) and the loop
+    keeps them beside the tokens; otherwise ``notes`` is None.
+    ``opens_on_token``: a slot's ``tok`` at a launch's start is the last
+    token of its reply, so an eos there starts the slot done; False where
+    ``tok`` is the round's own state (a block under denoising)."""
     run: Callable
     width: int
     rows: int
     counted: bool
+    noted: bool = False
+    opens_on_token: bool = True
 
 
 class _SlabStore:
@@ -295,10 +319,32 @@ class SingleDeviceSlotBackend:
             raise ValueError(
                 f"spec_tokens must be >= 2, got {spec}")
         self.spec_tokens = spec
+        # how the model generates: a token a step unless it declares
+        # otherwise. ``("block_diffusion", L, T, mask)``: a block of L
+        # positions a step, through T denoise passes and a commit pass
+        # (:meth:`_block_round`); the slots' ``tok`` is then the block
+        # ``[S, L]`` and their ``hist`` seat holds ``masked [S, L]``
+        how = getattr(model, "generation", None)
+        if how is not None and how[0] != "block_diffusion":
+            raise ValueError(f"{type(model).__name__} generates by "
+                             f"{how[0]!r}: no round of the decode program")
+        self._block = None if how is None else tuple(how[1:])
         # tokens per round: the readout stride of the token buffer the
         # decode program returns. Spec mode re-sets this per launch to
         # the adaptive ladder rung that ran.
         self.decode_width = spec if spec is not None else decode_chunk
+        # forward passes a round, where that is not its width
+        self.round_passes = None
+        if self._block is not None:
+            if decode_chunk != 1:
+                raise ValueError(
+                    "a block round is one block of the model's own length "
+                    f"(decode_chunk must be 1, got {decode_chunk})")
+            # the last block of a reply is written whole
+            self.max_len = max_len = -(-max_len // self._block[0]) \
+                * self._block[0]
+            self.decode_width = self._block[0]
+            self.round_passes = self._block[1] + 1
 
         stage_params, pre_params, post_params = params
         cd = model.cfg.compute_dtype
@@ -329,6 +375,11 @@ class SingleDeviceSlotBackend:
                 refuse_grouped(model, "_PoolStore (the paged KV pool)")
             if spec is not None:
                 refuse_grouped(model, "_spec_round (speculative decoding)")
+        if self._block is not None and self._groups is None:
+            raise TypeError(
+                f"{type(model).__name__} generates by diffusion over "
+                "blocks: the block round keeps its counts with a grouped "
+                "model's (layer_groups)")
         self._pre = pre_params
         self._post = post_params
 
@@ -440,6 +491,9 @@ class SingleDeviceSlotBackend:
             else:
                 self._caches = self._make_group_slabs(cd)
         self._tok = jnp.zeros((num_slots,), jnp.int32)
+        if self._block is not None:
+            self._tok = jnp.full((num_slots, self._block[0]),
+                                 self._block[2], jnp.int32)
         self._pos = jnp.zeros((num_slots,), jnp.int32)
         kd0 = jax.random.key_data(jax.random.key(0))
         self._key_data = jnp.broadcast_to(kd0, (num_slots,) + kd0.shape)
@@ -450,13 +504,21 @@ class SingleDeviceSlotBackend:
         # last position. None where no round drafts.
         self._hist = None if spec is None else jnp.full(
             (num_slots, max_len + spec), gen.pad_token_id, jnp.int32)
+        if self._block is not None:     # the block round's seat: masked
+            self._hist = jnp.ones(self._tok.shape, jnp.bool_)
+        self.launch_notes = None
 
         # THE decode program: one jit per round width — one in all
-        # without speculation, one per ladder rung with it (every rung
+        # without speculation (the plain round, or the block round of a
+        # model that generates so), one per ladder rung with it (every rung
         # traces once, then the steady state is rung selection over
         # compiled programs). Donated: the store's KV and the history.
         self._store = _PoolStore(self) if self.paged else _SlabStore(self)
-        if spec is None:
+        if self._block is not None:
+            rounds = [_Round(self._block_round, self.decode_width,
+                             self.decode_width, counted=True, noted=True,
+                             opens_on_token=False)]
+        elif spec is None:
             rounds = [_Round(self._plain_round, decode_chunk, decode_chunk,
                              False)]
         else:
@@ -489,9 +551,14 @@ class SingleDeviceSlotBackend:
                 f"blocks but the whole pool holds "
                 f"{self.pool.allocatable}; raise kv_pool_blocks or "
                 f"shorten the request")
-        if prompt_len + max_new_tokens + self._spec_overshoot > self.max_len:
+        rows = prompt_len + max_new_tokens + self._spec_overshoot
+        if self._block is not None:      # the last block is written whole
+            rows = -(-rows // self._block[0]) * self._block[0]
+        if rows > self.max_len:
             extra = (f" + speculative headroom {self._spec_overshoot}"
                      if self._spec_overshoot else "")
+            if self._block is not None:
+                extra = f", in whole blocks of {self._block[0]},"
             raise ValueError(
                 f"prompt_len {prompt_len} + max_new_tokens "
                 f"{max_new_tokens}{extra} exceeds the slot cache "
@@ -571,7 +638,7 @@ class SingleDeviceSlotBackend:
         window's), and ``counts [2, n]``: what the layers and the decode
         step count (``self._count_names``), row :data:`COUNT_PREFILL`
         by the prefill programs and row :data:`COUNT_DECODE` by the
-        decode program, summed on the device for as long as the backend
+        decode program (with a block round, :data:`BLOCK_COUNTS` too), summed on the device for as long as the backend
         lives (int32, wrapping: the host takes differences)."""
         kinds = {}
         for g in self._groups:
@@ -584,6 +651,8 @@ class SingleDeviceSlotBackend:
         self._cache_kinds = tuple(slabs)
         self._count_names = self._layer_counts + tuple(
             f"cache.{kind}_rows_read" for kind in slabs)
+        if self._block is not None:
+            self._count_names += BLOCK_COUNTS
         self._counts_seen = np.zeros((2, len(self._count_names)), np.uint32)
         self.launch_counts = {}
         return dict(slabs, counts=jnp.zeros(self._counts_seen.shape,
@@ -616,15 +685,16 @@ class SingleDeviceSlotBackend:
         caches["counts"] = caches["counts"].at[row, :n_counts].add(total)
         return h, caches, rows
 
-    def _count_rows_read(self, caches, pos, live):
+    def _count_rows_read(self, caches, pos, live, q: int = 1):
         """The cache rows a decode step's live slots read, by kind of
         cache (a ring's stop growing at its length), into the carried
-        counts."""
+        counts; ``q``: the rows a slot's step writes and then reads (a
+        block's)."""
         counts = caches["counts"]
         for j, kind in enumerate(self._cache_kinds,
                                  start=len(self._layer_counts)):
             layers, _, length, _ = caches[kind]["k"].shape
-            rows = jnp.sum(jnp.where(live, jnp.minimum(pos + 1, length), 0))
+            rows = jnp.sum(jnp.where(live, jnp.minimum(pos + q, length), 0))
             counts = counts.at[COUNT_DECODE, j].add(rows * layers)
         return dict(caches, counts=counts)
 
@@ -672,6 +742,27 @@ class SingleDeviceSlotBackend:
                 put(pos, jnp.asarray(true_len, pos.dtype), slot, 0),
                 put(key_data, jax.random.key_data(key), slot, 0), hist)
 
+    def _arm_block(self, state, prompt, true_len, slot, seed):
+        """Traced, the last lines of a block-round admission's program:
+        the slot's block is the prompt's tail (its ``true_len % L``
+        tokens behind the last whole block, revealed) and the mask token
+        behind it, its position the first row no whole block filled, its
+        key the request's."""
+        tok, pos, key_data, masked = state
+        L, _, mask_id = self._block
+        put = jax.lax.dynamic_update_index_in_dim
+        whole = true_len - true_len % L
+        # padded, so that the slice is never clamped back into the prompt
+        tail = jax.lax.dynamic_slice(
+            jnp.pad(prompt[0], (0, L)), (whole,), (L,))
+        hidden = jnp.arange(L, dtype=jnp.int32) >= true_len - whole
+        return (put(tok, jnp.where(hidden, jnp.int32(mask_id), tail), slot,
+                    0),
+                put(pos, jnp.asarray(whole, pos.dtype), slot, 0),
+                put(key_data, jax.random.key_data(jax.random.key(seed)),
+                    slot, 0),
+                put(masked, hidden, slot, 0))
+
     def _prefill_fn(self, block_stack, pre, post, caches, state, prompt,
                     true_len, slot, seed, row):
         """One bucket-length-B prefill: runs the padded prompt through
@@ -686,6 +777,16 @@ class SingleDeviceSlotBackend:
         m, gen = self.model, self.gen
         cd = m.cfg.compute_dtype
         get_registry().counter("serve.engine.prefill_traces").inc()
+        if self._block is not None:
+            # a prompt's whole blocks are cached; its tail is the revealed
+            # part of the first block, which the block round runs. No
+            # head: nothing is sampled here
+            L = self._block[0]
+            _, caches = self._prefill_groups(
+                block_stack, pre, caches, prompt, true_len - true_len % L,
+                slot)
+            return caches, self._arm_block(state, prompt, true_len, slot,
+                                           seed), None
         if self._groups is not None:
             h, caches = self._prefill_groups(block_stack, pre, caches,
                                              prompt, true_len, slot)
@@ -848,17 +949,117 @@ class SingleDeviceSlotBackend:
         carry, toks = jax.lax.scan(
             lambda c, _: self._resident_step(block_stack, pre, post, c),
             carry, None, length=self.decode_chunk)
-        return carry, jnp.moveaxis(toks, 0, 1), None
+        return carry, jnp.moveaxis(toks, 0, 1), None, None
+
+    def _block_round(self, block_stack, pre, post, carry):
+        """Seam B, the block round: one block of ``L`` positions for all S
+        slots. Counted (a reply ends inside its last block: the tokens
+        behind its budget are not valid) and noted (the pass that revealed
+        each token).
+        A slot's state is its block's tokens ``tok [S, L]`` and which of
+        them are still ``masked [S, L]`` (never inferred from a token id),
+        at a block-aligned ``pos``. ``T`` DENOISE passes: the block's rows
+        at their positions through every layer, over the cache of all
+        earlier blocks and over each other (the ``q = L`` form of
+        :meth:`_run_layers` under an all-ones within-chunk mask), the
+        head, and at each masked position the token ``x_p`` put first (or
+        sampled on the slot's key chain) with its probability ``c_p``;
+        the ``ceil(masked / passes left)`` masked positions of largest
+        ``c_p`` are revealed. A pass of a slot that has nothing masked
+        left changes nothing and is not counted. Then one COMMIT pass of
+        the finished block, whose keys and values are what the cache
+        keeps (every pass writes rows ``pos .. pos + L - 1``, the last
+        writer wins, and no read sees an earlier pass's: the speculative
+        round's "rollback is free"); no head. The positions that were
+        masked at the block's start are its tokens (all ``L`` but for a
+        prompt's tail in a request's first block), emitted left-aligned
+        with the pass that revealed each; those behind the slot's budget
+        are the host's to drop."""
+        m, gen = self.model, self.gen
+        L, T, mask_id = self._block
+        eos = gen.eos_token_id
+        caches, tok, pos, key_data, masked, done, budget = carry
+        ones = np.ones((L, L), bool)
+        ar = jnp.arange(L, dtype=jnp.int32)
+        n_gen = jnp.sum(masked.astype(jnp.int32), axis=1)      # [S]
+        base = len(self._count_names) - len(BLOCK_COUNTS)
+
+        def count(caches, *, blocks=0, denoise=0, commit=0, tokens=0, cut=0):
+            """Into the carried counts, in :data:`BLOCK_COUNTS`' order."""
+            add = jnp.stack([jnp.asarray(v, jnp.int32) for v in
+                             (blocks, denoise, commit, tokens, cut)])
+            return dict(caches, counts=caches["counts"].at[
+                COUNT_DECODE, base:].add(add))
+
+        def layers(tok, caches, work, scope):
+            h = jax.vmap(
+                lambda xs, p: m.embed_at(pre, xs[None], p)[0])(tok, pos)
+            with ev.device_scope(scope):
+                h, caches = self._run_layers(block_stack, h, caches, pos,
+                                             tree=ones, live=work)
+            return h, self._count_rows_read(caches, pos, work, q=L)
+
+        def denoise(c, t):
+            caches, tok, masked, key_data, note = c
+            left = jnp.sum(masked.astype(jnp.int32), axis=1)
+            work = ~done & (left > 0)
+            h, caches = layers(tok, caches, work, ev.DIFFUSION_DENOISE)
+            logits = head_logits(m, post, h)                   # [S, L, V]
+            with ev.device_scope(ev.HEAD), \
+                    ev.device_scope(ev.DIFFUSION_SELECT):
+                if gen.temperature == 0.0:
+                    subs = None
+                else:
+                    ks = jax.vmap(jax.random.split)(
+                        jax.random.wrap_key_data(key_data))
+                    key_data = jax.random.key_data(ks[:, 0])
+                    subs = jax.vmap(lambda k: jax.random.split(k, L))(
+                        ks[:, 1])
+                x, conf = sample_with_confidence(logits, subs, gen)
+                reveal = most_confident(conf, masked & work[:, None],
+                                        -(-left // (T - t)))
+                tok = jnp.where(reveal, x, tok)
+                masked = masked & ~reveal
+                note = jnp.where(reveal, t, note)
+            caches = count(caches, denoise=jnp.sum(work),
+                           tokens=jnp.sum(reveal))
+            return (caches, tok, masked, key_data, note), None
+
+        (caches, tok, masked, key_data, note), _ = jax.lax.scan(
+            denoise, (caches, tok, masked, key_data,
+                      jnp.zeros(tok.shape, jnp.int32)),
+            jnp.arange(T, dtype=jnp.int32))
+        _, caches = layers(tok, caches, ~done, ev.DIFFUSION_COMMIT)
+        n_emit = jnp.where(done, 0, jnp.minimum(n_gen, budget))
+        caches = count(caches, blocks=jnp.sum(~done),
+                       commit=jnp.sum(~done),
+                       cut=jnp.sum(jnp.where(done, 0, n_gen) - n_emit))
+        # the block's own tokens, left-aligned (a first block's lie
+        # behind the prompt's tail), with the pass that revealed each
+        src = (ar[None, :] + (L - n_gen)[:, None]) % L
+        emit = ar[None, :] < n_emit[:, None]
+        toks = jnp.where(emit, jnp.take_along_axis(tok, src, axis=1),
+                         jnp.int32(gen.pad_token_id))
+        note = jnp.where(emit, jnp.take_along_axis(note, src, axis=1), 0)
+        pos = jnp.where(done, pos, pos + L)
+        budget = budget - n_emit
+        done = done | (budget <= 0)
+        if eos is not None:
+            done = done | jnp.any((toks == jnp.int32(eos)) & emit, axis=1)
+        # the next block: all of it masked
+        return ((caches, jnp.full(tok.shape, jnp.int32(mask_id)), pos,
+                 key_data, jnp.ones(masked.shape, jnp.bool_), done, budget),
+                toks, n_emit, note)
 
     def _decode_program(self, store, rnd):
         """Build THE decode program: one ``lax.while_loop`` of rounds
         over the carry ``(rows, tok, pos, key_data, hist, done, budget,
-        back, buf, counts, k)``, calling at one site each the two things
-        that differ between its uses. Seam A, the cache ``store``
+        back, buf, counts, notes, k)``, calling at one site each the two
+        things that differ between its uses. Seam A, the cache ``store``
         (:class:`_SlabStore` | :class:`_PoolStore`): what stands behind
         the carried ``[L, S, T, C]`` rows. Seam B, the round ``rnd``
-        (:meth:`_plain_round` | :meth:`_spec_round`): what one iteration
-        emits. ``done`` is the per-slot eos/length mask and ``budget``
+        (:meth:`_plain_round` | :meth:`_spec_round` |
+        :meth:`_block_round`): what one iteration emits. ``done`` is the per-slot eos/length mask and ``budget``
         the per-slot remaining max_new_tokens; the loop exits early when
         any LIVE slot goes done (a slot freed: host admission can change
         the slot set) or after ``r_max`` rounds (traced, <= the static
@@ -869,9 +1070,9 @@ class SingleDeviceSlotBackend:
         break reaches them never.
 
         The program returns the store's KV, the slots' state, the token
-        buffer ``[S, R*W]``, per-round valid counts ``[S, R]`` and the
+        buffer ``[S, R*W]``, per-round valid counts ``[S, R]``, the
         round count run (the launch's ONE host sync, which sizes the
-        readout). Traced exactly once per round width — the counter
+        readout) and a noted round's notes ``[S, R*W]`` (else None). Traced exactly once per round width — the counter
         below increments at trace time only, pinning the zero-recompile
         claim (``decode_traces`` for a one-chunk horizon,
         ``resident_traces`` for a longer one)."""
@@ -887,39 +1088,45 @@ class SingleDeviceSlotBackend:
             # slots: what a round advances, (rows, tok, pos, key_data,
             # hist, done, budget)
             def body(state):
-                slots, back, buf, counts, k = state
+                slots, back, buf, counts, notes, k = state
                 pos0 = slots[2]
-                slots, toks, n_emit = rnd.run(block_stack, pre, post, slots)
+                slots, toks, n_emit, said = rnd.run(block_stack, pre, post,
+                                                    slots)
                 back = store.commit(back, slots[0], aux, pos0, rnd.rows)
                 buf = jax.lax.dynamic_update_slice(buf, toks, (0, k * W))
                 if rnd.counted:
                     counts = jax.lax.dynamic_update_slice(
                         counts, n_emit[:, None], (0, k))
-                return slots, back, buf, counts, k + 1
+                if rnd.noted:
+                    notes = jax.lax.dynamic_update_slice(notes, said,
+                                                         (0, k * W))
+                return slots, back, buf, counts, notes, k + 1
 
             def cond(state):
-                slots, _, _, _, k = state
+                slots, *_, k = state
                 return (k < r_max) & ~jnp.any(live & slots[5])
 
             # dead slots, spent budgets, and slots whose first token is
             # eos (known only here: the host reads an admission's first
             # token after this launch is in the queue) start done
             done = ~live | (budget <= 0)
-            if eos is not None:
+            if eos is not None and rnd.opens_on_token:
                 done = done | (tok == jnp.int32(eos))
             rows, back = store.enter(kv, aux)
-            slots, back, buf, counts, k = jax.lax.while_loop(cond, body, (
-                (rows, tok, pos, key_data, hist, done, budget), back,
-                jnp.full((S, R * W), jnp.int32(pad), jnp.int32),
-                jnp.zeros((S, R), jnp.int32) if rnd.counted else None,
-                jnp.int32(0)))
+            slots, back, buf, counts, notes, k = jax.lax.while_loop(
+                cond, body, (
+                    (rows, tok, pos, key_data, hist, done, budget), back,
+                    jnp.full((S, R * W), jnp.int32(pad), jnp.int32),
+                    jnp.zeros((S, R), jnp.int32) if rnd.counted else None,
+                    jnp.zeros((S, R * W), jnp.int32) if rnd.noted else None,
+                    jnp.int32(0)))
             rows, tok, pos, key_data, hist, _, _ = slots
             if not rnd.counted:     # every live slot emitted every round
                 counts = jnp.where(
                     (jnp.arange(R, dtype=jnp.int32)[None, :] < k)
                     & live[:, None], jnp.int32(W), jnp.int32(0))
             return (store.leave(rows, back), tok, pos, key_data, hist, buf,
-                    counts, k)
+                    counts, k, notes)
 
         return _resident_fn
 
@@ -1075,7 +1282,7 @@ class SingleDeviceSlotBackend:
             done = done | jnp.any(
                 (t_lin == jnp.int32(eos)) & emit_mask, axis=1)
         return ((caches, tok, pos, key_data, hist, done, budget),
-                toks_out, n_emit)
+                toks_out, n_emit, None)
 
     # -- backend API -------------------------------------------------------
 
@@ -1089,7 +1296,9 @@ class SingleDeviceSlotBackend:
         engine reads it once the tick's decode launch is in the queue).
         The slot's (token, position, key) and draft history are written
         by the program itself (:meth:`_arm`), so a ``decode`` may follow
-        at once. Slab mode: one program per prompt-length bucket. Paged
+        at once. A model that generates by blocks has no first token
+        here: its prefill caches the prompt's whole blocks, arms the slot
+        with the tail (:meth:`_arm_block`) and returns None. Slab mode: one program per prompt-length bucket. Paged
         mode: ONE chunked program regardless of length;
         ``max_new_tokens`` sizes the block reservation (defaults to the
         engine cap — full-demand reservation means no mid-decode OOM)."""
@@ -1274,8 +1483,9 @@ class SingleDeviceSlotBackend:
         rewritten at the next prefill — or, paged, lands in the
         sacrificial block. A launch in which a live slot starts done
         (its first token eos, its budget spent) runs no round and
-        returns zero columns. Without ``budgets`` the same program runs
-        one round with no budget limit. ``launched`` is called once the
+        returns zero columns. A noted round's notes of those tokens
+        stand in ``launch_notes`` ``[S, k*width]`` afterwards. Without
+        ``budgets`` the same program runs one round with no budget limit. ``launched`` is called once the
         launch is in the device's queue and before the host waits for
         it: the caller's moment for what may wait on earlier programs
         (the engine reads its admissions' first tokens there)."""
@@ -1290,22 +1500,24 @@ class SingleDeviceSlotBackend:
         if self.spec_tokens is not None:
             self.decode_width = self._pick_spec_k(live)
         with ev.span(ev.SERVE_DECODE_LAUNCH, chunks=rm):
-            kv, tok, pos, kd, hist, buf, counts, k = \
+            kv, tok, pos, kd, hist, buf, counts, k, notes = \
                 self._resident_jits[self.decode_width](
                     *self._decode_args(live_d, budget, np.int32(rm)))
             self._store.put(kv)
         self._put_slot_state((tok, pos, kd, hist))
         if launched is not None:
             launched()
+        W = self.decode_width
         with ev.span(ev.SERVE_DECODE_SYNC):
             k = int(k)                         # THE host sync
             buf = np.asarray(buf)              # then the two fetches
             counts = np.asarray(counts)
+            if notes is not None:              # a noted round's
+                self.launch_notes = np.asarray(notes)[:, :k * W]
             if self._groups is not None:       # and the layers' counts
                 self._land_counts(reg, np.asarray(self._caches["counts"]))
         if k < rm:
             reg.counter("serve.engine.device_exits").inc()
-        W = self.decode_width
         toks = buf[:, :k * W]
         counts = counts[:, :k]
         valid = (np.arange(W)[None, None, :]
@@ -1801,7 +2013,8 @@ class ServeEngine:
         resp = Response(request_id=req.id, tokens=list(st.tokens),
                         status=status, finish_reason=reason,
                         prompt_len=len(req.prompt), ttft=st.ttft,
-                        latency=now - req.submitted_at)
+                        latency=now - req.submitted_at,
+                        reveal_pass=list(st.notes) if st.notes else None)
         self._record(resp, bucket, req)
         return resp
 
@@ -1949,8 +2162,10 @@ class ServeEngine:
                 if isinstance(tok0, (int, np.integer)):
                     # a token that has already arrived
                     self._land_first_tokens([slot], finished)
-                else:
+                elif tok0 is not None:
                     pending.append(slot)
+                # None, a block round: the first tokens, and TTFT, come
+                # with the slot's first launch
 
         # 3) decode — one launch for every slot, under the slots' token
         # budgets and the deadline horizon. A failure is
@@ -2003,6 +2218,7 @@ class ServeEngine:
                     else 0.8 * self._chunk_ewma + 0.2 * per
                 emitted = 0
                 n_before = len(finished)
+                notes = getattr(self.backend, "launch_notes", None)
                 with self.events.span(ev.SERVE_RETIRE) as retire:
                     for slot in range(self.backend.num_slots):
                         st = self._slots[slot]
@@ -2011,8 +2227,12 @@ class ServeEngine:
                         for k in range(toks.shape[1]):
                             if not valid[slot, k]:
                                 continue
+                            if not st.tokens:    # a block round's first
+                                self._first_token_landed(slot, st, t1)
                             t = int(toks[slot, k])
                             st.tokens.append(t)
+                            if notes is not None:
+                                st.notes.append(int(notes[slot, k]))
                             emitted += 1
                             if eos is not None and t == eos:
                                 finished.append(
@@ -2024,8 +2244,13 @@ class ServeEngine:
                                 break
                     retire.set_metadata(finished=len(finished) - n_before)
                 # the launch's counts, known only now; the counters say
-                # the same to an operator without a profiler
+                # the same to an operator without a profiler. A step is a
+                # forward pass of every slot: a token column, or a round's
+                # passes where the backend says a round is not its width
                 steps = int(toks.shape[1])
+                passes = getattr(self.backend, "round_passes", None)
+                if passes is not None:
+                    steps = steps // max(1, width) * passes
                 with self.events.span(
                         ev.SERVE_DECODE_DONE, steps=steps, chunks=chunks,
                         live=n_live, rows=rows, emitted=emitted,
@@ -2092,20 +2317,30 @@ class ServeEngine:
                 finished.append(self._fail_queued(req, e, self.clock()))
                 continue
             t_first = self.clock()
-            st.ttft = t_first - req.submitted_at
-            reg.counter("serve.engine.admitted").inc()
+            self._first_token_landed(slot, st, t_first)
             if overlapped:
                 reg.counter("serve.engine.first_tokens_overlapped").inc()
-            reg.histogram("serve.engine.ttft_sec").observe(st.ttft)
-            self.events.event(REQUEST, request=req.id,
-                              stage="prefill", trace=req.trace_id,
-                              slot=slot, ttft=st.ttft,
-                              attempts=req.attempts,
-                              prompt_len=len(req.prompt))
             if eos is not None and tok0 == eos:
                 finished.append(self._retire(slot, "ok", "eos", t_first))
             elif req.max_new_tokens == 1:
                 finished.append(self._retire(slot, "ok", "length", t_first))
+
+    def _first_token_landed(self, slot: int, st: _Slot,
+                            t_first: float) -> None:
+        """The TTFT moment of the request in ``slot``: its first token is
+        on the host (a prefill's, or with the first block of a block
+        round the whole block's). ``ttft`` is stamped and the request's
+        prefill record written."""
+        reg = get_registry()
+        req = st.req
+        st.ttft = t_first - req.submitted_at
+        reg.counter("serve.engine.admitted").inc()
+        reg.histogram("serve.engine.ttft_sec").observe(st.ttft)
+        self.events.event(REQUEST, request=req.id,
+                          stage="prefill", trace=req.trace_id,
+                          slot=slot, ttft=st.ttft,
+                          attempts=req.attempts,
+                          prompt_len=len(req.prompt))
 
     def _resident_horizon(self, now: float) -> int:
         """How many chunks the device may run before host attention

@@ -97,7 +97,10 @@ class Response:
     serve again). ``tokens`` holds whatever was generated
     before the request finished (possibly empty when it never reached a
     slot). ``ttft`` is first-token latency (None when no token was
-    produced); ``latency`` is submit-to-retire."""
+    produced; for a model that generates by blocks, the landing of the
+    first block); ``latency`` is submit-to-retire. ``reveal_pass``, for a
+    request generated by diffusion over blocks: the denoise pass of its
+    block (0 .. T - 1) that revealed each token; None otherwise."""
 
     request_id: int
     tokens: List[int]
@@ -106,6 +109,7 @@ class Response:
     prompt_len: int
     ttft: Optional[float]
     latency: float
+    reveal_pass: Optional[List[int]] = None
 
 
 class RequestQueue:

@@ -13,7 +13,7 @@ over a 512 and a 2048 bucket's pairs at other row tiles. Device times from a
 profiler capture (the benchmark's reader), a run's whole program and the
 product kernels inside it; the kernels' `op_name`, and whether the accepted
 readers count them under `moe_experts`. Needs the chip; one JSON line, and
-`chiprun_out/grouped_product_bench.json`.
+`chiprun_out/grouped_product_bench.d<hidden>f<width>k<top-k>.json`.
 """
 
 from __future__ import annotations
@@ -31,7 +31,7 @@ for _p in (ROOT, os.path.join(ROOT, "benchmark")):
 import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 
-D, F, EXPERTS, HELD, TOP_K, LAYERS = 3072, 1024, 256, 128, 10, 3
+LAYERS = 3
 ROW_TILES = (128, 256, 64)
 
 
@@ -41,7 +41,20 @@ def main(argv=None):
     ap.add_argument("--runs", type=int, default=5)
     ap.add_argument("--top", type=int, default=12,
                     help="operations listed beside a program's products")
+    # the expert layer's shape: Laguna-S-2.1's share by default; SDAR's is
+    # --hidden 2048 --width 768 --experts 128 --held 128 --top-k 8
+    # --scale 1 --rows 128 (a pass of 32 slots: 1,024 pairs, 8 an expert)
+    ap.add_argument("--hidden", type=int, default=3072)
+    ap.add_argument("--width", type=int, default=1024)
+    ap.add_argument("--experts", type=int, default=256)
+    ap.add_argument("--held", type=int, default=128)
+    ap.add_argument("--top-k", type=int, default=10)
+    ap.add_argument("--scale", type=float, default=2.5)
+    ap.add_argument("--tiles", type=int, default=1,
+                    help="0: leave the kernel-alone row-tile runs out")
     args = ap.parse_args(argv)
+    D, F, EXPERTS, HELD, TOP_K = (args.hidden, args.width, args.experts,
+                                  args.held, args.top_k)
     if jax.devices()[0].platform != "tpu":
         raise SystemExit("grouped_product_bench: a device time needs the "
                          "chip")
@@ -74,12 +87,13 @@ def main(argv=None):
             def layer(p, x, impl=impl):
                 with device_scope(FFN):
                     return moe._dropless_moe(
-                        p, x, top_k=TOP_K, first=0, scale=2.5, live=None,
+                        p, x, top_k=TOP_K, first=0, scale=args.scale,
+                        live=None,
                         layer=jnp.int32(1), impl=impl)
             layer.__name__ = f"layer_{impl}_r{rows}"
             programs[layer.__name__] = (jax.jit(layer), (p, x))
     # the kernel alone, over sizes as a bucket's routing gives them
-    for rows in (512, 2048):
+    for rows in (512, 2048) if args.tiles else ():
         m = rows * TOP_K
         sizes = jnp.bincount(jax.random.randint(
             jax.random.key(rows), (m // 2,), 0, HELD), length=HELD
@@ -116,6 +130,8 @@ def main(argv=None):
             jax.block_until_ready(out)
     cap = pb_spans.read({"trace_dir": logdir})
     report = {"device_kind": jax.devices()[0].device_kind, "runs": args.runs,
+              "shape": {"hidden": D, "width": F, "experts": EXPERTS,
+                        "held": HELD, "top_k": TOP_K},
               "tiled_against_compiler": apart, "programs": {}}
     for name in programs:
         pids, runs, ns = cap.programs(rf"^jit_{name}\(")
@@ -137,7 +153,9 @@ def main(argv=None):
             "rest": [{"op": op.hlo, "op_name": op.op_name[-60:],
                       "ms_a_run": round(op.self_ns / 1e6 / max(runs, 1), 4)}
                      for op in rest]}
-    out_path = os.path.join(ROOT, "chiprun_out", "grouped_product_bench.json")
+    os.makedirs(os.path.join(ROOT, "chiprun_out"), exist_ok=True)
+    out_path = os.path.join(
+        ROOT, "chiprun_out", f"grouped_product_bench.d{D}f{F}k{TOP_K}.json")
     with open(out_path, "w") as f:
         json.dump(report, f, indent=1)
     for name, r in report["programs"].items():

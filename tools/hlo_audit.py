@@ -627,6 +627,7 @@ SLAB_MODELS = {
     # the sizes of each model's serve cell (benchmark/traffic/)
     "gpt2": dict(num_slots=8, max_len=640, prefill_bucket=512),
     "laguna": dict(num_slots=16, max_len=2432, prefill_bucket=2048),
+    "sdar": dict(num_slots=32, max_len=2048, prefill_bucket=1024),
 }
 HBM_BYTES = int(15.75 * 2**30)
 
@@ -667,7 +668,10 @@ def slab(num_slots: int = None, max_len: int = None, decode_chunk: int = 4,
     them (the compiler's ``ragged-dot``s, Pallas custom calls), and ``ok``
     also asks that they come to under a twentieth of the arguments (at
     these sizes a copy of the weights does not fit) and that arguments
-    and temporaries stay under the chip's 15.75 GiB."""
+    and temporaries stay under the chip's 15.75 GiB. ``model="sdar"``:
+    SDAR-30B-A3B-Chat's first stage (``SdarConfig()``: 8.1 GiB, unmade) at
+    its cell's sizes (32 slots of 2,048 rows, the 1,024 bucket, a block a
+    round); its prefill program arms the slot's block and mask in place."""
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
@@ -687,6 +691,10 @@ def slab(num_slots: int = None, max_len: int = None, decode_chunk: int = 4,
     if model == "laguna":
         from pipe_tpu.models.laguna import LagunaConfig, PipelinedLaguna
         net = PipelinedLaguna(LagunaConfig(), 1)
+    elif model == "sdar":
+        from pipe_tpu.models.sdar import PipelinedSdar, SdarConfig
+        net = PipelinedSdar(SdarConfig(), 1)
+        decode_chunk = 1            # a round is one block
     else:
         from pipe_tpu.models.gpt2 import GPT2Config, PipelinedGPT2
         net = PipelinedGPT2(GPT2Config(
@@ -768,10 +776,16 @@ def slab(num_slots: int = None, max_len: int = None, decode_chunk: int = 4,
             "io": _io_census(hlo)}
         aliased = collections.Counter(
             a["shape"] for a in out["programs"][name]["io"]["aliases"])
+        # a block round's tok is the slots' blocks, beside their masks
+        block = getattr(b, "_block", None)
+        tok_pos = ([f"s32[{num_slots}]"] * 2 if block is None else
+                   [f"s32[{num_slots}]", f"s32[{num_slots},{block[0]}]",
+                    f"pred[{num_slots},{block[0]}]"])
         if name != "resident" and not (
-                aliased[f"s32[{num_slots}]"] >= 2
+                all(aliased[s] >= tok_pos.count(s) for s in tok_pos)
                 and aliased[f"u32[{num_slots},2]"] >= 1
-                and sum(aliased.values()) >= 3 + 2 * len(slabs)):
+                and sum(aliased.values()) >= 1 + len(tok_pos)
+                + 2 * len(slabs)):
             violations.append(
                 f"{name}: the slab and the slots' tok, pos and key_data "
                 f"are not all written in place: aliased {dict(aliased)}")
