@@ -11,9 +11,11 @@ shared expert; a final RMSNorm and an untied head over 151936 words.
 Visibility is BLOCK-causal everywhere: position ``j`` is visible from ``i``
 iff ``j // L <= i // L``, ``L`` the block length. The equations, and how a
 reply is generated block by block (``T`` denoise passes that each reveal
-the masked positions the model is surest of, then one commit pass whose
-keys and values the cache keeps), are written out in
-``benchmark/reference/sdar.py``.
+the masked positions the model is surest of, then the commit: the finished
+block's pass, whose keys and values the cache keeps), are written out in
+``benchmark/reference/sdar.py``. The engine runs the commit as the first
+half of the next block's first denoise pass (:meth:`SdarBlock.decode` over
+two blocks' rows), the reference as a forward of its own.
 
 What is held here is a stage: the ``n_layers`` leading layers, each whole
 (all 128 experts: ``experts_held`` is the whole range, nothing is absent),
@@ -153,14 +155,17 @@ class SdarBlock(Module):
         return self._layer(params, x, self.attn.prefill, live, at)
 
     def decode(self, params, x, cache, pos, tree=None, layer=None,
-               live=None, at=None):
+               live=None, at=None, lead=None):
         """A block's rows ``x [S, L, d]`` at block-aligned ``pos`` over
         the cache of the earlier blocks and over each other (``tree``: the
-        all-ones within-chunk mask): ``(x, cache, counts)``."""
+        all-ones within-chunk mask), or two blocks' ``[S, 2L, d]`` under
+        the block lower-triangular one, the first of them written where
+        ``lead`` says (``MultiHeadAttention.decode``): ``(x, cache,
+        counts)``."""
         return self._layer(
             params, x,
             lambda p, y: self.attn.decode(p, y, cache, pos, tree=tree,
-                                          layer=layer), live, at)
+                                          layer=layer, lead=lead), live, at)
 
 
 class PipelinedSdar(PipelinedTransformer):

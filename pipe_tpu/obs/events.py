@@ -135,14 +135,18 @@ MOE_SHARED = "moe_shared"
 ATTN_WINDOW = "attn_window"
 ATTN_FULL = "attn_full"
 # Generation by diffusion over blocks (the serve engine's block round):
-# around the layer loop of a denoise pass and of the commit pass, outside
-# every layer's own scopes, and inside ``head`` around what picks the
-# positions a pass reveals (softmax, confidence, the reveal).
+# around the layer loop of a denoise pass, outside every layer's own
+# scopes (a round's first one also carries the commit of the block before,
+# which is no pass of its own and has no scope of its own), and inside
+# ``head`` around what picks the positions a pass reveals (softmax,
+# confidence, the reveal). What the round counts, carried on the device and
+# put on ``serve.decode.done`` beside the layers' and the caches' counts:
+# ``serve.diffusion.{blocks, denoise_passes, commit_passes, tokens,
+# cut_tokens, fused_commits}`` (``serve/engine.py`` ``BLOCK_COUNTS``).
 DIFFUSION_DENOISE = "diffusion_denoise"
-DIFFUSION_COMMIT = "diffusion_commit"
 DIFFUSION_SELECT = "diffusion_select"
 MODEL_SCOPES = (MOE_ROUTER, MOE_EXPERTS, MOE_SHARED, ATTN_WINDOW, ATTN_FULL,
-                DIFFUSION_DENOISE, DIFFUSION_COMMIT, DIFFUSION_SELECT)
+                DIFFUSION_DENOISE, DIFFUSION_SELECT)
 # Not a layer but a mark that cuts across them: a forward that runs again
 # for its backward. ``jax.checkpoint`` writes this name itself; the
 # scheduled executor's manual re-forward opens a scope of the same name.

@@ -608,7 +608,8 @@ class MultiHeadAttention(Module):
         return jnp.take(rows, jnp.clip(src, 0, rows.shape[-2] - 1),
                         axis=-2)
 
-    def decode(self, params, x, cache, pos, tree=None, layer=None):
+    def decode(self, params, x, cache, pos, tree=None, layer=None,
+               lead=None):
         """Incremental self-attention with a KV cache (inference only).
 
         ``x``: the new tokens' hidden states ``[b, q, d]`` occupying
@@ -636,6 +637,13 @@ class MultiHeadAttention(Module):
         ``[S]``. Only the ``S x q`` new rows are written, at ``(layer,
         s, pos[s])``, and only ``cache[layer]`` is read, so the slab
         stays one buffer updated in place.
+
+        ``lead`` (optional ``(n, write [S])``, slab form): the chunk's
+        ``n`` leading rows are a slot's to write only where ``write``;
+        elsewhere the cache keeps the rows it has at ``[pos, pos + n)``,
+        which may then lie before row 0 (``pos`` negative: no start is
+        clamped), and the queries read those. The other ``q - n`` rows
+        are written as ever.
 
         Why this shape (PERF.md, PR 29). A TPU tiles an array's two
         minor dimensions, 16 x 128 for bf16. Rows of ``[H, D]`` (the
@@ -684,6 +692,10 @@ class MultiHeadAttention(Module):
         if ring and (q != 1 or tree is not None):
             raise ValueError("a ring of window rows takes one new row a "
                              "step (no speculative or chunked rows)")
+        if lead is not None and (layer is None or ring
+                                 or 2 * lead[0] > q):
+            raise ValueError("lead= gates the leading rows of a slab "
+                             "write, at most half the chunk's")
         # the cache's update, and below its two reads (every cached row
         # of k for the scores, of v for the mix): what a decode step pays
         # for the cache, apart from the projections and the softmax
@@ -696,8 +708,8 @@ class MultiHeadAttention(Module):
                 ck, cv = cache["k"], cache["v"]
             else:
                 cache = {n: _write_slab_rows(
-                    cache[n], rows[n], layer, pos % win if ring else pos)
-                         for n in rows}
+                    cache[n], rows[n], layer, pos % win if ring else pos,
+                    lead) for n in rows}
                 ck, cv = (jax.lax.dynamic_index_in_dim(          # [S, T, C]
                     cache[n], layer, 0, keepdims=False) for n in ("k", "v"))
             if layer is not None:
@@ -807,17 +819,30 @@ def _own_blocks_of(o, head_dim: int, group: int = 1):
                       _block_of_head(h, group, o.dtype))
 
 
-def _write_slab_rows(slab, rows, layer, pos):
+def _write_slab_rows(slab, rows, layer, pos, lead=None):
     """``rows [S, q, H, D]`` into ``slab [L, S, T, C]`` at ``(layer, s,
     pos[s])``, folded (:func:`fold_heads`): one ``dynamic_update_slice``
     a slot (its clamping is the batch form's), each on the buffer the
     last one left. Unrolled over the slots on purpose: on the v5e one
     scatter of ``S`` windows runs as a loop and cost four times as much
-    (PERF.md, PR 26)."""
+    (PERF.md, PR 26). ``lead = (n, write [S])``: two a slot, the ``n``
+    leading rows first. Where they are not the slot's to write they go
+    where the rest goes, ``pos[s] + n``, and the rest then covers them:
+    nothing is read back, and no start lies before row 0."""
     rows = fold_heads(rows)                                # [S, q, C]
+    if lead is None:
+        for s in range(rows.shape[0]):
+            slab = jax.lax.dynamic_update_slice(
+                slab, rows[s][None, None], (layer, s, pos[s], 0))
+        return slab
+    n, write = lead
     for s in range(rows.shape[0]):
+        rest = pos[s] + n
         slab = jax.lax.dynamic_update_slice(
-            slab, rows[s][None, None], (layer, s, pos[s], 0))
+            slab, rows[s, :n][None, None],
+            (layer, s, jnp.where(write[s], pos[s], rest), 0))
+        slab = jax.lax.dynamic_update_slice(
+            slab, rows[s, n:][None, None], (layer, s, rest, 0))
     return slab
 
 

@@ -90,12 +90,14 @@ __all__ = ["SingleDeviceSlotBackend", "ServeEngine", "EngineDraining"]
 # rows of a grouped model's carried ``counts``: who counted
 COUNT_PREFILL, COUNT_DECODE = 0, 1
 # what the block round counts, behind the layers' and the caches' counts:
-# blocks and passes a LIVE slot went through (a pass counts once a slot),
-# positions revealed (the prompt's tail not counted), and those of them
-# behind the reply's asked length, which the host drops
+# blocks and passes a LIVE slot went through (a pass counts once a slot;
+# a commit pass is one that ran for the commit alone: the block round has
+# none, so it stands at 0), positions revealed (the prompt's tail not
+# counted), those of them behind the reply's asked length, which the host
+# drops, and blocks whose commit rode the next block's first denoise pass
 BLOCK_COUNTS = ("diffusion.blocks", "diffusion.denoise_passes",
                 "diffusion.commit_passes", "diffusion.tokens",
-                "diffusion.cut_tokens")
+                "diffusion.cut_tokens", "diffusion.fused_commits")
 
 
 class EngineDraining(RuntimeError):
@@ -132,7 +134,9 @@ class _Round(NamedTuple):
     key_data, hist, done, budget)``, writing ``rows`` cache rows a slot.
     ``tok`` and ``hist`` are the round's own: one token a slot and the
     draft history (None where nothing drafts), or, for the block round,
-    a block of tokens ``[S, L]`` and which of them are still masked.
+    two blocks of tokens ``[S, 2L]`` (the finished one that awaits its
+    commit, the one under denoising) and ``(masked [S, L], awaits [S])``:
+    which of the second are still masked, and whether the first is there.
     ``counted``: ``n_emit`` varies (the accepted length; a reply's last
     block cut) and the loop records it; otherwise it is None, and every
     live slot emits ``width``. ``noted``: the round says one number of
@@ -321,9 +325,11 @@ class SingleDeviceSlotBackend:
         self.spec_tokens = spec
         # how the model generates: a token a step unless it declares
         # otherwise. ``("block_diffusion", L, T, mask)``: a block of L
-        # positions a step, through T denoise passes and a commit pass
-        # (:meth:`_block_round`); the slots' ``tok`` is then the block
-        # ``[S, L]`` and their ``hist`` seat holds ``masked [S, L]``
+        # positions a step, through T denoise passes, the first of which
+        # also commits the block before (:meth:`_block_round`); the
+        # slots' ``tok`` is then ``[S, 2L]``, the block that awaits its
+        # commit and the block under denoising, and their ``hist`` seat
+        # holds ``(masked [S, L], awaits [S])``
         how = getattr(model, "generation", None)
         if how is not None and how[0] != "block_diffusion":
             raise ValueError(f"{type(model).__name__} generates by "
@@ -333,7 +339,8 @@ class SingleDeviceSlotBackend:
         # decode program returns. Spec mode re-sets this per launch to
         # the adaptive ladder rung that ran.
         self.decode_width = spec if spec is not None else decode_chunk
-        # forward passes a round, where that is not its width
+        # forward passes a round, where that is not its width (a block
+        # round's T: the commit is no pass of its own)
         self.round_passes = None
         if self._block is not None:
             if decode_chunk != 1:
@@ -344,7 +351,7 @@ class SingleDeviceSlotBackend:
             self.max_len = max_len = -(-max_len // self._block[0]) \
                 * self._block[0]
             self.decode_width = self._block[0]
-            self.round_passes = self._block[1] + 1
+            self.round_passes = self._block[1]
 
         stage_params, pre_params, post_params = params
         cd = model.cfg.compute_dtype
@@ -492,7 +499,7 @@ class SingleDeviceSlotBackend:
                 self._caches = self._make_group_slabs(cd)
         self._tok = jnp.zeros((num_slots,), jnp.int32)
         if self._block is not None:
-            self._tok = jnp.full((num_slots, self._block[0]),
+            self._tok = jnp.full((num_slots, 2 * self._block[0]),
                                  self._block[2], jnp.int32)
         self._pos = jnp.zeros((num_slots,), jnp.int32)
         kd0 = jax.random.key_data(jax.random.key(0))
@@ -504,8 +511,10 @@ class SingleDeviceSlotBackend:
         # last position. None where no round drafts.
         self._hist = None if spec is None else jnp.full(
             (num_slots, max_len + spec), gen.pad_token_id, jnp.int32)
-        if self._block is not None:     # the block round's seat: masked
-            self._hist = jnp.ones(self._tok.shape, jnp.bool_)
+        if self._block is not None:     # the block round's seat: masked,
+            self._hist = (                # and no block awaits its commit
+                jnp.ones((num_slots, self._block[0]), jnp.bool_),
+                jnp.zeros((num_slots,), jnp.bool_))
         self.launch_notes = None
 
         # THE decode program: one jit per round width — one in all
@@ -579,7 +588,7 @@ class SingleDeviceSlotBackend:
     # -- device programs ---------------------------------------------------
 
     def _run_layers(self, block_stack, h, caches, pos, tree=None,
-                    live=None):
+                    live=None, lead=None):
         """THE layer loop of the decode program (slab and paged views
         alike, the plain step, the speculative verify and the truncated
         drafters): ``h [S, q, d]`` through all layers
@@ -603,15 +612,20 @@ class SingleDeviceSlotBackend:
 
         A model whose layers come in groups (:meth:`_run_groups`) has
         one such carried slab a kind of cache, in ``caches`` by name,
-        and ``live [S]`` tells its expert layers which slots' rows to
-        compute."""
+        and ``live [S]`` (or, a row at a time, ``[S, q]``) tells its
+        expert layers which slots' rows to compute; ``lead``: whose
+        leading rows the cache takes (``MultiHeadAttention.decode``), for
+        a block that says so."""
         m = self.model
         cd = m.cfg.compute_dtype
         if self._groups is not None:
+            more = {} if lead is None else {"lead": lead}
+
             def step(g, bp, i, h, slab):
                 h, slab, counts = g.block.decode(
                     bp, h, slab, pos, tree=tree, layer=g.first + i,
-                    live=None if live is None else live[:, None], at=i)
+                    live=live if live is None or live.ndim == 2
+                    else live[:, None], at=i, **more)
                 return h, slab, None, counts
 
             return self._run_groups(block_stack, h, caches, COUNT_DECODE,
@@ -747,8 +761,11 @@ class SingleDeviceSlotBackend:
         the slot's block is the prompt's tail (its ``true_len % L``
         tokens behind the last whole block, revealed) and the mask token
         behind it, its position the first row no whole block filled, its
-        key the request's."""
-        tok, pos, key_data, masked = state
+        key the request's. No block awaits its commit: the prompt's whole
+        blocks are in the cache already, and the block the slot's last
+        reply ended in was never owed one (the whole slab is written anew),
+        so the flag is put down."""
+        tok, pos, key_data, (masked, awaits) = state
         L, _, mask_id = self._block
         put = jax.lax.dynamic_update_index_in_dim
         whole = true_len - true_len % L
@@ -756,12 +773,14 @@ class SingleDeviceSlotBackend:
         tail = jax.lax.dynamic_slice(
             jnp.pad(prompt[0], (0, L)), (whole,), (L,))
         hidden = jnp.arange(L, dtype=jnp.int32) >= true_len - whole
-        return (put(tok, jnp.where(hidden, jnp.int32(mask_id), tail), slot,
-                    0),
+        block = jnp.where(hidden, jnp.int32(mask_id), tail)
+        return (put(tok, jnp.concatenate(
+                    [jnp.full((L,), jnp.int32(mask_id)), block]), slot, 0),
                 put(pos, jnp.asarray(whole, pos.dtype), slot, 0),
                 put(key_data, jax.random.key_data(jax.random.key(seed)),
                     slot, 0),
-                put(masked, hidden, slot, 0))
+                (put(masked, hidden, slot, 0),
+                 put(awaits, jnp.zeros((), jnp.bool_), slot, 0)))
 
     def _prefill_fn(self, block_stack, pre, post, caches, state, prompt,
                     true_len, slot, seed, row):
@@ -956,54 +975,92 @@ class SingleDeviceSlotBackend:
         slots. Counted (a reply ends inside its last block: the tokens
         behind its budget are not valid) and noted (the pass that revealed
         each token).
-        A slot's state is its block's tokens ``tok [S, L]`` and which of
-        them are still ``masked [S, L]`` (never inferred from a token id),
-        at a block-aligned ``pos``. ``T`` DENOISE passes: the block's rows
-        at their positions through every layer, over the cache of all
-        earlier blocks and over each other (the ``q = L`` form of
-        :meth:`_run_layers` under an all-ones within-chunk mask), the
+        A slot's state is two blocks of tokens ``tok [S, 2L]``, the
+        finished block at ``pos - L`` that awaits its commit (where
+        ``awaits [S]``) and the block under denoising at the block-aligned
+        ``pos``, and which positions of the second are still ``masked [S,
+        L]`` (never inferred from a token id). ``T`` DENOISE passes: the
+        block's rows at their positions through every layer, over the
+        cache of all earlier blocks and over each other (the ``q = L`` form
+        of :meth:`_run_layers` under an all-ones within-chunk mask), the
         head, and at each masked position the token ``x_p`` put first (or
         sampled on the slot's key chain) with its probability ``c_p``;
         the ``ceil(masked / passes left)`` masked positions of largest
         ``c_p`` are revealed. A pass of a slot that has nothing masked
-        left changes nothing and is not counted. Then one COMMIT pass of
-        the finished block, whose keys and values are what the cache
-        keeps (every pass writes rows ``pos .. pos + L - 1``, the last
-        writer wins, and no read sees an earlier pass's: the speculative
-        round's "rollback is free"); no head. The positions that were
-        masked at the block's start are its tokens (all ``L`` but for a
-        prompt's tail in a request's first block), emitted left-aligned
-        with the pass that revealed each; those behind the slot's budget
-        are the host's to drop."""
+        left changes nothing and is not counted.
+
+        The COMMIT of a block, the pass of its finished tokens whose keys
+        and values are what the cache keeps, is no pass of its own: it is
+        the first half of the NEXT round's first denoise pass. That pass
+        takes ``2L`` rows a slot at ``pos - L``, the awaiting block and
+        then the block under denoising, under a block lower-triangular
+        mask (the first sees the cache and itself; the second the cache,
+        the first and itself): every row goes through what a pass of its
+        own would put it through, and the weights are read once. The head
+        and the reveal take the second half only. Every pass writes the
+        rows it computes, the last writer wins, and no read sees an
+        earlier pass's (the speculative round's "rollback is free"); the
+        first half is written where a block awaits and nowhere else: a
+        slot's first round after its admission has the prompt's rows
+        below ``pos``, or none (``pos`` 0), and keeps them. So the round
+        ends on its last denoise pass and leaves its block awaiting: across
+        rounds, launches and other slots' admissions (the state is what a
+        launch returns and the next takes). A reply's LAST block is never
+        committed: nobody reads rows behind a reply's end, the slot
+        retires, and the next admission writes the whole slab and puts the
+        flag down (:meth:`_arm_block`). A slot that is done takes part in
+        neither half.
+
+        The positions that were masked at the block's start are its
+        tokens (all ``L`` but for a prompt's tail in a request's first
+        block), emitted left-aligned with the pass that revealed each;
+        those behind the slot's budget are the host's to drop."""
         m, gen = self.model, self.gen
         L, T, mask_id = self._block
         eos = gen.eos_token_id
-        caches, tok, pos, key_data, masked, done, budget = carry
+        caches, tok, pos, key_data, (masked, awaits), done, budget = carry
+        held, tok = tok[:, :L], tok[:, L:]
         ones = np.ones((L, L), bool)
         ar = jnp.arange(L, dtype=jnp.int32)
         n_gen = jnp.sum(masked.astype(jnp.int32), axis=1)      # [S]
         base = len(self._count_names) - len(BLOCK_COUNTS)
+        rides = awaits & ~done          # commits in this round's first pass
 
-        def count(caches, *, blocks=0, denoise=0, commit=0, tokens=0, cut=0):
+        def count(caches, *, blocks=0, denoise=0, tokens=0, cut=0, fused=0):
             """Into the carried counts, in :data:`BLOCK_COUNTS`' order."""
             add = jnp.stack([jnp.asarray(v, jnp.int32) for v in
-                             (blocks, denoise, commit, tokens, cut)])
+                             (blocks, denoise, 0, tokens, cut, fused)])
             return dict(caches, counts=caches["counts"].at[
                 COUNT_DECODE, base:].add(add))
 
-        def layers(tok, caches, work, scope):
-            h = jax.vmap(
-                lambda xs, p: m.embed_at(pre, xs[None], p)[0])(tok, pos)
-            with ev.device_scope(scope):
-                h, caches = self._run_layers(block_stack, h, caches, pos,
-                                             tree=ones, live=work)
+        def embed(rows, at):
+            return jax.vmap(
+                lambda xs, p: m.embed_at(pre, xs[None], p)[0])(rows, at)
+
+        def layers(tok, caches, work, fused):
+            with ev.device_scope(ev.DIFFUSION_DENOISE):
+                if fused:
+                    both = np.kron(np.tril(np.ones((2, 2), bool)), ones)
+                    live = jnp.repeat(jnp.stack([rides, work], axis=1), L,
+                                      axis=1)                  # [S, 2L]
+                    h, caches = self._run_layers(
+                        block_stack,
+                        embed(jnp.concatenate([held, tok], axis=1), pos - L),
+                        caches, pos - L, tree=both, live=live,
+                        lead=(L, rides))
+                    h = h[:, L:]
+                else:
+                    h, caches = self._run_layers(
+                        block_stack, embed(tok, pos), caches, pos,
+                        tree=ones, live=work)
+            # the two halves read the same rows, once
             return h, self._count_rows_read(caches, pos, work, q=L)
 
-        def denoise(c, t):
+        def denoise(c, t, fused=False):
             caches, tok, masked, key_data, note = c
             left = jnp.sum(masked.astype(jnp.int32), axis=1)
             work = ~done & (left > 0)
-            h, caches = layers(tok, caches, work, ev.DIFFUSION_DENOISE)
+            h, caches = layers(tok, caches, work, fused)
             logits = head_logits(m, post, h)                   # [S, L, V]
             with ev.device_scope(ev.HEAD), \
                     ev.device_scope(ev.DIFFUSION_SELECT):
@@ -1023,16 +1080,15 @@ class SingleDeviceSlotBackend:
                 note = jnp.where(reveal, t, note)
             caches = count(caches, denoise=jnp.sum(work),
                            tokens=jnp.sum(reveal))
-            return (caches, tok, masked, key_data, note), None
+            return caches, tok, masked, key_data, note
 
+        c = denoise((caches, tok, masked, key_data,
+                     jnp.zeros(tok.shape, jnp.int32)), 0, fused=True)
         (caches, tok, masked, key_data, note), _ = jax.lax.scan(
-            denoise, (caches, tok, masked, key_data,
-                      jnp.zeros(tok.shape, jnp.int32)),
-            jnp.arange(T, dtype=jnp.int32))
-        _, caches = layers(tok, caches, ~done, ev.DIFFUSION_COMMIT)
+            lambda c, t: (denoise(c, t), None), c,
+            jnp.arange(1, T, dtype=jnp.int32))
         n_emit = jnp.where(done, 0, jnp.minimum(n_gen, budget))
-        caches = count(caches, blocks=jnp.sum(~done),
-                       commit=jnp.sum(~done),
+        caches = count(caches, blocks=jnp.sum(~done), fused=jnp.sum(rides),
                        cut=jnp.sum(jnp.where(done, 0, n_gen) - n_emit))
         # the block's own tokens, left-aligned (a first block's lie
         # behind the prompt's tail), with the pass that revealed each
@@ -1043,12 +1099,19 @@ class SingleDeviceSlotBackend:
         note = jnp.where(emit, jnp.take_along_axis(note, src, axis=1), 0)
         pos = jnp.where(done, pos, pos + L)
         budget = budget - n_emit
-        done = done | (budget <= 0)
+        ran, done = ~done, done | (budget <= 0)
         if eos is not None:
             done = done | jnp.any((toks == jnp.int32(eos)) & emit, axis=1)
-        # the next block: all of it masked
-        return ((caches, jnp.full(tok.shape, jnp.int32(mask_id)), pos,
-                 key_data, jnp.ones(masked.shape, jnp.bool_), done, budget),
+        # the finished block awaits its commit where the reply goes on (a
+        # slot that took no part keeps what it had); the next block: all
+        # of it masked
+        return ((caches,
+                 jnp.concatenate(
+                     [jnp.where(ran[:, None], tok, held),
+                      jnp.full(tok.shape, jnp.int32(mask_id))], axis=1),
+                 pos, key_data,
+                 (jnp.ones(masked.shape, jnp.bool_),
+                  jnp.where(ran, ~done, awaits)), done, budget),
                 toks, n_emit, note)
 
     def _decode_program(self, store, rnd):

@@ -1,6 +1,7 @@
 """SDAR-30B-A3B-Chat on the serve path, at a small size on the CPU (hidden
 64, 4 query heads over 2 KV heads of 16, 8 experts top-2, 3 layers, a
-vocabulary of 96, blocks of 4 through 4 denoise passes and a commit pass).
+vocabulary of 96, blocks of 4 through 4 denoise passes, the first of which
+commits the block before).
 
 What they hold: (a) the program's whole-sequence forward agrees with the
 plain float32 reference (``benchmark/reference/sdar.py``, which imports
@@ -8,9 +9,11 @@ nothing of the program); (b) ``ServeEngine`` generates, for every ``n % 4``
 and ``m % 4``, several slots out of step and admissions in mid-launch, the
 reference's tokens in the reference's reveal order, and the reference's
 logits at the served states (``denoise_logits``) put the served tokens first;
-(c) the cache after a block holds the reference's keys and values of the
-clean tokens and not a denoise pass's: without the commit pass the test
-fails; (d) QK-norm, the block-causal prefill and the ``q = L`` decode each
+(c) the cache holds the reference's keys and values of the clean tokens of
+every block but a reply's last, and not a denoise pass's: with the first half
+of the fused pass dropped the test fails; a slot's first round writes nothing
+below its position; however the rounds are chopped into launches, the tokens
+and their reveal passes are the same; (d) QK-norm, the block-causal prefill and the ``q = L`` decode each
 against a direct ``jax.numpy`` expression; (e) the plain and speculative
 rounds' own tests are elsewhere and untouched; (f) the paths that decode one
 stacked block a token a step refuse the model by name.
@@ -50,7 +53,7 @@ REF = FAMILY.reference
 TOL = 2e-4          # float32 against float32 `highest`, three layers deep
 L = T = 4
 COUNTS = ("blocks", "denoise_passes", "commit_passes", "tokens",
-          "cut_tokens")
+          "cut_tokens", "fused_commits")
 
 
 def tiny_cfg(**over):
@@ -74,14 +77,14 @@ def tiny():
     return cfg, weights, FAMILY.build_model(cfg, 1)
 
 
-def backend(tiny, slots=3, **kw):
+def backend(tiny, slots=3, resident_chunks=3, **kw):
     cfg, weights, model = tiny
     return SingleDeviceSlotBackend(
         model, FAMILY.serve_params(weights), num_slots=slots,
         max_len=32 + 24,
         gen=GenerationConfig(max_new_tokens=24, temperature=0.0),
         buckets=BucketSpec.pow2(min_len=8, max_len=32), decode_chunk=1,
-        resident=True, resident_chunks=3, **kw)
+        resident=True, resident_chunks=resident_chunks, **kw)
 
 
 def test_the_family_builds_the_model_whole(tiny):
@@ -227,12 +230,15 @@ def test_the_counts_add_up(served):
     assert kept == sum(m for _, m in sent.values())
     assert grew["tokens"] == L * grew["blocks"] - tails
     assert grew["tokens"] - grew["cut_tokens"] == kept == grew["engine"]
-    assert grew["commit_passes"] == grew["blocks"]
+    # no pass runs for a commit alone: a block's commit rides the first
+    # denoise pass of the round after it, whichever launch that round falls
+    # in, and a reply's last block has none
+    assert grew["commit_passes"] == 0
+    assert grew["fused_commits"] == grew["blocks"] - len(sent)
     # a first block whose prompt ends r positions inside it takes r denoise
     # passes fewer (at L = T)
     assert grew["denoise_passes"] == T * grew["blocks"] - tails
-    assert grew["denoise_passes"] + grew["commit_passes"] \
-        == grew["slot_passes"] - tails
+    assert grew["denoise_passes"] == grew["slot_passes"] - tails
     # no first token comes from a prefill: every token is a launch's
     assert grew["admitted"] == len(sent)
     assert set(be.launch_counts) >= set(COUNTS) | {
@@ -244,7 +250,7 @@ def test_the_counts_add_up(served):
 def test_one_decode_program_and_no_retrace(tiny, served):
     be = served[3]
     assert list(be._resident_jits) == [L]
-    assert be.round_passes == T + 1 and be.decode_width == L
+    assert be.round_passes == T and be.decode_width == L
     fn, args = be.decode_program()
     assert fn._cache_size() == 1
     text = open(engine_mod.__file__).read()
@@ -305,59 +311,170 @@ def _reference_rows(weights, tokens, cfg):
     return {"k": np.stack(ks), "v": np.stack(vs)}
 
 
-def test_the_cache_keeps_the_commit_passs_rows(tiny, monkeypatch):
+def _serve_one(tiny, prompt, m, resident_chunks=3):
+    be = backend(tiny, slots=1, resident_chunks=resident_chunks)
+    eng = ServeEngine(be, RequestQueue(capacity=4))
+    eng.submit(prompt, max_new_tokens=m)
+    (r,) = eng.run_until_idle()
+    return be, r
+
+
+@pytest.mark.parametrize("resident_chunks", [1, 3])
+def test_the_cache_keeps_the_commit_passs_rows(tiny, monkeypatch,
+                                               resident_chunks):
+    """Every block but a reply's last has the clean tokens' keys and values
+    in the cache once the next round's first pass has run; the last block,
+    which no round follows, keeps its last denoise pass's."""
     cfg, weights, _ = tiny
     # 14 rows asked: the reply ends inside the fourth block, which the
-    # engine finishes and commits all the same
+    # engine finishes all the same, and never commits
     prompt, m = [5, 17, 44, 80, 2, 61, 33], 7
-
-    def serve():
-        be = backend(tiny, slots=1)
-        eng = ServeEngine(be, RequestQueue(capacity=4))
-        eng.submit(prompt, max_new_tokens=m)
-        (r,) = eng.run_until_idle()
-        return be, r
-
-    be, r = serve()
+    be, r = _serve_one(tiny, prompt, m, resident_chunks)
     tokens, order, _ = REF.generate(weights, prompt, m + 2, cfg)
     assert r.tokens == tokens[:m] and len(prompt) + len(tokens) == 16
     seq = prompt + tokens                      # the last block whole
     want = _reference_rows(weights, seq, cfg)
     got = _cache_rows(be, 0, len(seq))
     for name in ("k", "v"):
-        np.testing.assert_allclose(got[name], want[name], atol=TOL)
+        np.testing.assert_allclose(got[name][:, :12], want[name][:, :12],
+                                   atol=TOL)
     # a denoise pass's rows are another thing: the state before the last
     # pass holds the mask token where that pass revealed
     noisy = REF.kept_tokens(seq, len(prompt), order,
                             dict(cfg, fault="no_commit"))
     assert (noisy != np.asarray(seq)).sum() == 2    # blocks 2 and 3
     stale = _reference_rows(weights, noisy, cfg)
-    assert np.abs(stale["k"] - want["k"]).max() > 100 * TOL
-    # and they are what the cache would hold without the commit pass
-    real = SingleDeviceSlotBackend._block_round
+    assert np.abs(stale["k"][:, 8:12] - want["k"][:, 8:12]).max() > 100 * TOL
+    # the last block's are what the cache is left with: over the clean
+    # blocks before it
+    last = _reference_rows(weights, list(seq[:12]) + list(noisy[12:]), cfg)
+    for name in ("k", "v"):
+        np.testing.assert_allclose(got[name][:, 12:], last[name][:, 12:],
+                                   atol=TOL)
+    assert np.abs(got["k"][:, 12:] - want["k"][:, 12:]).max() > 100 * TOL
+    # and stale rows are what every block would keep if the first half of
+    # the fused pass were dropped: the cache as the denoise passes left it,
+    # and other tokens from the third block on
+    layers = SingleDeviceSlotBackend._run_layers
 
-    def no_commit(self, block_stack, pre, post, carry):
-        layers = self._run_layers
-        calls = []
+    def no_commit(self, *a, lead=None, **kw):
+        if lead is not None:
+            lead = (lead[0], jnp.zeros_like(lead[1]))
+        return layers(self, *a, lead=lead, **kw)
 
-        def counted(*a, **kw):
-            calls.append(1)
-            h, caches = layers(*a, **kw)
-            # the pass after the scanned denoise passes is the commit pass:
-            # give back the cache as it came
-            return (h, a[2]) if len(calls) == 2 else (h, caches)
-
-        monkeypatch.setattr(self, "_run_layers", counted)
-        try:
-            return real(self, block_stack, pre, post, carry)
-        finally:
-            monkeypatch.setattr(self, "_run_layers", layers)
-
-    monkeypatch.setattr(SingleDeviceSlotBackend, "_block_round", no_commit)
-    be2, r2 = serve()
+    monkeypatch.setattr(SingleDeviceSlotBackend, "_run_layers", no_commit)
+    be2, r2 = _serve_one(tiny, prompt, m, resident_chunks)
     got2 = _cache_rows(be2, 0, len(seq))
-    assert np.abs(got2["k"] - want["k"]).max() > 100 * TOL
-    assert r2.tokens != r.tokens
+    assert np.abs(got2["k"][:, 8:12] - want["k"][:, 8:12]).max() > 100 * TOL
+    assert r2.tokens != r.tokens and r2.tokens[:1] == r.tokens[:1]
+
+
+@pytest.mark.parametrize("n", [2, 4, 7, 12])
+def test_a_first_round_writes_nothing_below_its_position(tiny, n):
+    """Nothing awaits a commit in a slot's first round after its admission:
+    the prompt's whole blocks are the prefill's, bit for bit, after it (a
+    prompt shorter than a block has none, and a write at ``pos - L`` would
+    have been clamped over row 0)."""
+    cfg, weights, _ = tiny
+    be = backend(tiny, slots=2, resident_chunks=1)
+    prompt = np.random.default_rng(n).integers(1, 95, size=n).tolist()
+    be.prefill(1, prompt, seed=0)
+    whole = n // L * L
+    masked, awaits = be._hist
+    assert int(be._pos[1]) == whole and not bool(awaits[1])
+    assert np.asarray(masked[1]).tolist() == [j >= n - whole
+                                              for j in range(L)]
+    before = {k: np.asarray(a[:, 1]) for k, a in be._caches["full"].items()}
+    toks, valid = be.decode(np.asarray([False, True]),
+                            budgets=np.asarray([0, 8], np.int32))
+    tokens, order, _ = REF.generate(weights, prompt, L - n % L, cfg)
+    assert toks[1][valid[1]].tolist() == tokens
+    assert be.launch_notes[1][valid[1]].tolist() == order
+    after = {k: np.asarray(a[:, 1]) for k, a in be._caches["full"].items()}
+    for k in before:
+        np.testing.assert_array_equal(after[k][:, :whole],
+                                      before[k][:, :whole])
+        assert np.abs(after[k][:, whole:whole + L]).max() > 0
+        # and nothing behind the block
+        np.testing.assert_array_equal(after[k][:, whole + L:],
+                                      before[k][:, whole + L:])
+    # the block now awaits; the slot that took no part has nothing
+    assert np.asarray(be._hist[1]).tolist() == [False, True]
+    assert be.launch_counts["fused_commits"] == 0
+    assert be.launch_counts["commit_passes"] == 0
+    assert be.launch_counts["denoise_passes"] == L - n % L
+    # the round after commits it in its first pass
+    be.decode(np.asarray([False, True]), budgets=np.asarray([0, 4], np.int32))
+    assert be.launch_counts["fused_commits"] == 1
+    seq = prompt + tokens
+    want = _reference_rows(weights, seq, cfg)
+    got = _cache_rows(be, 1, len(seq))
+    for name in ("k", "v"):
+        np.testing.assert_allclose(got[name], want[name], atol=TOL)
+
+
+# a prompt shorter than a block (pos 0), every n % L, a reply that ends
+# inside its last block or on its edge, a reply of one block: more requests
+# than slots, so that an admission lands in one slot between two launches
+# while the other's block awaits its commit
+CHOPPED = [(3, 9), (2, 3), (8, 19), (9, 7), (10, 6), (11, 5), (1, 11),
+           (16, 22), (13, 4)]
+
+
+@pytest.fixture(scope="module")
+def chopped(tiny):
+    """The same requests through launches of one round and of up to eight:
+    ``{resident_chunks: (replies in the order sent, admissions that landed
+    while another slot's block awaited its commit)}``."""
+    cfg, weights, _ = tiny
+    rng = np.random.default_rng(2)
+    prompts = [rng.integers(1, 95, size=n).tolist() for n, _ in CHOPPED]
+    got = {}
+    for chunks in (1, 8):
+        be = backend(tiny, slots=2, resident_chunks=chunks)
+        eng = ServeEngine(be, RequestQueue(capacity=16))
+        ids = [eng.submit(p, max_new_tokens=m).id
+               for p, (_, m) in zip(prompts, CHOPPED)]
+        out, beside = {}, 0
+        while not eng.idle:
+            awaits = np.asarray(be._hist[1])
+            held = [None if s is None else s.req.id for s in eng._slots]
+            for r in eng.tick():
+                out[r.request_id] = r
+            now = [None if s is None else s.req.id for s in eng._slots]
+            for i in range(2):       # admitted beside a reply under way
+                if now[i] is not None and now[i] != held[i] \
+                        and awaits[1 - i] and held[1 - i] is not None:
+                    beside += 1
+        got[chunks] = ([out[i] for i in ids], beside)
+    return prompts, got
+
+
+@pytest.mark.parametrize("resident_chunks", [1, 8])
+@pytest.mark.parametrize("case", range(len(CHOPPED)),
+                         ids=["n%d-m%d" % c for c in CHOPPED])
+def test_the_fused_round_gives_the_references_tokens_in_its_order(
+        tiny, chopped, case, resident_chunks):
+    cfg, weights, _ = tiny
+    prompts, got = chopped
+    (n, m), prompt = CHOPPED[case], prompts[case]
+    r = got[resident_chunks][0][case]
+    tokens, order, _ = REF.generate(weights, prompt, m, cfg)
+    assert r.status == "ok" and r.finish_reason == "length"
+    assert r.tokens == tokens
+    assert r.reveal_pass == order
+
+
+def test_an_awaiting_block_survives_a_launchs_end_and_an_admission(chopped):
+    """However the rounds are chopped into launches: the same tokens and
+    reveal passes, through admissions into the other slot between two
+    launches of a slot whose block awaits its commit."""
+    _, got = chopped
+    one, eight = got[1], got[8]
+    assert [r.tokens for r in one[0]] == [r.tokens for r in eight[0]]
+    assert [r.reveal_pass for r in one[0]] == \
+        [r.reveal_pass for r in eight[0]]
+    assert one[1] >= 3 and eight[1] >= 1
 
 
 # (d) -----------------------------------------------------------------------
@@ -435,6 +552,61 @@ def test_qk_norm_block_causal_prefill_and_block_decode_against_jnp():
     with pytest.raises(ValueError, match="takes no window"):
         blocked_causal_attention(rows["k"], rows["k"], rows["v"], block=4,
                                  window=8)
+
+
+def test_two_blocks_decode_in_one_pass_and_the_first_is_written_on_leave():
+    """The fused pass's attention: ``2L`` rows at ``pos - L`` under the block
+    lower-triangular mask are each block's own pass; ``lead`` says whose
+    first block the cache takes, and a first block before row 0 is no
+    write."""
+    attn = _attention()
+    key = jax.random.key(4)
+    params = attn.init(key, jnp.zeros((1, 1, 32)))
+    x = jax.random.normal(jax.random.fold_in(key, 1), (2, 16, 32))
+    want = _direct(attn, params, x, 4)
+    _, rows = attn.prefill(params, x)
+    both = np.kron(np.tril(np.ones((2, 2), bool)), np.ones((4, 4), bool))
+    # slot 0: blocks 0-1 cached, block 2 rides; slot 1: blocks 0-2 cached,
+    # nothing rides, and what it is handed for block 2 is junk
+    slab = attn.make_slab(1, 2, 24)
+    slab = {n: slab[n].at[0, 0, :8].set(attn.seat(rows[n][:1, :8], 8)[0])
+            .at[0, 1, :12].set(attn.seat(rows[n][1:, :12], 12)[0])
+            for n in rows}
+    xin = x[:, 8:].at[1, :4].set(7.0)
+    got, after = attn.decode(params, xin, slab, jnp.asarray([8, 8]),
+                             tree=both, layer=0,
+                             lead=(4, jnp.asarray([True, False])))
+    np.testing.assert_allclose(np.asarray(got[0]), np.asarray(want[0, 8:]),
+                               atol=1e-5)
+    np.testing.assert_allclose(np.asarray(got[1, 4:]),
+                               np.asarray(want[1, 12:]), atol=1e-5)
+    for n in rows:
+        seated = np.asarray(attn.seat(rows[n], 16))        # [2, 16, C]
+        np.testing.assert_allclose(np.asarray(after[n][0, :, :16]), seated,
+                                   atol=1e-6)
+        np.testing.assert_array_equal(np.asarray(after[n][0, 1, :12]),
+                                      np.asarray(slab[n][0, 1, :12]))
+        assert not np.asarray(after[n][0, :, 16:]).any()
+    # a first block at pos 0: its junk half lies before row 0 and no start
+    # is clamped over the block's own rows
+    empty = attn.make_slab(1, 2, 24)
+    got, after = attn.decode(
+        params, jnp.concatenate([xin[:, :4], x[:, :4]], axis=1), empty,
+        jnp.asarray([-4, -4]), tree=both, layer=0,
+        lead=(4, jnp.asarray([False, False])))
+    np.testing.assert_allclose(np.asarray(got[:, 4:]),
+                               np.asarray(want[:, :4]), atol=1e-5)
+    for n in rows:
+        np.testing.assert_allclose(
+            np.asarray(after[n][0, :, :4]),
+            np.asarray(attn.seat(rows[n][:, :4], 4)), atol=1e-6)
+        assert not np.asarray(after[n][0, :, 4:]).any()
+    with pytest.raises(ValueError, match="lead="):
+        attn.decode(params, xin, {n: jnp.zeros((2, 24, 2, 8)) for n in rows},
+                    8, tree=both, lead=(4, jnp.asarray([True, False])))
+    with pytest.raises(ValueError, match="lead="):
+        attn.decode(params, xin, slab, jnp.asarray([8, 8]), tree=both,
+                    layer=0, lead=(5, jnp.asarray([True, False])))
 
 
 def test_the_reveal_takes_the_surest_masked_positions():

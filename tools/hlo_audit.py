@@ -776,11 +776,13 @@ def slab(num_slots: int = None, max_len: int = None, decode_chunk: int = 4,
             "io": _io_census(hlo)}
         aliased = collections.Counter(
             a["shape"] for a in out["programs"][name]["io"]["aliases"])
-        # a block round's tok is the slots' blocks, beside their masks
+        # a block round's tok is the slots' two blocks (the one that
+        # awaits its commit, the one under denoising), beside the second's
+        # mask and the flag that says the first is there
         block = getattr(b, "_block", None)
         tok_pos = ([f"s32[{num_slots}]"] * 2 if block is None else
-                   [f"s32[{num_slots}]", f"s32[{num_slots},{block[0]}]",
-                    f"pred[{num_slots},{block[0]}]"])
+                   [f"s32[{num_slots}]", f"s32[{num_slots},{2 * block[0]}]",
+                    f"pred[{num_slots},{block[0]}]", f"pred[{num_slots}]"])
         if name != "resident" and not (
                 all(aliased[s] >= tok_pos.count(s) for s in tok_pos)
                 and aliased[f"u32[{num_slots},2]"] >= 1
