@@ -64,8 +64,8 @@ import jax
 
 __all__ = ["EventLog", "NullEventLog", "NULL_EVENT_LOG", "SPAN_KINDS",
            "STEP", "STAGE", "MICROBATCH", "COMM", "RECOMPUTE", "REQUEST",
-           "RECOVERY", "DEVICE_SCOPES", "MODEL_SCOPES", "REMAT_SCOPE",
-           "device_scope",
+           "RECOVERY", "CYCLE_PHASES", "STALL_SEC", "DEVICE_SCOPES",
+           "MODEL_SCOPES", "REMAT_SCOPE", "device_scope",
            "scoped", "stage_scope", "span", "SpanHandle"]
 
 STEP = "step"
@@ -96,18 +96,51 @@ SERVE_RETIRE = "serve.retire"
 # ... and the slot backend's calls under them: a prefill's dispatch; the
 # engine's wait for its first token, once the tick's decode launch is
 # queued; the dispatch of a decode or resident program, the host's wait
-# for it (the chunk count, then the token buffer), and a zero-length
-# record of the counts known only afterwards
+# for it (``serve.decode.sync``: inside it the wait for the round count,
+# ``rounds`` known at its end, then the blocking reads of what the launch
+# left, ``reads`` of ``bytes`` in all), and a zero-length record of the
+# counts known only afterwards
 SERVE_PREFILL = "serve.prefill"
 SERVE_PREFILL_SYNC = "serve.prefill.sync"
 SERVE_DECODE_LAUNCH = "serve.decode.launch"
 SERVE_DECODE_SYNC = "serve.decode.sync"
+SERVE_DECODE_WAIT = "serve.decode.wait"
+SERVE_DECODE_FETCH = "serve.decode.fetch"
 SERVE_DECODE_DONE = "serve.decode.done"
+# ... and a zero-length record of a request's first token (under a block
+# round its first block) reaching the host, with the stages of its TTFT:
+# ``queued_ms`` (submit to its admission's start), ``admit_ms`` (to the
+# dispatch of the launch it rides), ``launch_ms`` (to the token on the
+# host), ``ttft_ms`` (their sum)
+SERVE_FIRST_TOKEN = "serve.first_token"
+# The launch cycle: what the host does from one decode launch's dispatch to
+# the next one's, in four phases that add up to it. Each is a registry timer
+# ``serve.engine.cycle.<phase>_sec`` in every run (host clock, one
+# observation a closed cycle) and, while a profiler session is open, the
+# spans named here on the device trace's clock:
+#   wait    dispatch to the round count back on the host: the first-token
+#           reads the engine makes meanwhile (``serve.prefill.sync``), then
+#           ``serve.decode.wait``
+#   fetch   to the last blocking read back: ``serve.decode.fetch``
+#   turn    to the next dispatch, less ``caller``: every other span inside
+#           the ``serve.tick``s (retirement, ``serve.decode.done``, the next
+#           tick's reaping, admissions and ``serve.decode.launch``)
+#   caller  ``tick`` returned to ``tick`` entered again: no span can hold
+#           it, ``serve.tick`` says it as ``away_ms``
+CYCLE_PHASES = ("wait", "fetch", "turn", "caller")
+# A phase of the host's (a launch cycle's ``fetch``, ``turn`` or ``caller``;
+# a train step's ``train.batch``) that stands longer than this is a stall:
+# the device runs dry behind a process that pauses so long (PERF.md, section
+# 5). A phase that waits for the device (``wait``; ``train.dispatch``,
+# ``train.sync``) has three times what the device is expected to take on
+# top. ``telemetry.record_stall`` counts and names one.
+STALL_SEC = 0.25
 SPAN_KINDS = (STEP, STAGE, MICROBATCH, COMM, RECOMPUTE, REQUEST,
               TRAIN_BATCH, TRAIN_DISPATCH, TRAIN_SYNC,
               SERVE_TICK, SERVE_REAP, SERVE_ADMIT, SERVE_DECODE,
               SERVE_RETIRE, SERVE_PREFILL, SERVE_PREFILL_SYNC,
-              SERVE_DECODE_LAUNCH, SERVE_DECODE_SYNC, SERVE_DECODE_DONE)
+              SERVE_DECODE_LAUNCH, SERVE_DECODE_SYNC, SERVE_DECODE_WAIT,
+              SERVE_DECODE_FETCH, SERVE_DECODE_DONE, SERVE_FIRST_TOKEN)
 
 # Device scopes: what a device operation is for, readable from a capture
 # alone (the ``tf_op`` stat of a TPU op event is its op_name). A reader

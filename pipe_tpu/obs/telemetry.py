@@ -27,6 +27,7 @@ from __future__ import annotations
 import bisect
 import contextlib
 import dataclasses
+import logging
 import math
 import threading
 import time
@@ -36,8 +37,11 @@ __all__ = [
     "Counter", "Gauge", "EwmaTimer", "Histogram", "MetricsRegistry",
     "StepReport", "get_registry", "set_registry", "null_registry",
     "labelled", "percentile_exact", "host_overhead_per_token",
+    "record_stall",
     "train_flops_per_token", "peak_flops_per_chip", "device_memory_peaks",
 ]
+
+_log = logging.getLogger(__name__)
 
 
 def _escape_label(value) -> str:
@@ -436,6 +440,21 @@ def host_overhead_per_token(registry: Optional[MetricsRegistry] = None
     if not toks:
         return 0.0
     return reg.timer("serve.engine.host_sec").total / toks
+
+
+def record_stall(registry: MetricsRegistry, family: str, where: str,
+                 phase: str, wall: float, cpu: float) -> None:
+    """A phase of a host loop stood over its threshold
+    (``events.STALL_SEC``): count it in ``<family>.stalls``, add its wall
+    seconds to ``<family>.stall_sec{phase=<phase>}``, and write ONE warning,
+    so that the standard error of a run nobody traces says where the loop
+    stood and, by the process's CPU seconds beside the wall seconds,
+    whether the process was running meanwhile (it was: the interpreter's or
+    a compile's time; it was not: the machine's)."""
+    registry.counter(f"{family}.stalls").inc()
+    registry.timer(labelled(f"{family}.stall_sec", phase=phase)).observe(wall)
+    _log.warning("%s stood %.2f s in %s, cpu %.2f s", where, wall, phase,
+                 cpu)
 
 
 # --------------------------------------------------------------------------

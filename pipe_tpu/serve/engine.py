@@ -60,6 +60,7 @@ K-1 slot-steps before the host sees it.
 
 from __future__ import annotations
 
+import collections
 import functools
 import time
 from typing import (Callable, List, NamedTuple, Optional, Sequence)
@@ -76,7 +77,8 @@ from ..inference.quant import QuantLeaf, dequant_tree
 from ..models.common import refuse_grouped
 from ..obs import events as ev
 from ..obs.events import NULL_EVENT_LOG, REQUEST
-from ..obs.telemetry import get_registry, host_overhead_per_token
+from ..obs.telemetry import (get_registry, host_overhead_per_token,
+                             record_stall)
 from ..ops.layers import fold_heads, unfold_heads
 from .buckets import BucketSpec
 from .kvpool import (HostKvStore, KvPool, PoolExhausted, block_demand,
@@ -84,7 +86,8 @@ from .kvpool import (HostKvStore, KvPool, PoolExhausted, block_demand,
                      scatter_block_rows, storage_for)
 from .queue import QueueFull, Request, RequestQueue, Response
 
-__all__ = ["SingleDeviceSlotBackend", "ServeEngine", "EngineDraining"]
+__all__ = ["SingleDeviceSlotBackend", "ServeEngine", "EngineDraining",
+           "LaunchPhases", "SlowCycle"]
 
 
 # rows of a grouped model's carried ``counts``: who counted
@@ -106,12 +109,51 @@ class EngineDraining(RuntimeError):
     shutdown signal — see ``apps/serve.py``'s SIGTERM handler)."""
 
 
+class LaunchPhases(NamedTuple):
+    """A decode launch's stamps on the backend's clock: the launch in the
+    device's queue; the host's wait for it begun (the caller's ``launched``
+    done); the round count back; the last blocking read back; and the
+    rounds it ran. ``wait`` is ``counted - dispatched``, ``fetch`` is
+    ``fetched - counted`` (``events.CYCLE_PHASES``)."""
+    dispatched: float
+    sync: float
+    counted: float
+    fetched: float
+    rounds: int
+
+
+class SlowCycle(NamedTuple):
+    """A phase of the launch cycle that stood over its threshold: the tick
+    that named it, the phase, its wall seconds, and the process's CPU
+    seconds over the cycle up to then (near none: the process was not
+    running, the machine's doing; near the wall seconds: it was, the
+    interpreter's or a compile's)."""
+    tick: int
+    phase: str
+    wall_s: float
+    cpu_s: float
+
+
+class _Cycle:
+    """The open launch cycle: its dispatch, what is known of its phases,
+    and the process's CPU seconds at its start."""
+
+    __slots__ = ("dispatched", "wait", "fetch", "caller", "cpu0")
+
+    def __init__(self, dispatched: float, wait: float, fetch: float,
+                 cpu0: float):
+        self.dispatched, self.wait, self.fetch = dispatched, wait, fetch
+        self.caller, self.cpu0 = 0.0, cpu0
+
+
 class _Slot:
     """Host-side state of one running request."""
 
-    __slots__ = ("req", "tokens", "notes", "ttft", "admitted_tick")
+    __slots__ = ("req", "tokens", "notes", "ttft", "admitted_tick",
+                 "admit_at")
 
-    def __init__(self, req: Request, first_token, admitted_tick: int = 0):
+    def __init__(self, req: Request, first_token, admitted_tick: int = 0,
+                 admit_at: float = 0.0):
         self.req = req
         # the first token holds its place from admission on, as the
         # backend's prefill returned it (an int, or what int() waits on
@@ -125,6 +167,8 @@ class _Slot:
         self.notes: List[int] = []
         self.ttft: Optional[float] = None
         self.admitted_tick = admitted_tick
+        # when its admission began: where TTFT's ``queued`` stage ends
+        self.admit_at = admit_at
 
 
 class _Round(NamedTuple):
@@ -516,6 +560,11 @@ class SingleDeviceSlotBackend:
                 jnp.ones((num_slots, self._block[0]), jnp.bool_),
                 jnp.zeros((num_slots,), jnp.bool_))
         self.launch_notes = None
+        # the newest launch's stamps for the engine's account of the launch
+        # cycle, on ``clock``: the engine that drives this backend puts its
+        # own clock there, so that both stamp on one
+        self.launch_phases: Optional[LaunchPhases] = None
+        self.clock: Callable[[], float] = time.monotonic
 
         # THE decode program: one jit per round width — one in all
         # without speculation (the plain round, or the block round of a
@@ -1567,18 +1616,32 @@ class SingleDeviceSlotBackend:
                 self._resident_jits[self.decode_width](
                     *self._decode_args(live_d, budget, np.int32(rm)))
             self._store.put(kv)
+        t_dispatched = self.clock()
         self._put_slot_state((tok, pos, kd, hist))
         if launched is not None:
             launched()
         W = self.decode_width
+        t_sync = self.clock()
         with ev.span(ev.SERVE_DECODE_SYNC):
-            k = int(k)                         # THE host sync
-            buf = np.asarray(buf)              # then the two fetches
-            counts = np.asarray(counts)
-            if notes is not None:              # a noted round's
-                self.launch_notes = np.asarray(notes)[:, :k * W]
-            if self._groups is not None:       # and the layers' counts
-                self._land_counts(reg, np.asarray(self._caches["counts"]))
+            with ev.span(ev.SERVE_DECODE_WAIT) as wait:
+                k = int(k)                     # THE host sync
+                wait.set_metadata(rounds=k)
+            t_counted = self.clock()
+            with ev.span(ev.SERVE_DECODE_FETCH) as fetch:
+                fetched = [np.asarray(buf), np.asarray(counts)]
+                if notes is not None:          # a noted round's
+                    fetched.append(np.asarray(notes))
+                if self._groups is not None:   # and the layers' counts
+                    fetched.append(np.asarray(self._caches["counts"]))
+                fetch.set_metadata(reads=len(fetched),
+                                   bytes=sum(a.nbytes for a in fetched))
+            self.launch_phases = LaunchPhases(
+                t_dispatched, t_sync, t_counted, self.clock(), k)
+            buf, counts = fetched[:2]
+            if notes is not None:
+                self.launch_notes = fetched[2][:, :k * W]
+            if self._groups is not None:
+                self._land_counts(reg, fetched[-1])
         if k < rm:
             reg.counter("serve.engine.device_exits").inc()
         toks = buf[:, :k * W]
@@ -1851,6 +1914,13 @@ class ServeEngine:
         # observed per-chunk decode latency (EWMA) — sizes the resident
         # deadline horizon in chunks; None until the first decode
         self._chunk_ewma: Optional[float] = None
+        # the launch cycle (``events.CYCLE_PHASES``): the open cycle, when
+        # ``tick`` last returned, and the last 32 phases that stalled
+        if hasattr(backend, "launch_phases"):
+            backend.clock = self.clock
+        self._cycle: Optional[_Cycle] = None
+        self._t_left: Optional[float] = None
+        self.slow_cycles: collections.deque = collections.deque(maxlen=32)
 
     # -- front door --------------------------------------------------------
 
@@ -1897,7 +1967,6 @@ class ServeEngine:
         self.backend.validate(len(req.prompt), req.max_new_tokens)
         self.queue.requeue(req)
         req.attempts += 1
-        reg.counter("serve.engine.placed").inc()
         reg.gauge("serve.engine.queue_depth").set(self.queue.depth)
         return req
 
@@ -2088,15 +2157,68 @@ class ServeEngine:
         watchdog policies, admit into free slots, launch the decode,
         retire. Returns the requests that reached a terminal state
         during this tick."""
+        t_in = self.clock()
+        away = 0.0 if self._t_left is None else t_in - self._t_left
         with self.events.span(ev.SERVE_TICK, tick=self._tick_index,
                               live=self.live_slots,
-                              queued=self.queue.depth):
-            return self._tick()
+                              queued=self.queue.depth, away_ms=1e3 * away):
+            finished = self._tick(away)
+        self._t_left = self.clock()
+        return finished
 
-    def _tick(self) -> List[Response]:
+    def _stalled(self, tick_idx: int, phase: str, wall: float,
+                 cpu0: float) -> None:
+        """A phase of the launch cycle stood over its threshold: keep it
+        in ``slow_cycles`` and have it counted and named once
+        (``telemetry.record_stall``), with the process's CPU seconds since
+        ``cpu0``."""
+        slow = SlowCycle(tick_idx, phase, wall, time.process_time() - cpu0)
+        self.slow_cycles.append(slow)
+        record_stall(get_registry(), "serve.engine",
+                     f"serve engine: tick {tick_idx}", phase, wall,
+                     slow.cpu_s)
+
+    def _launch_dispatched(self, tick_idx: int, t0: float, t1: float,
+                           cpu0: float, chunks: int) -> float:
+        """A launch came back: close the cycle its dispatch ends (the four
+        timers, which so add up to the time between dispatches; ``turn`` is
+        what the other three leave) and open its own, whose ``wait`` and
+        ``fetch`` are known. A backend that stamps nothing gives the whole
+        call as ``wait``. Returns the dispatch's time."""
+        reg = get_registry()
+        ph = getattr(self.backend, "launch_phases", None) \
+            or LaunchPhases(t0, t0, t1, t1, chunks)
+        old = self._cycle
+        if old is not None:
+            turn = (ph.dispatched - old.dispatched - old.wait - old.fetch
+                    - old.caller)
+            for phase, sec in zip(ev.CYCLE_PHASES, (old.wait, old.fetch,
+                                                    turn, old.caller)):
+                reg.timer(f"serve.engine.cycle.{phase}_sec").observe(sec)
+            if turn > ev.STALL_SEC:
+                self._stalled(tick_idx, "turn", turn, old.cpu0)
+        new = self._cycle = _Cycle(
+            ph.dispatched, ph.counted - ph.dispatched,
+            ph.fetched - ph.counted, cpu0)
+        # the wait proper, behind the first-token reads, against what the
+        # rounds it ran take by the running mean (none before the first
+        # launch, which compiles)
+        ew = self._chunk_ewma
+        if ew is not None and (ph.counted - ph.sync
+                               > ev.STALL_SEC + 3 * ph.rounds * ew):
+            self._stalled(tick_idx, "wait", ph.counted - ph.sync, cpu0)
+        if new.fetch > ev.STALL_SEC:
+            self._stalled(tick_idx, "fetch", new.fetch, cpu0)
+        return ph.dispatched
+
+    def _tick(self, away: float) -> List[Response]:
         reg = get_registry()
         tick_idx = self._tick_index
         self._tick_index += 1
+        if self._cycle is not None:
+            self._cycle.caller += away
+            if away > ev.STALL_SEC:
+                self._stalled(tick_idx, "caller", away, self._cycle.cpu0)
         if self.chaos is not None:
             self._apply_chaos(reg, tick_idx)
         t_start = self.clock()
@@ -2221,7 +2343,8 @@ class ServeEngine:
                         self._fail_queued(req, e, self.clock()))
                     continue
                 device_sec += self.clock() - t_pre
-                self._slots[slot] = _Slot(req, tok0, admitted_tick=tick_idx)
+                self._slots[slot] = _Slot(req, tok0, admitted_tick=tick_idx,
+                                          admit_at=t_pre)
                 if isinstance(tok0, (int, np.integer)):
                     # a token that has already arrived
                     self._land_first_tokens([slot], finished)
@@ -2251,9 +2374,10 @@ class ServeEngine:
             # the admissions' first tokens are read once the launch is in
             # the device's queue: they wait for the prefill programs, which
             # end before it does, with the device busy meanwhile
-            kw = {"launched": functools.partial(
-                self._land_first_tokens, pending, finished,
-                overlapped=True)} if pending else {}
+            kw = {"launched": lambda: self._land_first_tokens(
+                pending, finished, dispatched=self.clock())} \
+                if pending else {}
+            cpu0 = time.process_time()
             t0 = self.clock()
             try:
                 reg.counter("serve.engine.host_syncs").inc()
@@ -2276,6 +2400,8 @@ class ServeEngine:
                     self.backend, "decode_width",
                     getattr(self.backend, "decode_chunk", 1))
                 chunks = max(1, toks.shape[1] // max(1, width))
+                dispatched = self._launch_dispatched(tick_idx, t0, t1, cpu0,
+                                                     chunks)
                 per = decode_sec / chunks
                 self._chunk_ewma = per if self._chunk_ewma is None \
                     else 0.8 * self._chunk_ewma + 0.2 * per
@@ -2291,7 +2417,8 @@ class ServeEngine:
                             if not valid[slot, k]:
                                 continue
                             if not st.tokens:    # a block round's first
-                                self._first_token_landed(slot, st, t1)
+                                self._first_token_landed(slot, st, t1,
+                                                         dispatched)
                             t = int(toks[slot, k])
                             st.tokens.append(t)
                             if notes is not None:
@@ -2335,6 +2462,10 @@ class ServeEngine:
         pool = getattr(self.backend, "pool", None)
         if pool is not None:
             pool.observe()
+        if self.idle:
+            # no work to hold the device back from: what the caller does
+            # until the next request is no launch's cost
+            self._cycle = None
         dur = self.clock() - t_start
         # everything in the tick that was NOT a device launch (prefill
         # or decode) is host overhead the resident loop amortizes away;
@@ -2352,16 +2483,17 @@ class ServeEngine:
 
     def _land_first_tokens(self, pending: List[int],
                            finished: List[Response],
-                           overlapped: bool = False) -> None:
+                           dispatched: Optional[float] = None) -> None:
         """The first token of every slot in ``pending`` (emptied) arrives
         on the host: ``int()`` of what the backend's prefill returned,
         which for a device value waits until that admission's program is
         done. This is the TTFT moment: ``ttft`` is stamped, the request's
         prefill record written, and a slot whose first token is eos or
         its whole budget retires. A read that raises fails that one
-        request, as a raising prefill does. ``overlapped``: the tick's
-        decode launch is already in the device's queue (the backend's
-        ``launched`` call), so the device works through the wait."""
+        request, as a raising prefill does. ``dispatched``: when the
+        tick's decode launch went into the device's queue (the backend's
+        ``launched`` call), so the device works through the wait; None
+        where there is no launch to ride."""
         reg = get_registry()
         eos = self.backend.gen.eos_token_id
         while pending:
@@ -2380,30 +2512,40 @@ class ServeEngine:
                 finished.append(self._fail_queued(req, e, self.clock()))
                 continue
             t_first = self.clock()
-            self._first_token_landed(slot, st, t_first)
-            if overlapped:
+            self._first_token_landed(slot, st, t_first, dispatched)
+            if dispatched is not None:
                 reg.counter("serve.engine.first_tokens_overlapped").inc()
             if eos is not None and tok0 == eos:
                 finished.append(self._retire(slot, "ok", "eos", t_first))
             elif req.max_new_tokens == 1:
                 finished.append(self._retire(slot, "ok", "length", t_first))
 
-    def _first_token_landed(self, slot: int, st: _Slot,
-                            t_first: float) -> None:
+    def _first_token_landed(self, slot: int, st: _Slot, t_first: float,
+                            dispatched: Optional[float] = None) -> None:
         """The TTFT moment of the request in ``slot``: its first token is
         on the host (a prefill's, or with the first block of a block
         round the whole block's). ``ttft`` is stamped and the request's
-        prefill record written."""
+        prefill record written, with TTFT's three stages, which add up to
+        it: in the queue, to the dispatch of the launch it rides (its own
+        admission and those after it; ``dispatched``, None where it rode
+        none), and from there to the token."""
         reg = get_registry()
         req = st.req
         st.ttft = t_first - req.submitted_at
         reg.counter("serve.engine.admitted").inc()
         reg.histogram("serve.engine.ttft_sec").observe(st.ttft)
+        rode = t_first if dispatched is None else dispatched
+        stages = {"queued_ms": 1e3 * (st.admit_at - req.submitted_at),
+                  "admit_ms": 1e3 * (rode - st.admit_at),
+                  "launch_ms": 1e3 * (t_first - rode)}
+        with self.events.span(ev.SERVE_FIRST_TOKEN, request=req.id,
+                              slot=slot, **stages, ttft_ms=1e3 * st.ttft):
+            pass
         self.events.event(REQUEST, request=req.id,
                           stage="prefill", trace=req.trace_id,
                           slot=slot, ttft=st.ttft,
                           attempts=req.attempts,
-                          prompt_len=len(req.prompt))
+                          prompt_len=len(req.prompt), **stages)
 
     def _resident_horizon(self, now: float) -> int:
         """How many chunks the device may run before host attention

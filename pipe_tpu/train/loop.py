@@ -28,7 +28,7 @@ from ..models.transformer_lm import LMConfig, PipelinedLM
 from ..obs import events as ev
 from ..obs.meters import profile_trace
 from ..obs.telemetry import (StepReport, device_memory_peaks, get_registry,
-                             peak_flops_per_chip)
+                             peak_flops_per_chip, record_stall)
 from ..parallel.mesh import make_mesh
 from ..parallel.spmd import SpmdPipeline, stack_stage_params
 from ..data import lm_text
@@ -345,6 +345,11 @@ class Trainer:
                 os.path.join(cfg.telemetry_dir, "events.jsonl"))
         else:
             self.events = ev.NULL_EVENT_LOG
+        # the step's clock, and the running mean of a step's wall time from
+        # the second step on (the first compiles): what a phase that waits
+        # for the device is held against (``_check_stall``)
+        self._clock: Callable[[], float] = time.perf_counter
+        self._step_ewma: Optional[float] = None
 
     # --- state ---
 
@@ -549,8 +554,25 @@ class Trainer:
 
     def _sync(self, loss, step: int) -> float:
         """A blocking read of a loss: the host waits for that step."""
+        t0, cpu0 = self._clock(), time.process_time()
         with self.events.span(ev.TRAIN_SYNC, step=step):
-            return float(loss)
+            value = float(loss)
+        self._check_stall(step, ev.TRAIN_SYNC, self._clock() - t0, cpu0,
+                          waits=True)
+        return value
+
+    def _check_stall(self, step: int, phase: str, wall: float, cpu0: float,
+                     waits: bool = False) -> None:
+        """The serve engine's stall rule on a phase of the train step: over
+        ``events.STALL_SEC``, and for a phase that ``waits`` for the device
+        three times a step's running mean more, it is counted
+        (``train.stalls``, ``train.stall_sec{phase=}``) and named once.
+        Before there is a mean (the first step compiles) nothing is."""
+        mean = self._step_ewma
+        if mean is not None and wall > ev.STALL_SEC + (3 * mean if waits
+                                                       else 0.0):
+            record_stall(self.registry, "train", f"trainer: step {step}",
+                         phase, wall, time.process_time() - cpu0)
 
     def _count_step_trace(self):
         """Runs at trace time only (as the serve programs' ``*_traces``
@@ -772,7 +794,7 @@ class Trainer:
             b = start_step + i
             tracing = bool(telemetry_on and cfg.profile_every
                            and (b + 1) % cfg.profile_every == 0)
-            t_step = time.perf_counter()
+            t_step, cpu_step = self._clock(), time.process_time()
             with contextlib.ExitStack() as scopes:
                 if tracing:
                     trace_dir = os.path.join(cfg.telemetry_dir,
@@ -799,6 +821,7 @@ class Trainer:
                         kill = (self.chaos.train_kill(b)
                                 if self.chaos is not None else KILL_NONE)
                         args += (jnp.int32(kill),)
+                t_batch = self._clock()
                 # the call: enqueue time on an asynchronous backend, not
                 # step time
                 with self.events.span(ev.TRAIN_DISPATCH, step=b):
@@ -812,7 +835,13 @@ class Trainer:
                 sync_if_forced_cpu(loss)
                 if tracing:
                     jax.block_until_ready(loss)  # capture the whole step
-            wall = time.perf_counter() - t_step
+            wall = self._clock() - t_step
+            self._check_stall(b, ev.TRAIN_BATCH, t_batch - t_step, cpu_step)
+            self._check_stall(b, ev.TRAIN_DISPATCH, wall - (t_batch - t_step),
+                              cpu_step, waits=True)
+            if i >= 1:
+                self._step_ewma = wall if self._step_ewma is None \
+                    else 0.8 * self._step_ewma + 0.2 * wall
             steps_ctr.inc()
             tokens_ctr.inc(tokens_per_step)
             if wall > 0:
